@@ -43,11 +43,6 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
 sys.path.insert(0, REPO)
 
-if "cpu" in os.environ.get("JAX_PLATFORMS", ""):
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
 RESULTS = os.path.join(HERE, "bench_results.jsonl")
 
 
@@ -65,9 +60,7 @@ def run(geometry: str | None = None) -> dict:
 
     from pathway_tpu.generation import DecodeSession
     from pathway_tpu.models.decoder import CausalLM, DecoderConfig
-    from pathway_tpu.utils.compile_cache import enable_compile_cache
 
-    enable_compile_cache()
     platform = jax.devices()[0].platform
     if geometry is None:
         geometry = "gpt2" if platform == "tpu" else "small"
@@ -212,9 +205,7 @@ def run_speculative(
     from pathway_tpu.generation import DecodeSession
     from pathway_tpu.generation.engine import generation_status
     from pathway_tpu.models.decoder import CausalLM, DecoderConfig
-    from pathway_tpu.utils.compile_cache import enable_compile_cache
 
-    enable_compile_cache()
     platform = jax.devices()[0].platform
     if geometry is None:
         geometry = "gpt2" if platform == "tpu" else "small"

@@ -21,11 +21,6 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
 sys.path.insert(0, REPO)
 
-if "cpu" in os.environ.get("JAX_PLATFORMS", ""):
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
 
 def run(geometry: str = "gpt2") -> dict:
     import jax
@@ -33,9 +28,7 @@ def run(geometry: str = "gpt2") -> dict:
     import numpy as np
 
     from pathway_tpu.models.decoder import CausalLM, DecoderConfig
-    from pathway_tpu.utils.compile_cache import enable_compile_cache
 
-    enable_compile_cache()
     platform = jax.devices()[0].platform
     if geometry == "tiny":
         cfg = DecoderConfig(

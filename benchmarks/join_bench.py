@@ -20,10 +20,6 @@ import time
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
-
 import pathway_tpu as pw  # noqa: E402
 
 

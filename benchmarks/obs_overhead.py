@@ -23,11 +23,6 @@ encoder tick would show.
 One JSON line (metric ``obs_overhead``) prints and appends to
 ``benchmarks/bench_results.jsonl``.
 
-Also hosts ``--profile-probe``: the chip watcher's ``profile`` suite —
-starts a webserver next to live device work and captures one REAL
-``/v1/debug/profile`` window, banking the artifact's existence + size
-(metric ``device_profile``; platform-gated by the watcher).
-
 ``--fleet`` runs the same A/B THROUGH a fleet router (replica + router
 per phase child): the ON side adds the federation scrape plane and the
 dispatch spans, the OFF side kills them with
@@ -251,60 +246,8 @@ FUSED_PHASE_ENV = {
 }
 
 
-def profile_probe() -> dict:
-    """chip_watch ``profile`` suite body: capture one REAL device-profile
-    window from a live webserver while device work runs, and report the
-    artifact (the watcher banks it only when platform == tpu)."""
-    import threading
-
-    import numpy as np
-
-    import jax
-    from pathway_tpu.io.http import PathwayWebserver
-    from pathway_tpu.ops.knn import DeviceKnnIndex
-
-    os.environ.setdefault(
-        "PATHWAY_PROFILE_DIR", os.path.join(tempfile.gettempdir(), "pw_chip_prof")
-    )
-    idx = DeviceKnnIndex(dim=128, capacity=4096)
-    rng = np.random.default_rng(0)
-    for i in range(1024):
-        idx.upsert(i, rng.standard_normal(128))
-    stop = threading.Event()
-
-    def churn():
-        q = rng.standard_normal((8, 128))
-        while not stop.is_set():
-            idx.search(q, k=10)
-
-    th = threading.Thread(target=churn, daemon=True)
-    th.start()
-    ws = PathwayWebserver(host="127.0.0.1", port=_free_port())
-    ws._ensure_started()
-    try:
-        req = urllib.request.Request(
-            f"http://127.0.0.1:{ws.port}/v1/debug/profile?ms=1000"
-        )
-        with urllib.request.urlopen(req, timeout=60) as resp:
-            body = resp.read()
-            kind = resp.headers.get("x-pathway-profile-kind")
-    finally:
-        stop.set()
-        th.join(timeout=5)
-    return {
-        "metric": "device_profile",
-        "platform": jax.default_backend(),
-        "kind": kind,
-        "size_bytes": len(body),
-        "window_ms": 1000,
-    }
-
-
 def main() -> int:
     args = sys.argv[1:]
-    if "--profile-probe" in args:
-        print(json.dumps(profile_probe()))
-        return 0
     n_docs = next((int(a) for a in args if a.isdigit()), N_DOCS)
     if "--phase" in args:
         print(json.dumps(_phase(n_docs)))

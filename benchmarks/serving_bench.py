@@ -108,11 +108,6 @@ if "--mesh" in sys.argv and (
 # file, so the cleared env propagates to every phase/loadgen subprocess)
 os.environ.pop("PATHWAY_SERVING_MESH", None)
 
-if "cpu" in os.environ.get("JAX_PLATFORMS", ""):
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
-
 
 def _free_port() -> int:
     s = socket.socket()
@@ -149,14 +144,12 @@ def run(n_docs: int = 120) -> dict:
     import numpy as np
 
     import pathway_tpu as pw
-    from pathway_tpu.utils.compile_cache import enable_compile_cache
     from pathway_tpu.xpacks.llm.embedders import SentenceTransformerEmbedder
     from pathway_tpu.xpacks.llm.vector_store import (
         VectorStoreClient,
         VectorStoreServer,
     )
 
-    enable_compile_cache()
     platform = jax.devices()[0].platform
     docs = _corpus(n_docs)
 
@@ -434,10 +427,8 @@ def run_concurrent(n_docs: int, clients: int, queries_per_client: int,
     import jax
 
     import pathway_tpu as pw
-    from pathway_tpu.utils.compile_cache import enable_compile_cache
     from pathway_tpu.xpacks.llm import _scheduler as sched_mod
 
-    enable_compile_cache()
     platform = jax.devices()[0].platform
     docs = _corpus(n_docs)
     out: dict = {
@@ -531,9 +522,7 @@ def run_mesh_phase(phase: str, n_docs: int, mesh_n: int, mock: bool,
     import pathway_tpu as pw  # noqa: F401 — jax config + path setup
     from pathway_tpu.parallel import make_mesh
     from pathway_tpu.stdlib.indexing.lowering import live_index_node
-    from pathway_tpu.utils.compile_cache import enable_compile_cache
 
-    enable_compile_cache()
     avail = jax.device_count()
     rec: dict = {
         "platform": jax.devices()[0].platform,
@@ -770,10 +759,8 @@ def run_zipf_phase(phase: str, n_docs: int, zipf_s: float, clients: int,
 
     import jax
 
-    from pathway_tpu.utils.compile_cache import enable_compile_cache
     from pathway_tpu.xpacks.llm import _query_cache as qc
 
-    enable_compile_cache()
     rec: dict = {"platform": jax.devices()[0].platform}
     docs = _corpus(n_docs)
     with tempfile.TemporaryDirectory() as base:
@@ -908,9 +895,7 @@ def run_fused_phase(phase: str, n_docs: int, ticks: int) -> dict:
 
     from pathway_tpu.ops import fused_serving as fs
     from pathway_tpu.ops.knn import DeviceKnnIndex
-    from pathway_tpu.utils.compile_cache import enable_compile_cache
 
-    enable_compile_cache()
     dim, q_per_tick, k = 64, 8, 10
     rng = np.random.default_rng(20260807)
     idx = DeviceKnnIndex(
@@ -1212,11 +1197,9 @@ def run_contention_phase(phase: str, n_docs: int, clients: int,
 
     from pathway_tpu import runtime as rt_mod
     from pathway_tpu.ops.knn import DeviceKnnIndex
-    from pathway_tpu.utils.compile_cache import enable_compile_cache
     from pathway_tpu.xpacks.llm._ingest import IngestPipeline
 
     fused = phase == "runtime"
-    enable_compile_cache()
     platform = jax.devices()[0].platform
     docs = _corpus(n_docs)
     ingest_docs = _ingest_corpus(max(4 * int(ingest_load), 256))
@@ -1489,7 +1472,10 @@ def _fleet_phase(n_replicas: int, n_docs: int, queries_per_client: int,
 
     from pathway_tpu.fleet import launcher
     from pathway_tpu.fleet.router import FleetRouter
+    from pathway_tpu.utils.chips import local_chip_count
 
+    # one process per chip: on a host with chips replica i owns chip i
+    on_chips = local_chip_count() > 0
     router = FleetRouter(poll_interval_s=0.5)
     rport = router.start(port=_free_port())
     router_url = f"http://127.0.0.1:{rport}"
@@ -1501,6 +1487,7 @@ def _fleet_phase(n_replicas: int, n_docs: int, queries_per_client: int,
                 port=_free_port(), router_url=router_url,
                 name=f"r{i}",
                 env={"PATHWAY_FLEET_EMU_DEVICE_MS": f"{emu_ms:g}"},
+                chip=i if on_chips else None,
             ))
         deadline = time.monotonic() + 300
         while time.monotonic() < deadline:
@@ -1727,8 +1714,7 @@ if __name__ == "__main__":
     if fused_ab:
         # 1024 docs: the dispatch-bound serving regime the fused launch
         # targets (the [Q, N] matmul is small enough that launch count,
-        # not FLOPs, sets the tick) — chip runs sweep larger N via the
-        # armed chip_watch `fused` suite
+        # not FLOPs, sets the tick)
         n = int(args[0]) if args else 1024
         out = run_fused_ab(n)
         out["ts"] = time.strftime("%Y-%m-%dT%H:%M:%S")

@@ -79,10 +79,6 @@ if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
         + " --xla_force_host_platform_device_count=8"
     ).strip()
 
-import jax
-
-jax.config.update("jax_platforms", "cpu")
-
 
 def _free_port() -> int:
     s = socket.socket()

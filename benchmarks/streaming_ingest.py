@@ -23,19 +23,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import jax  # noqa: E402
-
-if "cpu" in os.environ.get("JAX_PLATFORMS", ""):
-    # a TPU shim may prepend its platform after env parsing; pinning the
-    # config is the only reliable way to honor a CPU request — but ONLY
-    # when CPU was requested: unconditional pinning made chip_suite's
-    # "on-chip" ingest benchmark silently measure CPU
-    jax.config.update("jax_platforms", "cpu")
-
-from pathway_tpu.utils.compile_cache import enable_compile_cache  # noqa: E402
-
-enable_compile_cache()
-
 import pathway_tpu as pw  # noqa: E402
 from pathway_tpu.xpacks.llm import mocks  # noqa: E402
 from pathway_tpu.xpacks.llm.vector_store import (  # noqa: E402

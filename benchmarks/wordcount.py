@@ -19,12 +19,6 @@ import time
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import jax  # noqa: E402
-
-# a TPU shim may prepend its platform after env parsing; pinning the
-# config is the only reliable way to stay on CPU (see tests/conftest.py)
-jax.config.update("jax_platforms", "cpu")
-
 import pathway_tpu as pw  # noqa: E402
 
 

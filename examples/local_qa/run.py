@@ -23,14 +23,6 @@ HERE = pathlib.Path(__file__).resolve().parent
 sys.path.insert(0, str(HERE.parent.parent))
 os.chdir(HERE.parent.parent)
 
-if "cpu" in os.environ.get("JAX_PLATFORMS", ""):
-    # honor a CPU request even when a TPU shim prepends its own platform
-    # after env parsing (same guard as examples/rag_app/run.py; the
-    # pathway_tpu import applies it too — this covers earlier jax imports)
-    import jax  # noqa: E402
-
-    jax.config.update("jax_platforms", "cpu")
-
 import pathway_tpu as pw  # noqa: E402
 from pathway_tpu.xpacks.llm.question_answering import RAGClient  # noqa: E402
 

@@ -29,13 +29,6 @@ import os  # noqa: E402
 # cwd work
 os.chdir(HERE.parent.parent)
 
-if "cpu" in os.environ.get("JAX_PLATFORMS", ""):
-    # honor a CPU request even when a TPU shim prepends its own platform
-    # after env parsing (same guard as __graft_entry__.py)
-    import jax  # noqa: E402
-
-    jax.config.update("jax_platforms", "cpu")
-
 import pathway_tpu as pw  # noqa: E402
 from pathway_tpu.xpacks.llm.question_answering import RAGClient  # noqa: E402
 

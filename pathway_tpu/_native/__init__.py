@@ -1,10 +1,12 @@
 """Loader for the C++ host-runtime core (native.cpp).
 
 Compiles ``native.cpp`` with g++ on first import (cached as a .so next to
-the source, keyed by a source hash) and binds it via ctypes.  Everything
-here has a pure-Python fallback at the call sites — import failure just
-means the slower path runs (keys.py, models/tokenizer.py check for this
-module with try/except).
+the source, keyed by a hash of the source and the compiler flags) and
+binds it via ctypes.  The build targets the baseline ISA, not the
+building host's: the .so is git-ignored but travels with a copied tree,
+so it must load on a machine with a different CPU.  The call site
+(models/tokenizer.py) has a pure-Python fallback, ~130x slower, which
+warns when it engages.
 """
 
 from __future__ import annotations
@@ -23,12 +25,16 @@ ABI_VERSION = 1
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "native.cpp")
+_CXXFLAGS = ("-O3", "-shared", "-fPIC", "-std=c++17")
 
 
 def _build() -> str:
     with open(_SRC, "rb") as f:
         src = f.read()
-    tag = hashlib.blake2b(src + str(ABI_VERSION).encode(), digest_size=8).hexdigest()
+    tag = hashlib.blake2b(
+        src + str(ABI_VERSION).encode() + " ".join(_CXXFLAGS).encode(),
+        digest_size=8,
+    ).hexdigest()
     so_path = os.path.join(_HERE, f"_pathway_native_{tag}.so")
     if os.path.exists(so_path):
         return so_path
@@ -38,10 +44,7 @@ def _build() -> str:
     os.close(fd)
     try:
         subprocess.run(
-            [
-                "g++", "-O3", "-march=native", "-shared", "-fPIC",
-                "-std=c++17", "-o", tmp, _SRC,
-            ],
+            ["g++", *_CXXFLAGS, "-o", tmp, _SRC],
             check=True,
             capture_output=True,
         )

@@ -12,7 +12,9 @@ Usage::
 Each spawned process gets PATHWAY_PROCESS_ID/PATHWAY_PROCESSES/
 PATHWAY_THREADS/PATHWAY_FIRST_PORT; process 0 inherits stdio.  The host
 plane shards sources by these (internals/config.py); the device plane
-sizes its mesh from jax.device_count, not from the env.
+sizes its mesh from jax.device_count, not from the env.  On a host with
+TPU chips, process i of N > 1 is pinned to chip i (``utils/chips.py``): a
+chip belongs to one process.
 """
 
 from __future__ import annotations
@@ -102,11 +104,16 @@ def spawn_program(
             "PATHWAY_FIRST_PORT": str(first_port),
         }
     )
+    from .utils.chips import child_chip_env
+
     procs: list[subprocess.Popen] = []
     try:
         for pid in range(processes):
             penv = dict(base_env)
             penv["PATHWAY_PROCESS_ID"] = str(pid)
+            # one process per chip: this launcher never touches JAX, and
+            # each child sees its own chip (utils/chips.py)
+            penv.update(child_chip_env(pid, processes, base_env))
             procs.append(
                 subprocess.Popen([program, *arguments], env=penv, cwd=cwd)
             )

@@ -44,10 +44,14 @@ def spawn_replica(
     mock_dim: int = 16,
     env: dict | None = None,
     python: str | None = None,
+    chip: int | None = None,
 ) -> "subprocess.Popen":
     """Start a replica child process; returns the ``Popen``.  The child
     registers itself with the router once ready — the caller only needs
-    to keep the handle for kill/wait."""
+    to keep the handle for kill/wait.  The child runs on whatever platform
+    JAX finds there; on a host with several TPU chips pass ``chip`` so
+    each replica owns exactly one (``utils/chips.py``) — without it the
+    first replica claims them all."""
     argv = [
         python or sys.executable,
         "-m",
@@ -66,7 +70,10 @@ def spawn_replica(
     if name:
         argv += ["--name", name]
     child_env = dict(os.environ)
-    child_env.setdefault("JAX_PLATFORMS", "cpu")
+    if chip is not None:
+        from ..utils.chips import one_chip_env
+
+        child_env.update(one_chip_env(chip))
     repo_root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)
     )))
@@ -122,7 +129,6 @@ def main(argv: "list[str] | None" = None) -> int:
     ap.add_argument("--mock-dim", type=int, default=16)
     args = ap.parse_args(argv)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import pathway_tpu as pw
     from ..xpacks.llm.vector_store import VectorStoreServer
     from . import member as member_mod

@@ -823,13 +823,18 @@ def insert_exchanges(engine, plane: ExchangePlane) -> None:
         return None
 
     # index serving: docs broadcast to every process (each keeps a full
-    # replica, reference external_index.rs:95-98); queries stay local
-    from ..stdlib.indexing.lowering import ExternalIndexNode
+    # replica, reference external_index.rs:95-98); queries stay local.
+    # A graph with an index node has imported its module already; a
+    # host-only graph must not pull jax in through it
+    import sys
+
+    lowering = sys.modules.get("pathway_tpu.stdlib.indexing.lowering")
+    index_nodes = (lowering.ExternalIndexNode,) if lowering else ()
 
     counter = 0
     for node in list(engine.nodes):
         broadcast_ports: set[int] = set()
-        if isinstance(node, ExternalIndexNode):
+        if isinstance(node, index_nodes):
             key_map: dict[int, Callable | None] | None = {0: None}
             broadcast_ports = {0}
         else:

@@ -80,6 +80,17 @@ def run(
 
     _warn_thread_mapping()
 
+    # device work starts here (every server start is a pw.run): compiles
+    # persist per the one cache policy of utils/compile_cache.py.  A graph
+    # that has not imported jax by now holds no index and no server, and
+    # importing it would cost a host-only pipeline a second of start-up
+    import sys
+
+    if "jax" in sys.modules:
+        from ..utils.compile_cache import enable_compile_cache
+
+        enable_compile_cache()
+
     from .telemetry import get_telemetry, setup_otlp
 
     # refresh: the endpoint may have been set (env or

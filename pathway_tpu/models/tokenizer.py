@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import itertools
 import os
+import subprocess
 import threading
 from typing import Sequence
 
@@ -26,7 +27,14 @@ from ..internals.lru import BoundedLru
 
 try:  # hot-path C++ batch encoder
     from pathway_tpu import _native
-except Exception:  # pragma: no cover - fallback always works
+except (OSError, ImportError, subprocess.CalledProcessError) as _exc:
+    import warnings
+
+    warnings.warn(
+        f"native tokenizer unavailable ({type(_exc).__name__}: {_exc}); "
+        "HashTokenizer runs its pure-Python path, ~130x slower",
+        stacklevel=2,
+    )
     _native = None
 
 __all__ = ["HashTokenizer", "load_tokenizer", "token_cache", "TokenCache"]

@@ -1018,8 +1018,8 @@ class DeviceKnnIndex:
     ) -> list[list[tuple[Hashable, float]]]:
         """Batched :meth:`search_among`: one device call rescoring every
         query against its own candidate set (padded to shared buckets so
-        compiled shapes stay stable).  The per-query form costs one RPC
-        round trip each over a remote chip; this is the LSH serving path."""
+        compiled shapes stay stable).  The per-query form pays one
+        dispatch per query; this is the LSH serving path."""
         with self._lock:
             return self._search_among_batched_locked(queries, keys_lists, k)
 

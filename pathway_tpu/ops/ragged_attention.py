@@ -164,9 +164,12 @@ def _ragged_kernel(
     sm_scale: float,
     causal: bool,
 ):
-    i = pl.program_id(1)
-    lo = bounds_ref[i, 0]
-    hi = bounds_ref[i, 1]
+    # a launch smaller than TOKEN_BLOCK is ONE block (ragged_block), so
+    # its offsets are the static 0: Mosaic refuses a dynamic lane-axis
+    # slice it cannot prove 128-aligned ("cannot statically prove that
+    # index in dimension 1 is a multiple of 128" at block 32/64)
+    single_block = seg_ref.shape[1] == block_q
+    i = 0 if single_block else pl.program_id(1)
     q = q_ref[0].astype(jnp.float32) * sm_scale  # [bq, dh]
     seg_q = seg_ref[0, pl.ds(i * block_q, block_q)]  # [bq]
     pos_q = pos_ref[0, pl.ds(i * block_q, block_q)]  # [bq]
@@ -202,7 +205,13 @@ def _ragged_kernel(
     m0 = jnp.full((block_q, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
     a0 = jnp.zeros((block_q, q_ref.shape[-1]), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(lo, hi, body, (m0, l0, a0))
+    if single_block:
+        # never a pure-pad launch (>= 1 real token), so bounds are [0, 1)
+        m, l, acc = body(0, (m0, l0, a0))
+    else:
+        m, l, acc = jax.lax.fori_loop(
+            bounds_ref[i, 0], bounds_ref[i, 1], body, (m0, l0, a0)
+        )
     # pad-tail blocks (zero-trip) and all-pad rows divide 0/eps -> 0
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 

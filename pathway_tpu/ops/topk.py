@@ -194,9 +194,8 @@ def among_topk_search(
 
     The LSH rescoring path (reference: _knn_lsh.py:219-256 rescores each
     query's bucket union) previously dispatched one gather+top-k per
-    query; over a remote chip that is a full RPC round trip each.  Here
-    all Q candidate sets ride one gather ([Q, C, D]) and one batched
-    matvec.
+    query.  Here all Q candidate sets ride one gather ([Q, C, D]) and
+    one batched matvec.
     """
     sub = vectors[idx]  # [Q, C, D]
     v = valid[idx] & pad_valid

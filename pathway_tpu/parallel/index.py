@@ -31,7 +31,6 @@ from ..ops.knn import (
     _quant_scatter_body,
     _scatter_rows_dropping_body,
 )
-from ._compat import shard_map
 from .mesh import data_axis
 
 __all__ = ["ShardedKnnIndex", "mesh_status"]
@@ -74,7 +73,7 @@ def _sharded_search_fn(mesh: Mesh, k: int, metric: str, n_local: int):
         in_specs=(P(), P(data_axis, None), P(data_axis)),
         out_specs=(P(), P()),
     )
-    mapped = shard_map(local_search, check_replication=False, **specs)
+    mapped = jax.shard_map(local_search, check_vma=False, **specs)
     return jax.jit(mapped)
 
 
@@ -128,7 +127,7 @@ def _sharded_quant_search_fn(
         in_specs=(P(), P(data_axis, None), P(data_axis), P(data_axis)),
         out_specs=(P(), P()),
     )
-    mapped = shard_map(local_search, check_replication=False, **specs)
+    mapped = jax.shard_map(local_search, check_vma=False, **specs)
     return jax.jit(mapped)
 
 

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ._compat import shard_map
 from .ring_attention import ring_attention
 
 __all__ = ["ring_encode", "ring_forward"]
@@ -96,12 +95,12 @@ def _compiled(mesh: Mesh, axis: str, num_layers: int, ln_eps: float,
     @jax.jit
     def run(params, ids, mask):
         out_spec = P() if pool else P(None, axis)
-        f = shard_map(
+        f = jax.shard_map(
             fwd,
             mesh=mesh,
             in_specs=(P(), P(None, axis), P(None, axis)),
             out_specs=out_spec,
-            check_replication=False,  # pooled output is replicated via psum
+            check_vma=False,  # pooled output is replicated via psum
         )
         return f(params, ids, mask)
 

@@ -86,14 +86,11 @@ def serving_mesh() -> Mesh | None:
             return None
     avail = len(jax.devices())
     if n is not None and n > avail:
-        import warnings
-
-        warnings.warn(
-            f"PATHWAY_SERVING_MESH={n} > {avail} visible devices — "
-            f"serving over all {avail}",
-            stacklevel=2,
+        raise ValueError(
+            f"PATHWAY_SERVING_MESH={n} but only {avail} device(s) are "
+            "visible; serving on fewer shards than asked would silently "
+            "change per-device memory and latency"
         )
-        n = avail
     mesh = make_mesh(n)
     _serving_mesh_cache[raw] = mesh
     return mesh

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ._compat import shard_map
-
 __all__ = ["ring_attention", "ring_attention_sharded"]
 
 NEG_INF = -1e30
@@ -82,7 +80,7 @@ def _compiled_ring(mesh: Mesh, axis: str):
 
     @jax.jit
     def run(q, k, v, valid):
-        f = shard_map(
+        f = jax.shard_map(
             lambda q, k, v, m: ring_attention(q, k, v, m, axis),
             mesh=mesh,
             in_specs=(P(None, axis), P(None, axis), P(None, axis), P(None, axis)),

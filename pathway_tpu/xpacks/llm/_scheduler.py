@@ -652,7 +652,7 @@ def _batch_embed_device(embedder, texts: list[str]):
     callers fall back to the host path.  ``PATHWAY_FUSED_SERVING=0``
     disables the device handoff for A/B runs (the host path is the
     pre-PR8 behavior: embeddings round-trip D2H then re-stage H2D for
-    the search — one extra wire round trip per tick on a remote chip)."""
+    the search)."""
     if not _env_flag("PATHWAY_FUSED_SERVING", True):
         return None
     ensure = getattr(embedder, "_ensure_encoder", None)

@@ -1,18 +1,14 @@
 import os
 
 # All tests run on a virtual 8-device CPU mesh so multi-chip sharding paths
-# compile and execute without TPU hardware (the driver separately dry-runs
-# them; bench.py uses the real chip).
+# compile and execute without TPU hardware; chip_smoke.py is the run on the
+# real chip.
 os.environ["JAX_PLATFORMS"] = "cpu"
 xla_flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in xla_flags:
     os.environ["XLA_FLAGS"] = (
         xla_flags + " --xla_force_host_platform_device_count=8"
     ).strip()
-
-import jax
-
-jax.config.update("jax_platforms", "cpu")
 
 import hashlib
 

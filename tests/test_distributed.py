@@ -632,8 +632,6 @@ def test_peer_death_aborts_barrier_promptly():
 _INDEX_SERVE_PROG = r"""
 import json, os, sys
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-import jax
-jax.config.update("jax_platforms", "cpu")  # a TPU shim may prepend its platform
 import numpy as np
 import pathway_tpu as pw
 from pathway_tpu.stdlib.indexing import BruteForceKnnFactory, DataIndex

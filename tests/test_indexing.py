@@ -482,7 +482,7 @@ def test_sharded_index_compaction_keeps_shard_divisibility():
 
 def test_lsh_index_staged_adds_batched_and_readd_clean():
     """LshKnnIndex defers signature computation to one batched device call
-    per flush (a per-add round trip never finishes over a remote chip), and
+    per flush (not one dispatch per add), and
     re-adding a key must drop its stale bucket entries."""
     from pathway_tpu.stdlib.indexing.retrievers import LshKnnIndex
 

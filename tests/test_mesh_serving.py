@@ -265,6 +265,11 @@ def test_serving_mesh_env_knob(monkeypatch, corpus_dir):
     with pytest.warns(UserWarning):
         assert serving_mesh() is None
 
+    # more shards than devices is an error, never "serve on what there is"
+    monkeypatch.setenv("PATHWAY_SERVING_MESH", "16")
+    with pytest.raises(ValueError, match="only 8 device"):
+        serving_mesh()
+
 
 def _small_real_embedder(mesh=None):
     import jax.numpy as jnp
