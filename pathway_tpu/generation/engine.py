@@ -32,6 +32,7 @@ and block reuse after free).
 
 from __future__ import annotations
 
+import contextlib
 import queue
 import threading
 import time
@@ -973,22 +974,20 @@ class DecodeSession:
                 )
             need = self.pool.blocks_for(total) - len(seq.blocks)
             if need > 0:
-                t0 = time.monotonic()
                 more = None
-                try:
-                    if _faults.enabled:
-                        _faults.perturb("kv.alloc")
-                    more = self.pool.allocator.alloc(need)
-                except _faults.FaultInjected:
-                    # injected alloc fault (any severity): refuse the
-                    # extension — the retained sequence stays parked and
-                    # extendable, nothing was allocated
-                    more = None
-                self._record_span(
-                    "kv:alloc", t0,
-                    {"blocks": need, "ok": more is not None},
-                    seqs=(seq,),
-                )
+                with self._record_span(
+                    "kv:alloc", {"blocks": need}, seqs=(seq,)
+                ) as timed:
+                    try:
+                        if _faults.enabled:
+                            _faults.perturb("kv.alloc")
+                        more = self.pool.allocator.alloc(need)
+                    except _faults.FaultInjected:
+                        # injected alloc fault (any severity): refuse the
+                        # extension — the retained sequence stays parked
+                        # and extendable, nothing was allocated
+                        more = None
+                    timed.set(ok=more is not None)
                 if more is None:
                     self._retained[id(handle)] = seq
                     raise AdmissionRefused(
@@ -1054,19 +1053,20 @@ class DecodeSession:
             self._work.notify_all()
 
     # -- tick engine -----------------------------------------------------
+    @contextlib.contextmanager
     def _record_span(
         self,
         name: str,
-        t0: float,
         attrs: dict,
         seqs: "Sequence[_Seq]" = (),
         launch_kind: str | None = None,
-    ) -> None:
-        from ..internals.flight_recorder import new_span_id, record_span
+    ):
+        """Time the block as a ``generate`` span (ring, and the profiler's
+        host plane while a session is open); a launch that ends well also
+        feeds ``pathway_decode_launch_ms{kind=}``.  A contained failure
+        marks itself with ``timed.set(ok=False)``."""
+        from ..internals.flight_recorder import span
 
-        dur_ms = (time.monotonic() - t0) * 1000.0
-        if launch_kind is not None:
-            _observe_launch(launch_kind, dur_ms, int(attrs.get("rows", 1)))
         # sequences carry the (trace_id, span_id) of the request that
         # submitted them: a launch serving traced sequences is recorded
         # once per distinct triggering trace so the stitched fleet tree
@@ -1075,15 +1075,12 @@ class DecodeSession:
         for seq in seqs:
             if seq.trace_link is not None and seq.trace_link not in links:
                 links.append(seq.trace_link)
-        if links:
-            for tid, parent in links:
-                record_span(
-                    name, "generate", time.time(), dur_ms,
-                    trace_id=tid, span_id=new_span_id(), parent_id=parent,
-                    attrs=attrs,
-                )
-        else:
-            record_span(name, "generate", time.time(), dur_ms, attrs=attrs)
+        with span(name, "generate", links=links or None, **attrs) as timed:
+            yield timed
+        if launch_kind is not None and timed.attrs.get("ok", True):
+            _observe_launch(
+                launch_kind, timed.duration_ms, int(attrs.get("rows", 1))
+            )
 
     def _has_work_locked(self) -> bool:
         return bool(self._pending) or bool(self._live)
@@ -1160,24 +1157,23 @@ class DecodeSession:
             # spare (net: no discount) so the first divergent write can
             # always copy without allocating under pressure
             fresh_need = need - len(full)
-            t0 = time.monotonic()
             fresh = None
             fatal_exc: BaseException | None = None
-            try:
-                if _faults.enabled:
-                    _faults.perturb("kv.alloc")
-                fresh = alloc.alloc(fresh_need)
-            except _faults.FaultInjected as exc:
-                # transient alloc fault: the request simply stays queued
-                # for the next tick; a fatal one escalates to recovery
-                if classify_device_error(exc) == FATAL:
-                    fatal_exc = exc
-            self._record_span(
-                "kv:alloc", t0,
-                {"blocks": fresh_need, "matched": len(full),
-                 "ok": fresh is not None},
+            with self._record_span(
+                "kv:alloc", {"blocks": fresh_need, "matched": len(full)},
                 seqs=(seq,),
-            )
+            ) as timed:
+                try:
+                    if _faults.enabled:
+                        _faults.perturb("kv.alloc")
+                    fresh = alloc.alloc(fresh_need)
+                except _faults.FaultInjected as exc:
+                    # transient alloc fault: the request simply stays
+                    # queued for the next tick; a fatal one escalates to
+                    # recovery
+                    if classify_device_error(exc) == FATAL:
+                        fatal_exc = exc
+                timed.set(ok=fresh is not None)
             if fresh is None:
                 # roll the shares back; pool full — stays queued until
                 # retirements free blocks
@@ -1200,27 +1196,27 @@ class DecodeSession:
             # attend resident pool KV; the packed ragged prefill cannot)
             bs = self.pool.block_size
             matched_len = len(full) * bs + (partial[1] if partial else 0)
-            if partial is not None:
-                seq.blocks = full + [partial[0]] + fresh[1:]
-                seq.cow_spare = fresh[0]
-            else:
-                seq.blocks = full + fresh
-            seq.length = matched_len
-            seq.chain = chain
-            seq.registered_upto = len(full)
-            tail = seq.ids[matched_len:]
-            seq.next_input = tail[0]
-            seq.forced = deque(tail[1:])
-            seq.count = 0
             hit_blocks = len(full) + (1 if partial is not None else 0)
-            _bump("prefix_hit_blocks_total", hit_blocks)
-            _bump("prefix_hit_tokens_total", matched_len)
-            self._record_span(
-                "kv:prefix_match", t0,
+            with self._record_span(
+                "kv:prefix_match",
                 {"blocks": hit_blocks, "tokens": matched_len,
                  "partial": partial is not None},
                 seqs=(seq,),
-            )
+            ):
+                if partial is not None:
+                    seq.blocks = full + [partial[0]] + fresh[1:]
+                    seq.cow_spare = fresh[0]
+                else:
+                    seq.blocks = full + fresh
+                seq.length = matched_len
+                seq.chain = chain
+                seq.registered_upto = len(full)
+                tail = seq.ids[matched_len:]
+                seq.next_input = tail[0]
+                seq.forced = deque(tail[1:])
+                seq.count = 0
+                _bump("prefix_hit_blocks_total", hit_blocks)
+                _bump("prefix_hit_tokens_total", matched_len)
             self._live.append(seq)
             matched_any = True
         if not admitted:
@@ -1369,72 +1365,72 @@ class DecodeSession:
             )
 
     def _recover_locked(self, exc: BaseException) -> int:
-        from ..internals.errors import register_error
-
         self._recovering = True
-        t0 = time.monotonic()
         try:
-            old = self.pool
-            # quarantine: never touch the suspect arrays again — a fresh
-            # pool (arrays + allocator + prefix index) replaces them
-            # atomically, and the HBM ledger's bytes_fn reads self.pool
-            # through the session so the ledger follows the swap
-            self.pool = PagedKVPool(
-                self.cfg,
-                block_size=old.block_size,
-                pool_tokens=old.num_blocks * old.block_size,
-            )
-            old.quarantine()
-            _bump("kv_pool_rebuilds_total")
-            victims = list(self._live) + list(self._retained.values())
-            self._live = []
-            replayed = 0
-            # one victim at a time, ON PURPOSE: each replay prefill
-            # content-registers its blocks before the next victim's
-            # prefix match runs, so identical prefixes (the shared RAG
-            # template case) re-prefill once and are adopted by every
-            # later victim — the PrefixIndex makes replay cheap
-            for seq in victims:
-                # old-pool block refs are void wholesale (the allocator
-                # was quarantined with the arrays)
-                seq.blocks = []
-                seq.cow_spare = None
-                plan = self._resurrect_locked(seq, exc)
-                if plan is None:
-                    continue
-                replayed += 1
-                tag, head = plan
-                if tag == "prefill":
-                    try:
-                        self._prefill_batch_locked(
-                            [seq], tokens=[head], replay=True
-                        )
-                    except BaseException as exc2:  # noqa: BLE001
-                        # a replay prefill failing (even fatally) is
-                        # contained to its sequence — recovery NEVER
-                        # recurses into another recovery
-                        self._contain_launch_failure_locked(
-                            [seq], exc2, "replay_prefill"
-                        )
-                elif seq.handle is not None and not seq.handle.done:
-                    self._live.append(seq)
-            register_error(
-                f"decode pool quarantined after fatal device error "
-                f"({type(exc).__name__}: {exc}); rebuilt fresh and "
-                f"replayed {replayed} sequence(s)",
-                kind="serving",
-                operator=self.name,
-            )
-            self._record_span(
-                "kv:rebuild", t0,
-                {"replayed": replayed, "pending": len(self._pending)},
-            )
-            # queued admissions were never lost — wake the pump so they
-            # drain against the fresh pool
-            self._work.notify_all()
-            return replayed
+            with self._record_span("kv:rebuild", {}) as timed:
+                return self._rebuild_pool_locked(exc, timed)
         finally:
             self._recovering = False
+
+    def _rebuild_pool_locked(self, exc: BaseException, timed: Any) -> int:
+        from ..internals.errors import register_error
+
+        old = self.pool
+        # quarantine: never touch the suspect arrays again — a fresh
+        # pool (arrays + allocator + prefix index) replaces them
+        # atomically, and the HBM ledger's bytes_fn reads self.pool
+        # through the session so the ledger follows the swap
+        self.pool = PagedKVPool(
+            self.cfg,
+            block_size=old.block_size,
+            pool_tokens=old.num_blocks * old.block_size,
+        )
+        old.quarantine()
+        _bump("kv_pool_rebuilds_total")
+        victims = list(self._live) + list(self._retained.values())
+        self._live = []
+        replayed = 0
+        # one victim at a time, ON PURPOSE: each replay prefill
+        # content-registers its blocks before the next victim's
+        # prefix match runs, so identical prefixes (the shared RAG
+        # template case) re-prefill once and are adopted by every
+        # later victim — the PrefixIndex makes replay cheap
+        for seq in victims:
+            # old-pool block refs are void wholesale (the allocator
+            # was quarantined with the arrays)
+            seq.blocks = []
+            seq.cow_spare = None
+            plan = self._resurrect_locked(seq, exc)
+            if plan is None:
+                continue
+            replayed += 1
+            tag, head = plan
+            if tag == "prefill":
+                try:
+                    self._prefill_batch_locked(
+                        [seq], tokens=[head], replay=True
+                    )
+                except BaseException as exc2:  # noqa: BLE001
+                    # a replay prefill failing (even fatally) is
+                    # contained to its sequence — recovery NEVER
+                    # recurses into another recovery
+                    self._contain_launch_failure_locked(
+                        [seq], exc2, "replay_prefill"
+                    )
+            elif seq.handle is not None and not seq.handle.done:
+                self._live.append(seq)
+        register_error(
+            f"decode pool quarantined after fatal device error "
+            f"({type(exc).__name__}: {exc}); rebuilt fresh and "
+            f"replayed {replayed} sequence(s)",
+            kind="serving",
+            operator=self.name,
+        )
+        timed.set(replayed=replayed, pending=len(self._pending))
+        # queued admissions were never lost — wake the pump so they
+        # drain against the fresh pool
+        self._work.notify_all()
+        return replayed
 
     def _resurrect_locked(
         self, seq: _Seq, exc: BaseException
@@ -1566,36 +1562,36 @@ class DecodeSession:
             off += ln
             cu[j + 1] = off
         bounds = ragged_bounds(cu, T, ragged_block(T))
-        t0 = time.monotonic()
-        k_pool, v_pool, logits = self._launch_guarded_locked(
-            "device.prefill",
-            lambda: _prefill_jit()(
-                self.params, self.pool.k_pool, self.pool.v_pool,
-                jnp.asarray(ids), jnp.asarray(pos), jnp.asarray(seg),
-                jnp.asarray(starts), jnp.asarray(bounds),
-                jnp.asarray(dest_block), jnp.asarray(dest_slot),
-                jnp.asarray(last_idx),
-                cfg=self.cfg, num_rows=R, dense_s=dense_s, mode=self.mode,
-            ),
-        )
-        self.pool.k_pool, self.pool.v_pool = k_pool, v_pool
-        seeds = np.zeros(R, np.int32)
-        counts = np.zeros(R, np.int32)
-        temps = np.zeros(R, np.float32)
-        for j, seq in enumerate(batch):
-            seeds[j] = seq.seed
-            temps[j] = seq.temperature
-        first = np.asarray(
-            _sample_rows(
-                logits, jnp.asarray(seeds), jnp.asarray(counts),
-                jnp.asarray(temps),
-            )
-        )
-        self._record_span(
-            "prefill", t0,
+        with self._record_span(
+            "prefill",
             {"rows": len(batch), "tokens": t_real, "bucket": T},
             seqs=batch, launch_kind="prefill",
-        )
+        ):
+            k_pool, v_pool, logits = self._launch_guarded_locked(
+                "device.prefill",
+                lambda: _prefill_jit()(
+                    self.params, self.pool.k_pool, self.pool.v_pool,
+                    jnp.asarray(ids), jnp.asarray(pos), jnp.asarray(seg),
+                    jnp.asarray(starts), jnp.asarray(bounds),
+                    jnp.asarray(dest_block), jnp.asarray(dest_slot),
+                    jnp.asarray(last_idx),
+                    cfg=self.cfg, num_rows=R, dense_s=dense_s,
+                    mode=self.mode,
+                ),
+            )
+            self.pool.k_pool, self.pool.v_pool = k_pool, v_pool
+            seeds = np.zeros(R, np.int32)
+            counts = np.zeros(R, np.int32)
+            temps = np.zeros(R, np.float32)
+            for j, seq in enumerate(batch):
+                seeds[j] = seq.seed
+                temps[j] = seq.temperature
+            first = np.asarray(
+                _sample_rows(
+                    logits, jnp.asarray(seeds), jnp.asarray(counts),
+                    jnp.asarray(temps),
+                )
+            )
         _bump("prefill_tokens_total", t_real)
         for j, seq in enumerate(batch):
             seq.length = lens[j]
@@ -1739,33 +1735,35 @@ class DecodeSession:
             temps[r] = seq.temperature
         if not active.any():
             return False
-        t0 = time.monotonic()
-        try:
-            k_pool, v_pool, toks_next = self._launch_guarded_locked(
-                "device.decode_step",
-                lambda: _step_jit()(
-                    self.params, self.pool.k_pool, self.pool.v_pool,
-                    jnp.asarray(bt), jnp.asarray(lengths), jnp.asarray(toks),
-                    jnp.asarray(active), jnp.asarray(seeds),
-                    jnp.asarray(counts), jnp.asarray(temps),
-                    cfg=self.cfg, block_size=self.pool.block_size,
-                    mode=self.mode,
-                ),
-            )
-        except BaseException as exc:
-            if classify_device_error(exc) == FATAL:
-                raise  # tick-level handler quarantines + replays
-            self._contain_launch_failure_locked(
-                [p[0] for r, p in enumerate(plans) if active[r]],
-                exc, "decode_step",
-            )
-            return True
-        self.pool.k_pool, self.pool.v_pool = k_pool, v_pool
-        out = np.asarray(toks_next)  # host read = device sync (handler contract)
-        self._record_span(
-            "decode:step", t0, {"rows": len(plans), "bucket": R},
+        with self._record_span(
+            "decode:step", {"rows": len(plans), "bucket": R},
             seqs=[p[0] for p in plans], launch_kind="decode_step",
-        )
+        ) as timed:
+            try:
+                k_pool, v_pool, toks_next = self._launch_guarded_locked(
+                    "device.decode_step",
+                    lambda: _step_jit()(
+                        self.params, self.pool.k_pool, self.pool.v_pool,
+                        jnp.asarray(bt), jnp.asarray(lengths),
+                        jnp.asarray(toks), jnp.asarray(active),
+                        jnp.asarray(seeds), jnp.asarray(counts),
+                        jnp.asarray(temps),
+                        cfg=self.cfg, block_size=self.pool.block_size,
+                        mode=self.mode,
+                    ),
+                )
+            except BaseException as exc:
+                if classify_device_error(exc) == FATAL:
+                    raise  # tick-level handler quarantines + replays
+                timed.set(ok=False)
+                self._contain_launch_failure_locked(
+                    [p[0] for r, p in enumerate(plans) if active[r]],
+                    exc, "decode_step",
+                )
+                return True
+            self.pool.k_pool, self.pool.v_pool = k_pool, v_pool
+            # host read = device sync (handler contract)
+            out = np.asarray(toks_next)
         for r, (seq, _inputs, _nf, _nd) in enumerate(plans):
             if not active[r]:
                 continue
@@ -1805,35 +1803,35 @@ class DecodeSession:
             temps[r] = seq.temperature
         if not active.any():
             return False
-        t0 = time.monotonic()
-        try:
-            k_pool, v_pool, toks_out = self._launch_guarded_locked(
-                "device.verify",
-                lambda: _multi_jit()(
-                    self.params, self.pool.k_pool, self.pool.v_pool,
-                    jnp.asarray(bt), jnp.asarray(base), jnp.asarray(n_new),
-                    jnp.asarray(toks), jnp.asarray(active),
-                    jnp.asarray(seeds), jnp.asarray(counts),
-                    jnp.asarray(temps),
-                    cfg=self.cfg, block_size=self.pool.block_size,
-                    mode=self.mode,
-                ),
-            )
-        except BaseException as exc:
-            if classify_device_error(exc) == FATAL:
-                raise  # tick-level handler quarantines + replays
-            self._contain_launch_failure_locked(
-                [p[0] for r, p in enumerate(plans) if active[r]],
-                exc, "verify",
-            )
-            return True
-        self.pool.k_pool, self.pool.v_pool = k_pool, v_pool
-        out = np.asarray(toks_out)  # host read = device sync
-        self._record_span(
-            "decode:verify", t0,
+        with self._record_span(
+            "decode:verify",
             {"rows": len(plans), "bucket": R, "k": K},
             seqs=[p[0] for p in plans], launch_kind="verify",
-        )
+        ) as timed:
+            try:
+                k_pool, v_pool, toks_out = self._launch_guarded_locked(
+                    "device.verify",
+                    lambda: _multi_jit()(
+                        self.params, self.pool.k_pool, self.pool.v_pool,
+                        jnp.asarray(bt), jnp.asarray(base),
+                        jnp.asarray(n_new), jnp.asarray(toks),
+                        jnp.asarray(active), jnp.asarray(seeds),
+                        jnp.asarray(counts), jnp.asarray(temps),
+                        cfg=self.cfg, block_size=self.pool.block_size,
+                        mode=self.mode,
+                    ),
+                )
+            except BaseException as exc:
+                if classify_device_error(exc) == FATAL:
+                    raise  # tick-level handler quarantines + replays
+                timed.set(ok=False)
+                self._contain_launch_failure_locked(
+                    [p[0] for r, p in enumerate(plans) if active[r]],
+                    exc, "verify",
+                )
+                return True
+            self.pool.k_pool, self.pool.v_pool = k_pool, v_pool
+            out = np.asarray(toks_out)  # host read = device sync
         for r, (seq, inputs, nf, nd) in enumerate(plans):
             if not active[r]:
                 continue
@@ -1860,8 +1858,7 @@ class DecodeSession:
     def _ensure_pump_locked(self) -> None:
         if self._pump is None or not self._pump.is_alive():
             self._pump = threading.Thread(
-                target=self._pump_loop, daemon=True,
-                name=f"pw-{self.name}-pump",
+                target=self._pump_loop, daemon=True, name="pw-decode",
             )
             self._pump.start()
 
@@ -1875,8 +1872,10 @@ class DecodeSession:
         return get_runtime() if use else None
 
     def _pump_loop(self) -> None:
+        from ..internals.flight_recorder import name_thread
         from ..runtime import QoS, WorkGroup
 
+        name_thread("pw-decode")
         if self._group is None:
             self._group = WorkGroup(
                 f"{self.name}:tick",

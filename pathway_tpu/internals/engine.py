@@ -1618,29 +1618,20 @@ class Engine:
 
             set_current_local(logs)
         try:
-            import time as _time_mod
+            from .flight_recorder import span
 
-            from .flight_recorder import get_recorder
-
-            recorder = get_recorder()
-            if self.monitor is None and not recorder.enabled:
-                return node.flush(time)
-            wall0 = _time_mod.time()
-            t0 = _time_mod.perf_counter()
-            out = node.flush(time)
-            elapsed = _time_mod.perf_counter() - t0
-            if self.monitor is not None:
-                self.monitor.record_flush(node.name, len(out), elapsed)
             # the flight recorder sees every flush even when the stats
             # monitor is off (the default server path): a slow operator
             # window is dumpable from /v1/debug/traces with zero setup
-            recorder.record(
-                f"flush:{node.name}",
-                "engine",
-                wall0,
-                elapsed * 1000.0,
-                attrs={"rows": len(out), "t": time},
-            )
+            with span(
+                f"flush:{node.name}", "engine", stage="engine.flush", t=time
+            ) as timed:
+                out = node.flush(time)
+                timed.set(rows=len(out))
+            if self.monitor is not None:
+                self.monitor.record_flush(
+                    node.name, len(out), timed.duration_ms / 1000.0
+                )
             return out
         finally:
             if logs:

@@ -20,6 +20,13 @@ buffer of spans from every plane:
   now carrying a ``qos`` attr — filter ``/v1/debug/traces?category=``
   on either to see how interactive/ingest work interleaves).
 
+Timed blocks go through :class:`span`: one measurement lands in the ring,
+in ``pathway_request_stage_ms{stage=}`` and, while a ``jax.profiler``
+session is open, in the profiler's host plane as ``pw.<category>.<name>``
+on the profiler's own clock; :func:`name_thread` gives the program's
+threads the OS names that trace shows.  After-the-fact :func:`record_span`
+stays for spans a callback reports or that have no duration.
+
 ``GET /v1/debug/traces`` (every webserver) filters the ring by trace id /
 duration floor and the ``format=perfetto`` exporter dumps Chrome-tracing
 JSON — a slow window can be captured and opened in ``chrome://tracing`` /
@@ -35,7 +42,8 @@ stage spans, default 1.0 — the ring append is cheap enough to keep on),
 
 Import discipline: this module is engine-hot-path adjacent and is
 imported at module level by ``internals/engine.py`` — it must only import
-stdlib and the :mod:`metrics_names` leaf, never ``monitoring``/``run``.
+stdlib and the :mod:`metrics_names` leaf, never ``monitoring``/``run``,
+and never ``jax`` (:class:`span` finds it in ``sys.modules`` or does without).
 ``monitoring.py`` pulls :func:`observability_metrics_lines` lazily
 instead.
 """
@@ -46,6 +54,7 @@ import contextlib
 import os
 import random
 import re
+import sys
 import threading
 import time
 from collections import deque
@@ -71,6 +80,8 @@ __all__ = [
     "parse_traceparent",
     "format_traceparent",
     "record_span",
+    "span",
+    "name_thread",
     "observe_stage",
     "record_xla_compile",
     "instrument_jit",
@@ -438,6 +449,142 @@ def observe_stage(stage: str, duration_ms: float) -> None:
         hist.observe(duration_ms)
 
 
+# ---------------------------------------------------------------------------
+# the span primitive: ring + stage histogram + the profiler's host plane
+# ---------------------------------------------------------------------------
+
+_trace_annotation: Any = None
+
+
+def _annotation_class() -> Any:
+    """``jax.profiler.TraceAnnotation`` once ``jax`` is loaded, else None.
+    Looked up in ``sys.modules``: this module never imports ``jax``."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        # None while jax itself is still importing: ask again next time
+        _trace_annotation = getattr(profiler, "TraceAnnotation", None)
+    return _trace_annotation
+
+
+class span:
+    """Time a block once and report it everywhere a span is read:
+
+    * the flight-recorder ring, as ``name`` / ``category`` with ``attrs``
+      (once per ``(trace_id, parent_id)`` of ``links`` when given, so
+      deferred work hangs under the request that caused it);
+    * ``pathway_request_stage_ms{stage=}`` when ``stage`` is set;
+    * while a ``jax.profiler`` session is open, the profiler's
+      ``/host:CPU`` plane as ``pw.<category>.<name>`` with ``attrs`` as
+      event stats, on the profiler's own clock beside the device events.
+
+    ``set(**attrs)`` adds what is known only at the end (row counts); a
+    block that raises reads ``ok=False``; ``stage``, ``links`` and
+    ``record`` may be assigned inside the block.  ``record=False`` keeps
+    the span out of the ring for callers that file it themselves (request
+    stages ride their ``RequestTrace``).  After the block ``start_mono``
+    / ``end_mono`` / ``duration_ms`` hold the one measurement.  Keep
+    attrs to counts and short labels: they are built on the hot path."""
+
+    __slots__ = (
+        "name", "category", "stage", "links", "record", "attrs",
+        "start_s", "start_mono", "end_mono", "duration_ms", "_annotation",
+    )
+
+    def __init__(
+        self,
+        name: str,
+        category: str,
+        *,
+        stage: str | None = None,
+        links: list[tuple[str, str]] | None = None,
+        record: bool = True,
+        **attrs: Any,
+    ):
+        self.name = name
+        self.category = category
+        self.stage = stage
+        self.links = links
+        self.record = record
+        self.attrs = attrs
+        self._annotation = None
+
+    def set(self, **attrs: Any) -> None:
+        self.attrs.update(attrs)
+        if self._annotation is not None:
+            self._annotation.set_metadata(**attrs)
+
+    def __enter__(self) -> "span":
+        annotation = _trace_annotation or _annotation_class()
+        if annotation is not None and annotation.is_enabled():
+            self._annotation = annotation(
+                f"pw.{self.category}.{self.name}", **self.attrs
+            )
+            self._annotation.__enter__()
+        self.start_s = time.time()
+        self.start_mono = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        self.end_mono = time.monotonic()
+        self.duration_ms = (self.end_mono - self.start_mono) * 1000.0
+        if exc_type is not None:
+            self.set(ok=False)
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+            self._annotation = None
+        if self.record:
+            rec = get_recorder()
+            if rec.enabled:
+                attrs = self.attrs or None
+                if self.links:
+                    for trace_id, parent_id in self.links:
+                        rec.record(
+                            self.name, self.category, self.start_s,
+                            self.duration_ms, trace_id, new_span_id(),
+                            parent_id, attrs,
+                        )
+                else:
+                    rec.record(
+                        self.name, self.category, self.start_s,
+                        self.duration_ms, attrs=attrs,
+                    )
+        if self.stage is not None:
+            observe_stage(self.stage, self.duration_ms)
+
+
+_libc: Any = None
+
+
+def name_thread(name: str) -> None:
+    """Give the CALLING thread an OS-level name (15 bytes at most): what
+    ``top -H`` shows and what the profiler calls the thread's host line.
+    Python 3.12 never sets it, so every thread reads ``python3``.  Call it
+    first thing in a thread's body, before the thread's first span.
+    Linux only (``prctl(PR_SET_NAME)``); never on the main thread, where
+    the call would rename the process."""
+    global _libc
+    if (
+        not sys.platform.startswith("linux")
+        or threading.current_thread() is threading.main_thread()
+    ):
+        return
+    try:
+        if _libc is None:
+            import ctypes
+
+            libc = ctypes.CDLL(None)
+            libc.prctl.argtypes = [
+                ctypes.c_int, ctypes.c_char_p,
+                ctypes.c_ulong, ctypes.c_ulong, ctypes.c_ulong,
+            ]
+            libc.prctl.restype = ctypes.c_int
+            _libc = libc
+        _libc.prctl(15, name.encode()[:15], 0, 0, 0)  # 15 = PR_SET_NAME
+    except Exception:  # noqa: BLE001 - a label, never worth a failed thread
+        pass
+
+
 class RequestTrace:
     """Mutable per-request trace context.
 
@@ -494,14 +641,12 @@ class RequestTrace:
 
     @contextlib.contextmanager
     def stage(self, name: str) -> Iterator[None]:
-        if not self.sampled:
-            yield
-            return
-        t0 = time.monotonic()
+        timed = span(name, "request", record=False)
         try:
-            yield
+            with timed:
+                yield
         finally:
-            self.add_stage_mono(name, t0, time.monotonic())
+            self.add_stage_mono(name, timed.start_mono, timed.end_mono)
 
     def set_attr(self, key: str, value: Any) -> None:
         self.attrs[key] = value
@@ -624,18 +769,15 @@ def current_trace_link() -> tuple[str, str] | None:
 @contextlib.contextmanager
 def batch_stage(name: str) -> Iterator[None]:
     """Time a batch-internal stage (embed, search, ...) and stamp it onto
-    every trace in the current batch scope.  Free when untraced."""
-    traces = getattr(_tls, "traces", None)
-    if not traces:
-        yield
-        return
-    t0 = time.monotonic()
+    every trace in the current batch scope.  Untraced it still shows in
+    a profiler session (``pw.request.<name>``)."""
+    timed = span(name, "request", record=False)
     try:
-        yield
+        with timed:
+            yield
     finally:
-        t1 = time.monotonic()
-        for tr in traces:
-            tr.add_stage_mono(name, t0, t1)
+        for tr in getattr(_tls, "traces", None) or ():
+            tr.add_stage_mono(name, timed.start_mono, timed.end_mono)
 
 
 # ---------------------------------------------------------------------------

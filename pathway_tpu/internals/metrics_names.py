@@ -105,7 +105,10 @@ METRICS: dict[str, tuple[str, str]] = {
     # request tracing (internals/flight_recorder.py)
     "pathway_request_stage_ms": (
         "histogram",
-        "per-request stage latency (queue_wait / embed / search / serialize / total)",
+        "stage latency: request stages (queue_wait / embed / search / serialize / "
+        "total) and every flight_recorder.span(stage=...) of the ingest path "
+        "(connector.scan, engine.flush, index.*, tick.*, embed.*, "
+        "ingest.read_to_indexed)",
     ),
     "pathway_flight_recorder_spans_total": (
         "counter",

@@ -442,6 +442,15 @@ class FreshnessTracker:
         # burn-rate treatment (observability/slo.py) — lazy and fail-open:
         # freshness accounting must never take down an index flush
         if sources:
+            from .flight_recorder import observe_stage
+
+            for read_wall in sources.values():
+                # connector read -> queryable, per source: the part of a
+                # document's way the program sees from the inside
+                observe_stage(
+                    "ingest.read_to_indexed",
+                    max(0.0, now - read_wall) * 1000.0,
+                )
             try:
                 from ..observability import slo
 

@@ -126,28 +126,19 @@ class GraphRunner:
 
     # ---- public ----
     def build(self, output_requests: list[tuple[Any, OutputNode]]) -> Engine:
-        import time as _time_mod
-
         from .config import get_pathway_config
-        from .flight_recorder import record_span
+        from .flight_recorder import span
 
-        wall0 = _time_mod.time()
-        t0 = _time_mod.perf_counter()
-        self.engine.set_threads(get_pathway_config().threads)
-        ops = G.relevant_operators([t._operator for t, _ in output_requests])
-        for op in ops:
-            self._lower(op)
-        for table, out_node in output_requests:
-            self.engine.add(out_node)
-            self._node_of(table).downstream.append((out_node, 0))
-        self._feed_static_sources()
-        record_span(
-            "graph.lower",
-            "runtime",
-            wall0,
-            (_time_mod.perf_counter() - t0) * 1000.0,
-            attrs={"operators": len(ops), "nodes": len(self.engine.nodes)},
-        )
+        with span("graph.lower", "runtime") as timed:
+            self.engine.set_threads(get_pathway_config().threads)
+            ops = G.relevant_operators([t._operator for t, _ in output_requests])
+            for op in ops:
+                self._lower(op)
+            for table, out_node in output_requests:
+                self.engine.add(out_node)
+                self._node_of(table).downstream.append((out_node, 0))
+            self._feed_static_sources()
+            timed.set(operators=len(ops), nodes=len(self.engine.nodes))
         return self.engine
 
     def _feed_static_sources(self):

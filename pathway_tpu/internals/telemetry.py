@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import contextlib
 import sys
-import time
 from typing import Any, Iterator
 
 __all__ = ["Telemetry", "get_telemetry", "max_rss_bytes"]
@@ -48,9 +47,9 @@ class Telemetry:
         """``with telemetry.span("graph_runner.run"): ...`` — OTel span
         when a tracer is available, and ALWAYS a flight-recorder span
         (the zero-infra trace dump must show build/run windows too)."""
-        start_s = time.time()
-        t0 = time.monotonic()
-        try:
+        from .flight_recorder import span
+
+        with span(name, "runtime", **attributes):
             if self._tracer is None:
                 yield
                 return
@@ -61,16 +60,6 @@ class Telemetry:
                     except Exception:  # noqa: BLE001 — non-serializable attr
                         pass
                 yield
-        finally:
-            from .flight_recorder import record_span
-
-            record_span(
-                name,
-                "runtime",
-                start_s,
-                (time.monotonic() - t0) * 1000.0,
-                attrs=dict(attributes) if attributes else None,
-            )
 
     def sys_metrics(self) -> dict:
         """Process memory/CPU snapshot (reference telemetry.rs:350

@@ -158,7 +158,22 @@ class _FsSubject(ConnectorSubject):
         return keys
 
     def _scan_once(self) -> bool:
+        from ...internals.flight_recorder import span
+
+        with span("connector.scan", "connector", record=False) as timed:
+            changed, emitted = self._scan_and_emit()
+            timed.set(files=emitted)
+            if changed:
+                # an empty poll is no part of a document's way: it shows
+                # in a profiler session only, not in the ring or the stage
+                timed.record = True
+                timed.stage = "connector.scan"
+        return changed
+
+    def _scan_and_emit(self) -> tuple[bool, int]:
+        """One pass over the path: ``(anything changed, files emitted)``."""
         changed = False
+        emitted = 0
         current = {}
         for path in self._list_files():
             try:
@@ -182,7 +197,9 @@ class _FsSubject(ConnectorSubject):
             if self.append_only and self.fmt in (
                 "plaintext", "json", "jsonlines"
             ):
-                changed |= self._scan_append_mode(path, old, mtime, size)
+                if self._scan_append_mode(path, old, mtime, size):
+                    changed = True
+                    emitted += 1
                 continue
             if old is not None:
                 for key, values in old[2]:
@@ -193,9 +210,10 @@ class _FsSubject(ConnectorSubject):
                 continue
             self._seen[path] = (mtime, size, keys)
             changed = True
+            emitted += 1
         if changed:
             self.commit()
-        return changed
+        return changed, emitted
 
     # ---- append-only tailing (opt-in log mode) --------------------------
 
@@ -303,8 +321,13 @@ class _FsSubject(ConnectorSubject):
         self._scan_once()
         if self._mode == "static":
             return
+        from ...internals.flight_recorder import span
+
         while not self._closed.is_set():
-            _time.sleep(self.refresh_s)
+            # profiler only: "between two polls" is an answer an idle gap
+            # can get, and no news for the ring
+            with span("connector.sleep", "connector", record=False):
+                _time.sleep(self.refresh_s)
             self._scan_once()
 
 

@@ -117,7 +117,7 @@ class PathwayWebserver:
             if self._thread is not None:
                 return
             self._thread = threading.Thread(
-                target=self._serve, daemon=True, name="pw-webserver"
+                target=self._serve, daemon=True, name="pw-http"
             )
             self._thread.start()
         self._started.wait()
@@ -125,6 +125,9 @@ class PathwayWebserver:
     def _serve(self) -> None:
         from aiohttp import web
 
+        from ...internals.flight_recorder import name_thread
+
+        name_thread("pw-http")
         self._loop = asyncio.new_event_loop()
         asyncio.set_event_loop(self._loop)
 

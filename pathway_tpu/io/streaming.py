@@ -1130,8 +1130,10 @@ class StreamingDriver:
                     monitor.record_connector_finished(self._connector_label(subject))
 
     def _start_connector_threads(self, data_event=None) -> list:
+        from ..internals.flight_recorder import name_thread
+
         threads = []
-        for subject, _src in self.subject_src:
+        for n, (subject, _src) in enumerate(self.subject_src):
             if data_event is not None:
                 subject._data_event = data_event
             supervisor = ConnectorSupervisor(
@@ -1139,14 +1141,15 @@ class StreamingDriver:
             )
             self.supervisors[id(subject)] = supervisor
 
-            def runner(s=subject, sup=supervisor):
+            def runner(s=subject, sup=supervisor, name=f"pw-conn-{n}"):
+                name_thread(name)
                 try:
                     sup.run()
                 finally:
                     s.close()
                     s.on_stop()
 
-            th = threading.Thread(target=runner, daemon=True, name="pw-connector")
+            th = threading.Thread(target=runner, daemon=True, name=f"pw-conn-{n}")
             th.start()
             threads.append(th)
         return threads
@@ -1429,12 +1432,14 @@ class StreamingDriver:
         stop_ingest = threading.Event()
         ingest_error: list[BaseException] = []
 
+        from ..internals.flight_recorder import name_thread
         from ..internals.health import get_health
 
         health = get_health()
         health.set_component("ingest_thread", "running", ready=True)
 
         def ingest_loop() -> None:
+            name_thread("pw-ingest")
             try:
                 while not stop_ingest.is_set():
                     health.beat("ingest_thread")
@@ -1455,7 +1460,9 @@ class StreamingDriver:
                     detail=f"{type(exc).__name__}: {exc}",
                 )
 
-        ingest_thread = threading.Thread(target=ingest_loop, daemon=True)
+        ingest_thread = threading.Thread(
+            target=ingest_loop, daemon=True, name="pw-ingest"
+        )
         ingest_thread.start()
         try:
             while True:

@@ -23,6 +23,7 @@ from .encoder import (
     TransformerEncoder,
     bucketed_dispatch,
     default_attention_impl,
+    named_jit,
 )
 from .tokenizer import load_tokenizer
 
@@ -133,16 +134,20 @@ class CrossEncoder:
 
         record_attention_impl(self.cfg.attention_impl)
         self._apply = instrument_jit(
-            jax.jit(
+            named_jit(
                 lambda params, ids, mask, tids: self.model.apply(
                     {"params": params}, ids, mask, tids
-                )
+                ),
+                "pw_cross_encoder_forward",
             ),
             "cross_encoder.forward",
         )
         self._packed_model = _PackedScoredEncoder(self.cfg)
         self._apply_ragged = instrument_jit(
-            jax.jit(self._forward_ragged, static_argnames=("dense_s",)),
+            named_jit(
+                self._forward_ragged, "pw_cross_encoder_forward_ragged",
+                static_argnames=("dense_s",),
+            ),
             "cross_encoder.forward_ragged",
         )
 

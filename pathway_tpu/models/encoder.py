@@ -745,6 +745,35 @@ def _dispatch_prepared(apply_fn, prepared) -> list[tuple[Any, np.ndarray]]:
     return pending
 
 
+def named_jit(fn, name: str, **jit_kwargs):
+    """``jax.jit(fn)`` as a program called ``jit_<name>``: the device
+    trace names a program after its function, and ``jit__forward`` could
+    be any model's."""
+
+    @functools.wraps(fn)
+    def call(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    call.__name__ = call.__qualname__ = name
+    return jax.jit(call, **jit_kwargs)
+
+
+def _collect_rows(pending, n: int) -> np.ndarray:
+    """Host half after the launches: wait for each device result and put
+    its real rows in submission order (the host's wait for the device)."""
+    from ..internals.flight_recorder import span
+
+    with span("embed.d2h_wait", "encoder", stage="embed.d2h_wait", rows=n):
+        out: np.ndarray | None = None
+        for res, rows in pending:
+            res = np.asarray(res, dtype=np.float32)
+            if out is None:
+                out = np.empty((n,) + res.shape[1:], dtype=np.float32)
+            out[rows] = res[: len(rows)]
+    assert out is not None
+    return out
+
+
 def bucketed_dispatch(
     apply_fn, ids_all, mask_all, max_length: int, type_ids_all=None,
     vocab_size: int = 1 << 31, batch_multiple: int = 1,
@@ -765,7 +794,7 @@ def bucketed_dispatch(
     ``pathway_xla_compile_total`` — stays flat across mixed-length
     corpora.  ``max_tokens`` caps ``batch_bucket * seq_bucket`` per
     launch (token-budget batching, ``PATHWAY_EMBED_MAX_TOKENS``)."""
-    from ..internals.flight_recorder import record_padding
+    from ..internals.flight_recorder import record_padding, span
 
     if packed is None:
         packed = packed_dispatch_enabled()
@@ -778,16 +807,13 @@ def bucketed_dispatch(
         record_padding(
             stats["real_tokens"], stats["padded_tokens"], stats["row_tokens"]
         )
-        pending = _dispatch_prepared(apply_fn, prepared)
-        out: np.ndarray | None = None
-        n = ids_all.shape[0]
-        for res, rows in pending:
-            res = np.asarray(res, dtype=np.float32)
-            if out is None:
-                out = np.empty((n,) + res.shape[1:], dtype=np.float32)
-            out[rows] = res[: len(rows)]
-        assert out is not None
-        return out
+        # H2D and dispatch, until the last launch call returns
+        with span(
+            "embed.launch", "encoder", stage="embed.launch",
+            chunks=len(prepared),
+        ):
+            pending = _dispatch_prepared(apply_fn, prepared)
+        return _collect_rows(pending, ids_all.shape[0])
 
     # legacy whole-batch path: ONE seq bucket for the whole batch, sized
     # by its single longest row — kept for A/B measurement and parity
@@ -976,13 +1002,18 @@ class SentenceEncoder:
             self,
             _encoder_params_nbytes,
         )
-        self._apply = instrument_jit(jax.jit(self._forward), "encoder.forward")
+        self._apply = instrument_jit(
+            named_jit(self._forward, "pw_encoder_forward"), "encoder.forward"
+        )
         # packed ragged forward: same params, concatenated-token layout —
         # built unconditionally (construction is free until first trace)
         # so probes can A/B both layouts on one encoder
         self._packed_model = PackedTransformerEncoder(self.cfg)
         self._apply_ragged = instrument_jit(
-            jax.jit(self._forward_ragged, static_argnames=("dense_s",)),
+            named_jit(
+                self._forward_ragged, "pw_encoder_forward_ragged",
+                static_argnames=("dense_s",),
+            ),
             "encoder.forward_ragged",
         )
 
@@ -1014,9 +1045,7 @@ class SentenceEncoder:
         reference can only chunk such documents (splitters.py:34)."""
         if not texts:
             return np.zeros((0, self.dim), dtype=np.float32)
-        ids_all, mask_all = self.tokenizer.encode_batch(
-            list(texts), max_length=self.max_length
-        )
+        ids_all, mask_all = self._tokenize(texts)
 
         if self.mesh is not None and self.max_length > SEQ_BUCKETS[-1]:
             lengths = mask_all.sum(axis=1)
@@ -1033,6 +1062,17 @@ class SentenceEncoder:
                 return out
 
         return self._encode_bucketed(ids_all, mask_all)
+
+    def _tokenize(self, texts: Sequence[str]):
+        from ..internals.flight_recorder import span
+
+        with span(
+            "embed.tokenize", "encoder", stage="embed.tokenize",
+            rows=len(texts),
+        ):
+            return self.tokenizer.encode_batch(
+                list(texts), max_length=self.max_length
+            )
 
     def _input_sharding(self, batch: int):
         """Data-parallel placement rule for one launch: shard the batch
@@ -1132,7 +1172,7 @@ class SentenceEncoder:
     def _encode_ragged(self, ids_all, mask_all) -> np.ndarray:
         """Ragged dispatch: one launch per token-budget group (ONE for a
         whole serving tick), order-preserving collection."""
-        from ..internals.flight_recorder import record_padding
+        from ..internals.flight_recorder import record_padding, span
 
         prepared, stats = ragged_prepare(
             ids_all, mask_all, self.max_length,
@@ -1141,19 +1181,15 @@ class SentenceEncoder:
         record_padding(
             stats["real_tokens"], stats["padded_tokens"], stats["row_tokens"]
         )
-        pending = [
-            (self.encode_prepared(payload), rows)
-            for payload, rows, _tokens in prepared
-        ]
-        out: np.ndarray | None = None
-        n = ids_all.shape[0]
-        for res, rows in pending:
-            res = np.asarray(res, dtype=np.float32)
-            if out is None:
-                out = np.empty((n,) + res.shape[1:], dtype=np.float32)
-            out[rows] = res[: len(rows)]
-        assert out is not None
-        return out
+        with span(
+            "embed.launch", "encoder", stage="embed.launch",
+            chunks=len(prepared),
+        ):
+            pending = [
+                (self.encode_prepared(payload), rows)
+                for payload, rows, _tokens in prepared
+            ]
+        return _collect_rows(pending, ids_all.shape[0])
 
     def encode_padded(self, texts: Sequence[str]) -> tuple[Any, int]:
         """Fused-serving embed half: ONE whole-batch launch whose DEVICE
@@ -1174,9 +1210,7 @@ class SentenceEncoder:
         n = len(texts)
         if n == 0 or n > BATCH_BUCKETS[-1]:
             raise ValueError(f"batch of {n} outside the dispatch buckets")
-        ids_all, mask_all = self.tokenizer.encode_batch(
-            list(texts), max_length=self.max_length
-        )
+        ids_all, mask_all = self._tokenize(texts)
         if self.cfg.attention_impl == "ragged":
             return self._encode_padded_ragged(ids_all, mask_all, n)
         longest = int(mask_all.sum(axis=1).max())

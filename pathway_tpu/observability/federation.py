@@ -57,7 +57,7 @@ def federation_enabled() -> bool:
 # ---------------------------------------------------------------------------
 
 #: span name -> (plane, description).  The ``generate`` plane entries are
-#: lint-pinned against the engine's ``_record_span`` call sites (tests
+#: lint-pinned against the engine's ``_record_span`` blocks (tests
 #: assert set equality in BOTH directions, the fault-site registry
 #: idiom): a new launch guard must document itself here, and a stale
 #: entry must not outlive its guard.
@@ -65,7 +65,7 @@ KNOWN_SPAN_KINDS: dict[str, tuple[str, str]] = {
     # generation launch guards (generation/engine.py)
     "kv:alloc": ("generate", "paged KV block allocation for one sequence"),
     "kv:prefix_match": (
-        "generate", "copy-on-write prefix lookup in the paged pool"
+        "generate", "adoption of copy-on-write prefix blocks matched in the paged pool"
     ),
     "kv:rebuild": (
         "generate", "KV-pool resurrection by replay re-prefill"

@@ -162,7 +162,13 @@ def _capture_jax(root: str, tag: str, ms: float) -> tuple[str, str]:
     import jax
 
     trace_dir = os.path.join(root, tag)
-    jax.profiler.start_trace(trace_dir)
+    # the Python tracer makes a live server many times slower; the
+    # program's own spans (flight_recorder.span) are in the host plane
+    # without it
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
     try:
         time.sleep(ms / 1000.0)
     finally:

@@ -61,9 +61,7 @@ import contextlib
 import functools
 import os
 import threading
-import time
 import warnings
-from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -222,12 +220,10 @@ class _Tick:
     """Per-serving-tick launch ledger (thread-local; nested ticks fold
     into the outermost one)."""
 
-    __slots__ = ("counts", "_t0_wall", "_t0_mono")
+    __slots__ = ("counts",)
 
     def __init__(self) -> None:
         self.counts: dict[str, int] = {}
-        self._t0_wall = time.time()
-        self._t0_mono = time.monotonic()
 
     @property
     def total(self) -> int:
@@ -296,25 +292,25 @@ def serving_tick():
     if outer is not None:
         yield outer
         return
+    from ..internals.flight_recorder import span
+
     tick = _Tick()
     _tls.tick = tick
-    try:
-        yield tick
-    finally:
-        _tls.tick = None
-        if tick.counts and launch_accounting_enabled():
-            from ..internals.flight_recorder import record_span
-
-            attrs: dict[str, Any] = {"launches": tick.total}
-            for stage, n in sorted(tick.counts.items()):
-                attrs[f"launches.{stage}"] = n
-            record_span(
-                "serving.tick",
-                "serve",
-                tick._t0_wall,
-                (time.monotonic() - tick._t0_mono) * 1000.0,
-                attrs=attrs,
-            )
+    with span("serving.tick", "serve") as timed:
+        try:
+            yield tick
+        finally:
+            _tls.tick = None
+            if tick.counts and launch_accounting_enabled():
+                timed.set(
+                    launches=tick.total,
+                    **{
+                        f"launches.{stage}": n
+                        for stage, n in sorted(tick.counts.items())
+                    },
+                )
+            else:  # a tick that launched nothing is no news for the ring
+                timed.record = False
 
 
 def launch_totals() -> dict[str, int]:

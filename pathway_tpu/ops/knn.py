@@ -700,6 +700,17 @@ class DeviceKnnIndex:
         ):
             self._maybe_compact()
             return
+        from ..internals.flight_recorder import span
+
+        with span(
+            "index.scatter", "index", stage="index.scatter",
+            rows=len(self._staged_set) + len(self._staged_coded),
+            device_batches=len(self._staged_device),
+        ):
+            self._scatter_staged()
+        self._maybe_compact()
+
+    def _scatter_staged(self) -> None:
         from ..testing import faults
 
         if faults.enabled:
@@ -763,7 +774,6 @@ class DeviceKnnIndex:
             )
         self._staged_set.clear()
         self._staged_valid.clear()
-        self._maybe_compact()
 
     def export_records(self, keys: Sequence[Hashable]) -> dict:
         """Snapshot records for ``keys`` holding the EXACT resident

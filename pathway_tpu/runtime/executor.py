@@ -533,7 +533,7 @@ class DeviceTickRuntime:
     def _ensure_thread(self) -> None:
         if self._thread is None or not self._thread.is_alive():
             self._thread = threading.Thread(
-                target=self._loop, daemon=True, name=f"pw-{self.name}-tick"
+                target=self._loop, daemon=True, name="pw-tick"
             )
             self._thread.start()
 
@@ -560,19 +560,31 @@ class DeviceTickRuntime:
         return window
 
     def _loop(self) -> None:
+        from ..internals.flight_recorder import name_thread, span
+
+        name_thread("pw-tick")
         while True:
             with self._cv:
-                while self._pending_locked() == 0:
-                    self._cv.wait()
+                if self._pending_locked() == 0:
+                    # "waiting for work" is one of the answers an idle
+                    # device gap can get
+                    with span("tick.idle", "runtime", stage="tick.idle"):
+                        while self._pending_locked() == 0:
+                            self._cv.wait()
                 # admission window: from the first pending item, wait for
                 # concurrent requests to join the tick, flushing early on
                 # max_batch / a full token budget
                 flush_at = time.monotonic() + self._window_s_locked()
-                while not self._should_flush_locked():
-                    remaining = flush_at - time.monotonic()
-                    if remaining <= 0:
-                        break
-                    self._cv.wait(timeout=remaining)
+                if (
+                    flush_at > time.monotonic()
+                    and not self._should_flush_locked()
+                ):
+                    with span("tick.admit", "runtime", stage="tick.admit"):
+                        while not self._should_flush_locked():
+                            remaining = flush_at - time.monotonic()
+                            if remaining <= 0:
+                                break
+                            self._cv.wait(timeout=remaining)
                 items, tick_stats = self._compose_tick_locked()
             if not items:
                 continue
@@ -654,9 +666,15 @@ class DeviceTickRuntime:
         }
 
     def _run_tick(self, items: list[WorkItem], tick_stats: dict) -> None:
+        from ..internals.flight_recorder import span
+
+        with span("tick:runtime", "runtime", stage="tick.run") as timed:
+            self._run_tick_timed(items, tick_stats, timed)
+
+    def _run_tick_timed(
+        self, items: list[WorkItem], tick_stats: dict, timed: Any
+    ) -> None:
         now = time.monotonic()
-        tick_wall = time.time()
-        tick_t0 = time.monotonic()
         live_groups: dict[int, tuple[Any, list[WorkItem]]] = {}
         live_tokens = 0
         for it in items:  # already in priority+submission order
@@ -714,23 +732,11 @@ class DeviceTickRuntime:
         for group, gitems in live_groups.values():
             for chunk in budget_chunks(group, gitems):
                 self._execute(group, chunk, chunk[0].qos)
-        from ..internals.flight_recorder import record_span
-
-        record_span(
-            "tick:runtime",
-            "runtime",
-            tick_wall,
-            (time.monotonic() - tick_t0) * 1000.0,
-            attrs={
-                "occupancy": len(items),
-                "tokens": live_tokens,
-                "preempted": preempted,
-                **{
-                    c.label: per_class[c][0]
-                    for c in QoS
-                    if per_class[c][0]
-                },
-            },
+        timed.set(
+            occupancy=len(items),
+            tokens=live_tokens,
+            preempted=preempted,
+            **{c.label: per_class[c][0] for c in QoS if per_class[c][0]},
         )
 
     def _execute(
@@ -742,7 +748,7 @@ class DeviceTickRuntime:
     ) -> None:
         if not chunk:
             return
-        from ..internals.flight_recorder import batch_traces, record_span
+        from ..internals.flight_recorder import batch_traces, span
 
         obs = chunk[0].observer
         if obs is not None:
@@ -751,33 +757,58 @@ class DeviceTickRuntime:
         # model off-thread while the loop runs
         lock = getattr(group, "_dispatch_lock", None)
         traces = [it.trace for it in chunk if it.trace is not None]
-        tick_wall = time.time()
-        tick_t0 = time.monotonic()
+        # deferred items carry the (trace_id, span_id) of the request
+        # that caused them: the tick span is recorded once per distinct
+        # triggering trace so the stitched tree shows the background
+        # work under its requester, and once unlinked otherwise
+        links: list[tuple[str, str]] = []
+        for it in chunk:
+            if it.trace_link is not None and it.trace_link not in links:
+                links.append(it.trace_link)
+        timed = span(
+            f"tick:{group.label}",
+            "scheduler",
+            stage=f"tick.execute.{qos.label}",
+            links=links or None,
+            runtime=self.name,
+            qos=qos.label,
+            occupancy=len(chunk),
+            ok=True,
+        )
+        if inline:
+            timed.set(inline=True)
+        if links:
+            timed.set(deferred=True)
         prev_qos = self._tick_qos
         self._tick_qos = qos
-        ok = True
         try:
-            from ..testing import faults
+            with timed:  # a raising body reads ok=False
+                from ..testing import faults
 
-            if faults.enabled:
-                # chaos site "scheduler.step": a failed device step fans
-                # out to the batch's waiters like any handler error
-                faults.perturb("scheduler.step")
-            # batch-scope the riding traces: the handler's stage timers
-            # (embed, search) stamp onto every request in the tick
-            with batch_traces(traces):
-                if lock is not None:
-                    with lock:
-                        results = group.batch_fn([it.payload for it in chunk])
-                else:
-                    results = group.batch_fn([it.payload for it in chunk])
-            if len(results) != len(chunk):
-                raise RuntimeError(
-                    f"batch handler {group.label!r} returned {len(results)} "
-                    f"results for {len(chunk)} items"
-                )
+                if faults.enabled:
+                    # chaos site "scheduler.step": a failed device step
+                    # fans out to the batch's waiters like any handler
+                    # error
+                    faults.perturb("scheduler.step")
+                # batch-scope the riding traces: the handler's stage
+                # timers (embed, search) stamp onto every request in
+                # the tick
+                with batch_traces(traces):
+                    if lock is not None:
+                        with lock:
+                            results = group.batch_fn(
+                                [it.payload for it in chunk]
+                            )
+                    else:
+                        results = group.batch_fn(
+                            [it.payload for it in chunk]
+                        )
+                if len(results) != len(chunk):
+                    raise RuntimeError(
+                        f"batch handler {group.label!r} returned "
+                        f"{len(results)} results for {len(chunk)} items"
+                    )
         except BaseException as exc:  # noqa: BLE001 — propagate to every waiter
-            ok = False
             with self._mx:
                 self._class_counters[qos]["failed_total"] += len(chunk)
             if obs is not None:
@@ -788,45 +819,6 @@ class DeviceTickRuntime:
             return
         finally:
             self._tick_qos = prev_qos
-            attrs = {
-                "runtime": self.name,
-                "qos": qos.label,
-                "occupancy": len(chunk),
-                "ok": ok,
-            }
-            if inline:
-                attrs["inline"] = True
-            dur_ms = (time.monotonic() - tick_t0) * 1000.0
-            # deferred items carry the (trace_id, span_id) of the request
-            # that caused them: record the tick span once per distinct
-            # triggering trace so the stitched tree shows the background
-            # work under its requester, and once unlinked otherwise
-            links: list[tuple[str, str]] = []
-            for it in chunk:
-                if it.trace_link is not None and it.trace_link not in links:
-                    links.append(it.trace_link)
-            if links:
-                from ..internals.flight_recorder import new_span_id
-
-                for tid, parent in links:
-                    record_span(
-                        f"tick:{group.label}",
-                        "scheduler",
-                        tick_wall,
-                        dur_ms,
-                        trace_id=tid,
-                        span_id=new_span_id(),
-                        parent_id=parent,
-                        attrs={**attrs, "deferred": True},
-                    )
-            else:
-                record_span(
-                    f"tick:{group.label}",
-                    "scheduler",
-                    tick_wall,
-                    dur_ms,
-                    attrs=attrs,
-                )
         with self._mx:
             self._class_counters[qos]["completed_total"] += len(chunk)
         if obs is not None:

@@ -125,31 +125,19 @@ class ExternalIndexNode(Node):
         # flush instead of one per document
         last: dict[Any, tuple | None] = {}
         payloads: dict[Any, tuple] = {}
-        for key, row, diff in self.take(0):
+        updates = self.take(0)
+        if updates:
             index_changed = True
-            ctx = (key, row)
-            data = self.doc_data_fn(ctx)
-            meta = self.doc_meta_fn(ctx)
-            if data is ERROR or meta is ERROR:
-                # a document whose embedding/metadata errored (failed UDF
-                # under terminate_on_error=False) must not poison the
-                # index: skip it both ways (its retraction computes the
-                # same ERROR and is skipped symmetrically) and log once
-                if diff > 0:
-                    from ...internals.errors import register_error
+            from ...internals.flight_recorder import span
 
-                    register_error(
-                        "document with ERROR embedding/metadata excluded "
-                        "from index",
-                        kind="index",
-                        operator=self.name,
-                    )
-                continue
-            if diff > 0:
-                last[key] = (data, meta)
-                payloads[key] = self.doc_payload_fn(ctx)
-            else:
-                last[key] = None
+            # the index data expression is evaluated here, row by row: for
+            # a vector index that is the embedder, so each document waits
+            # for a device tick of its own inside this span
+            with span(
+                "index.doc_data", "index", stage="index.doc_data",
+                rows=len(updates),
+            ):
+                self._collect_updates(updates, last, payloads)
         add_keys = [k for k, v in last.items() if v is not None]
         # the corpus visible to queries changes only when something real
         # applies: an upsert, or a remove of a key actually present.
@@ -260,6 +248,34 @@ class ExternalIndexNode(Node):
                         slot[1] = new_row
         return consolidate(out)
 
+    def _collect_updates(self, updates, last: dict, payloads: dict) -> None:
+        """Evaluate index data, metadata and payload of each update; within
+        one timestamp a key's FINAL entry decides its state."""
+        for key, row, diff in updates:
+            ctx = (key, row)
+            data = self.doc_data_fn(ctx)
+            meta = self.doc_meta_fn(ctx)
+            if data is ERROR or meta is ERROR:
+                # a document whose embedding/metadata errored (failed UDF
+                # under terminate_on_error=False) must not poison the
+                # index: skip it both ways (its retraction computes the
+                # same ERROR and is skipped symmetrically) and log once
+                if diff > 0:
+                    from ...internals.errors import register_error
+
+                    register_error(
+                        "document with ERROR embedding/metadata excluded "
+                        "from index",
+                        kind="index",
+                        operator=self.name,
+                    )
+                continue
+            if diff > 0:
+                last[key] = (data, meta)
+                payloads[key] = self.doc_payload_fn(ctx)
+            else:
+                last[key] = None
+
     # -- serving-cache freshness watermark -------------------------------
     def bump_commit_seq(self) -> None:
         """Advance the per-index commit sequence (see the attribute doc:
@@ -287,25 +303,31 @@ class ExternalIndexNode(Node):
 
     # -- index-update application + device-fault containment ------------
     def _apply_index_updates(self, last, payloads, add_keys) -> None:
-        for key, action in last.items():
-            if action is None:
-                self.index.remove(key)
-                self.doc_payload.pop(key, None)
-        if add_keys:
-            if hasattr(self.index, "add_batch"):
-                self.index.add_batch(
-                    add_keys,
-                    [last[k][0] for k in add_keys],
-                    [last[k][1] for k in add_keys],
-                )
-            else:  # duck-typed custom index without the batched protocol
-                for key in add_keys:
-                    self.index.add(key, last[key][0], last[key][1])
-            for key in add_keys:
-                self.doc_payload[key] = payloads[key]
-            from ...internals.flight_recorder import record_ingest_docs
+        if not last:
+            return
+        from ...internals.flight_recorder import record_ingest_docs, span
 
-            record_ingest_docs(len(add_keys))
+        with span(
+            "index.apply", "index", stage="index.apply",
+            added=len(add_keys), removed=len(last) - len(add_keys),
+        ):
+            for key, action in last.items():
+                if action is None:
+                    self.index.remove(key)
+                    self.doc_payload.pop(key, None)
+            if add_keys:
+                if hasattr(self.index, "add_batch"):
+                    self.index.add_batch(
+                        add_keys,
+                        [last[k][0] for k in add_keys],
+                        [last[k][1] for k in add_keys],
+                    )
+                else:  # duck-typed custom index without the batched protocol
+                    for key in add_keys:
+                        self.index.add(key, last[key][0], last[key][1])
+                for key in add_keys:
+                    self.doc_payload[key] = payloads[key]
+                record_ingest_docs(len(add_keys))
 
     def _contain_device_fault(self, exc: BaseException) -> bool:
         """Containment for device errors raised by index mutation/search:
@@ -346,27 +368,20 @@ class ExternalIndexNode(Node):
         host mirror first, snapshot vectors as the fallback (the
         ``_place()`` rebuild hook re-pins sharded matrices to the mesh).
         Returns True when a rebuild happened."""
-        import time as _time
-
-        from ...internals.flight_recorder import record_span
+        from ...internals.flight_recorder import span
 
         inner = getattr(self.index, "index", None)
         if inner is None or not hasattr(inner, "rebuild_device_arrays"):
             return False
-        wall = _time.time()
-        t0 = _time.monotonic()
-        ok = inner.rebuild_device_arrays()
-        source = "host_mirror"
-        if not ok:
-            vectors = self._snapshot_vectors()
-            if vectors:
-                ok = inner.rebuild_device_arrays(vectors)
-                source = "snapshot"
-        record_span(
-            f"rebuild:{self.name}", "restore", wall,
-            (_time.monotonic() - t0) * 1000.0,
-            attrs={"ok": ok, "source": source, "index": self.name},
-        )
+        with span(f"rebuild:{self.name}", "restore", index=self.name) as timed:
+            ok = inner.rebuild_device_arrays()
+            source = "host_mirror"
+            if not ok:
+                vectors = self._snapshot_vectors()
+                if vectors:
+                    ok = inner.rebuild_device_arrays(vectors)
+                    source = "snapshot"
+            timed.set(ok=ok, source=source)
         return ok
 
     def _snapshot_vectors(self) -> dict | None:

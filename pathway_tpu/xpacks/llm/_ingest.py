@@ -44,7 +44,6 @@ from __future__ import annotations
 import os
 import queue
 import threading
-import time
 from concurrent.futures import Future
 from typing import Any, Sequence
 
@@ -132,11 +131,11 @@ class IngestPipeline:
         if self._tok_thread is None:
             self._tok_thread = threading.Thread(
                 target=self._tokenize_loop, daemon=True,
-                name="pw-ingest-tokenize",
+                name="pw-ingest-tok",
             )
             self._dev_thread = threading.Thread(
                 target=self._device_loop, daemon=True,
-                name="pw-ingest-device",
+                name="pw-ingest-dev",
             )
             self._tok_thread.start()
             self._dev_thread.start()
@@ -224,25 +223,20 @@ class IngestPipeline:
         )
 
     def _tokenize_loop(self) -> None:
-        from ...internals.flight_recorder import record_span
+        from ...internals.flight_recorder import name_thread, span
 
+        name_thread("pw-ingest-tok")
         enc = self.encoder
         while True:
             item = self._in.get()
             if item is _SENTINEL:
                 self._ready.put(_SENTINEL)
                 return
-            wall = time.time()
-            t0 = time.monotonic()
             try:
-                ids_all, mask_all = enc.tokenizer.encode_batch(
-                    item.texts, max_length=enc.max_length
-                )
-                record_span(
-                    "tokenize", "ingest", wall,
-                    (time.monotonic() - t0) * 1000.0,
-                    attrs={"docs": len(item.texts)},
-                )
+                with span("tokenize", "ingest", docs=len(item.texts)):
+                    ids_all, mask_all = enc.tokenizer.encode_batch(
+                        item.texts, max_length=enc.max_length
+                    )
                 item.prepared, item.stats = self._prepare(ids_all, mask_all)
             except BaseException as exc:  # noqa: BLE001 — fail THIS batch only
                 if not item.future.done():
@@ -273,62 +267,50 @@ class IngestPipeline:
         return [out]
 
     def _encode_chunk(self, payload) -> Any:
-        from ...internals.flight_recorder import record_span
+        from ...internals.flight_recorder import span
 
         enc = self.encoder
         encode_prepared = getattr(enc, "encode_prepared", None)
-        wall = time.time()
-        t0 = time.monotonic()
         if encode_prepared is not None:
             # the encoder's own device half: packed (bb, seq) launch or
             # ONE ragged concatenated-token launch, H2D + mesh placement
             # included (attention_impl-aware)
-            out = encode_prepared(payload)
-            record_span(
-                "encode", "ingest", wall,
-                (time.monotonic() - t0) * 1000.0,
-                attrs={"tokens": int(np.asarray(payload[0]).size)
-                       if isinstance(payload, tuple)
-                       else int(np.asarray(payload.ids).size)},
+            tokens = int(
+                np.asarray(payload[0]).size if isinstance(payload, tuple)
+                else np.asarray(payload.ids).size
             )
-            return out
+            with span("encode", "ingest", tokens=tokens):
+                return encode_prepared(payload)
         import jax.numpy as jnp
 
         ids, mask, tids = payload
-        args = [jnp.asarray(ids), jnp.asarray(mask)]
-        if tids is not None:
-            args.append(jnp.asarray(tids))
-        if getattr(enc, "mesh", None) is not None:
-            import jax
+        with span("h2d", "ingest", chunks=1):
+            args = [jnp.asarray(ids), jnp.asarray(mask)]
+            if tids is not None:
+                args.append(jnp.asarray(tids))
+            if getattr(enc, "mesh", None) is not None:
+                import jax
 
-            # the encoder's own data-parallel rule: shard chunks that
-            # divide the data axis, replicate small tails
-            rule = getattr(enc, "_input_sharding", None)
-            sharding = (
-                rule(args[0].shape[0]) if rule is not None
-                else enc._data_sharding
-            )
-            args = [jax.device_put(a, sharding) for a in args]
-        record_span(
-            "h2d", "ingest", wall, (time.monotonic() - t0) * 1000.0,
-            attrs={"chunks": 1},
-        )
-        wall = time.time()
-        t0 = time.monotonic()
-        out = enc._apply(enc.params, *args)
-        record_span(
-            "encode", "ingest", wall, (time.monotonic() - t0) * 1000.0,
-            attrs={"rows": int(np.asarray(ids).shape[0])},
-        )
-        return out
+                # the encoder's own data-parallel rule: shard chunks that
+                # divide the data axis, replicate small tails
+                rule = getattr(enc, "_input_sharding", None)
+                sharding = (
+                    rule(args[0].shape[0]) if rule is not None
+                    else enc._data_sharding
+                )
+                args = [jax.device_put(a, sharding) for a in args]
+        with span("encode", "ingest", rows=int(np.asarray(ids).shape[0])):
+            return enc._apply(enc.params, *args)
 
     def _device_loop(self) -> None:
         from ...internals.flight_recorder import (
+            name_thread,
             record_ingest_docs,
             record_padding,
-            record_span,
+            span,
         )
 
+        name_thread("pw-ingest-dev")
         while True:
             item = self._ready.get()
             if item is _SENTINEL:
@@ -378,24 +360,18 @@ class IngestPipeline:
                         for payload, rows, _tokens in item.prepared
                     ]
                 if self.index is not None:
-                    wall = time.time()
-                    t0 = time.monotonic()
-                    for out, rows in outs:
-                        keys = [item.keys[i] for i in rows]
-                        metas = (
-                            [item.metas[i] for i in rows]
-                            if item.metas is not None
-                            else [None] * len(rows)
-                        )
-                        if hasattr(self.index, "add_batch"):
-                            self.index.add_batch(keys, out, metas)
-                        else:
-                            self.index.upsert_batch(keys, out)
-                    record_span(
-                        "upsert", "ingest", wall,
-                        (time.monotonic() - t0) * 1000.0,
-                        attrs={"docs": len(item.texts)},
-                    )
+                    with span("upsert", "ingest", docs=len(item.texts)):
+                        for out, rows in outs:
+                            keys = [item.keys[i] for i in rows]
+                            metas = (
+                                [item.metas[i] for i in rows]
+                                if item.metas is not None
+                                else [None] * len(rows)
+                            )
+                            if hasattr(self.index, "add_batch"):
+                                self.index.add_batch(keys, out, metas)
+                            else:
+                                self.index.upsert_batch(keys, out)
                     record_ingest_docs(len(item.texts))
                     result: Any = len(item.texts)
                 else:

@@ -325,11 +325,14 @@ class ServingScheduler:
     def _ensure_thread(self) -> None:
         if self._thread is None or not self._thread.is_alive():
             self._thread = threading.Thread(
-                target=self._loop, daemon=True, name=f"pw-scheduler-{self.name}"
+                target=self._loop, daemon=True, name="pw-sched"
             )
             self._thread.start()
 
     def _loop(self) -> None:
+        from ...internals.flight_recorder import name_thread
+
+        name_thread("pw-sched")
         while True:
             with self._cv:
                 while not self._queue:
@@ -385,7 +388,7 @@ class ServingScheduler:
     def _execute(self, group: WorkGroup, chunk: list[_WorkItem]) -> None:
         if not chunk:
             return
-        from ...internals.flight_recorder import batch_traces, record_span
+        from ...internals.flight_recorder import batch_traces, span
 
         with self._mx:
             self._counters["batches_total"] += 1
@@ -398,49 +401,44 @@ class ServingScheduler:
         # model off-thread while the loop runs
         lock = getattr(group, "_dispatch_lock", None)
         traces = [it.trace for it in chunk if it.trace is not None]
-        tick_wall = time.time()
-        tick_t0 = time.monotonic()
-        ok = True
+        timed = span(
+            f"tick:{group.label}", "scheduler",
+            scheduler=self.name, occupancy=len(chunk), ok=True,
+        )
         try:
-            from ...testing import faults
+            with timed:  # a raising body reads ok=False
+                from ...testing import faults
 
-            if faults.enabled:
-                # chaos site "scheduler.step": a failed device step fans
-                # out to the batch's waiters like any handler error
-                faults.perturb("scheduler.step")
-            # batch-scope the riding traces: the handler's stage timers
-            # (embed, search) stamp onto every request in the tick
-            with batch_traces(traces):
-                if lock is not None:
-                    with lock:
-                        results = group.batch_fn([it.payload for it in chunk])
-                else:
-                    results = group.batch_fn([it.payload for it in chunk])
-            if len(results) != len(chunk):
-                raise RuntimeError(
-                    f"batch handler {group.label!r} returned {len(results)} "
-                    f"results for {len(chunk)} items"
-                )
+                if faults.enabled:
+                    # chaos site "scheduler.step": a failed device step
+                    # fans out to the batch's waiters like any handler
+                    # error
+                    faults.perturb("scheduler.step")
+                # batch-scope the riding traces: the handler's stage
+                # timers (embed, search) stamp onto every request in
+                # the tick
+                with batch_traces(traces):
+                    if lock is not None:
+                        with lock:
+                            results = group.batch_fn(
+                                [it.payload for it in chunk]
+                            )
+                    else:
+                        results = group.batch_fn(
+                            [it.payload for it in chunk]
+                        )
+                if len(results) != len(chunk):
+                    raise RuntimeError(
+                        f"batch handler {group.label!r} returned "
+                        f"{len(results)} results for {len(chunk)} items"
+                    )
         except BaseException as exc:  # noqa: BLE001 — propagate to every waiter
-            ok = False
             with self._mx:
                 self._counters["failed_total"] += len(chunk)
             for it in chunk:
                 if not it.future.done():
                     it.future.set_exception(exc)
             return
-        finally:
-            record_span(
-                f"tick:{group.label}",
-                "scheduler",
-                tick_wall,
-                (time.monotonic() - tick_t0) * 1000.0,
-                attrs={
-                    "scheduler": self.name,
-                    "occupancy": len(chunk),
-                    "ok": ok,
-                },
-            )
         with self._mx:
             self._counters["completed_total"] += len(chunk)
         for it, res in zip(chunk, results):

@@ -248,6 +248,7 @@ def run_with_cache(
     An explicit ``persistence_config`` (durable serving: the recovery
     plane under ``PersistenceMode.OPERATOR_PERSISTING``) takes precedence
     over the default in-memory UDF cache."""
+    from ...internals.flight_recorder import name_thread
     from ...internals.run import run
 
     if persistence_config is None and with_cache:
@@ -257,13 +258,15 @@ def run_with_cache(
         persistence_config = Config(backend, persistence_mode="UDF_CACHING")
 
     def target():
+        # the engine's thread when threaded (a no-op on the main thread)
+        name_thread("pw-engine")
         run(
             persistence_config=persistence_config,
             terminate_on_error=terminate_on_error,
         )
 
     if threaded:
-        th = threading.Thread(target=target, daemon=True, name="pw-server")
+        th = threading.Thread(target=target, daemon=True, name="pw-engine")
         th.start()
         return th
     target()

@@ -1220,3 +1220,201 @@ def test_openapi_schema_advertises_slo_knobs(corpus_dir):
     knobs = next(iter(retrieve.values()))["x-pathway-slo-knobs"]
     assert "PATHWAY_SLO_RETRIEVE_P99_MS" in knobs
     assert "PATHWAY_SLO_RETRIEVE_AVAIL" in knobs
+
+
+# ---------------------------------------------------------------------------
+# the span primitive (ISSUE 26): ring + stage histogram + profiler host plane
+# ---------------------------------------------------------------------------
+
+
+def _stage_counts() -> dict[str, float]:
+    """``{stage: count}`` from the exposition, as the benchmark reads it."""
+    out = {}
+    for line in fr.observability_metrics_lines():
+        m = re.match(r'pathway_request_stage_ms_count\{stage="([^"]+)"\} (\S+)', line)
+        if m:
+            out[m.group(1)] = float(m.group(2))
+    return out
+
+
+def _profiled(body, tmp_path):
+    """Run ``body`` inside a CPU profiler session with the harness's
+    options and return the planes as ``perfbench/trace_reduce.load`` reads
+    them."""
+    import os
+    import sys
+
+    import jax
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(__file__)), "perfbench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import trace_reduce
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    return trace_reduce.load(trace_reduce.find_xplane(str(tmp_path)))
+
+
+def _inside(child, parent) -> bool:
+    return parent[1] <= child[1] and child[1] + child[2] <= parent[1] + parent[2]
+
+
+def test_span_writes_ring_stage_and_profiler_event(tmp_path):
+    import threading
+
+    fr.reset_recorder()
+    fr.reset_stage_metrics()
+
+    def body():
+        fr.name_thread("pw-span-test")
+        with fr.span("unit.work", "unit", stage="unit.stage", rows=3) as timed:
+            time.sleep(0.002)
+            timed.set(done=True)
+        with pytest.raises(ValueError):
+            with fr.span("unit.fails", "unit"):
+                raise ValueError("boom")
+
+    def run():
+        th = threading.Thread(target=body)
+        th.start()
+        th.join(timeout=30)
+        assert not th.is_alive()
+
+    planes = _profiled(run, tmp_path)
+    work, fails = fr.get_recorder().spans(category="unit")
+    assert (work.name, work.attrs) == ("unit.work", {"rows": 3, "done": True})
+    assert work.duration_ms >= 2.0 and abs(time.time() - work.start_s) < 60.0
+    assert (fails.name, fails.attrs) == ("unit.fails", {"ok": False})
+    assert _stage_counts() == {"unit.stage": 1.0}
+    names = [name for name, _s, _d in planes["host"]["pw-span-test"]]
+    assert names == ["pw.unit.unit.work", "pw.unit.unit.fails"]
+
+
+def test_span_never_imports_jax():
+    """The recorder stays engine-hot-path adjacent: a process that has not
+    loaded ``jax`` gets ring and stage and no import."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from pathway_tpu.internals import flight_recorder as fr\n"
+        "with fr.span('a', 'b', stage='s', n=1):\n"
+        "    pass\n"
+        "fr.name_thread('pw-main')  # a no-op on the main thread\n"
+        "assert [s.name for s in fr.get_recorder().spans()] == ['a']\n"
+        "assert 'jax' not in sys.modules, 'span imported jax'\n"
+        "print(open('/proc/self/comm').read().strip())\n"
+    )
+    out = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True, timeout=120
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.strip() != "pw-main", "name_thread renamed the process"
+
+
+def test_nested_spans_on_named_threads_reach_the_profiler_trace(tmp_path):
+    """Two threads, each named, each with a child span inside a parent:
+    the trace holds a host line per thread under its own name, on the
+    profiler's clock, with the attrs' names intact."""
+    import threading
+
+    def worker(name):
+        fr.name_thread(name)
+        for _ in range(3):
+            with fr.span("parent", "t", who=name):
+                time.sleep(0.002)
+                with fr.span("child", "t"):
+                    time.sleep(0.002)
+
+    def body():
+        threads = [
+            threading.Thread(target=worker, args=(n,))
+            for n in ("pw-test-a", "pw-test-b")
+        ]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=30)
+            assert not th.is_alive()
+
+    planes = _profiled(body, tmp_path)
+    for name in ("pw-test-a", "pw-test-b"):
+        events = planes["host"][name]
+        parents = [e for e in events if e[0] == "pw.t.parent"]
+        children = [e for e in events if e[0] == "pw.t.child"]
+        assert len(parents) == len(children) == 3
+        for child in children:
+            assert sum(_inside(child, p) for p in parents) == 1
+        assert all(d >= 2_000_000 for _n, _s, d in children)  # nanoseconds
+
+
+def test_runtime_tick_spans_account_for_the_tick():
+    """One tick on a stub group: the loop's waits and the tick's parts are
+    spans with stages, and the execute span lies inside ``tick:runtime``."""
+    from pathway_tpu.runtime import DeviceTickRuntime, QoS, WorkGroup
+
+    fr.reset_recorder()
+    fr.reset_stage_metrics()
+    rt = DeviceTickRuntime(tick_tokens=100, max_wait_ms=20, name="t-spans")
+
+    def work(xs):
+        time.sleep(0.005)
+        return xs
+
+    group = WorkGroup("stub", work, max_batch=8)
+    rt.submit(group, 0, qos=QoS.LLM_RERANK).result(timeout=30)
+    time.sleep(0.05)  # the loop is back in its idle wait
+    rt.submit(group, 1, qos=QoS.LLM_RERANK).result(timeout=30)
+
+    def done():
+        return _stage_counts().get("tick.run", 0) >= 2
+
+    _wait(done)
+    stages = _stage_counts()
+    assert stages["tick.idle"] >= 1 and stages["tick.admit"] >= 2
+    assert stages["tick.run"] == 2 and stages["tick.execute.llm_rerank"] == 2
+    rec = fr.get_recorder()
+    ticks = [s for s in rec.spans(category="runtime") if s.name == "tick:runtime"]
+    executes = [s for s in rec.spans(category="scheduler") if s.name == "tick:stub"]
+    assert len(ticks) == len(executes) == 2
+    for tick, execute in zip(ticks, executes):
+        assert tick.attrs["occupancy"] == 1 and tick.attrs["llm_rerank"] == 1
+        assert execute.attrs == {
+            "runtime": "t-spans", "qos": "llm_rerank", "occupancy": 1, "ok": True,
+        }
+        assert tick.start_s <= execute.start_s + 1e-4
+        assert execute.duration_ms <= tick.duration_ms + 0.1
+        assert execute.duration_ms >= 5.0
+    waits = {s.name for s in rec.spans(category="runtime")} - {"tick:runtime"}
+    assert waits == {"tick.idle", "tick.admit"}
+
+
+def test_debug_profile_capture_keeps_the_python_tracer_off(tmp_path, monkeypatch):
+    """``/v1/debug/profile`` on a live server takes the harness's options:
+    host tracer on, Python tracer off (it made the host 25 times slower)."""
+    import jax
+
+    from pathway_tpu.observability import profiler
+
+    seen = {}
+
+    def start_trace(logdir, profiler_options=None, **_kw):
+        seen["python"] = profiler_options.python_tracer_level
+        seen["host"] = profiler_options.host_tracer_level
+        import os
+
+        os.makedirs(logdir, exist_ok=True)
+
+    monkeypatch.setattr(jax.profiler, "start_trace", start_trace)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    artifact, kind = profiler._capture_jax(str(tmp_path), "t", 1.0)
+    assert kind == "jax" and artifact.endswith(".zip")
+    assert seen == {"python": 0, "host": 2}
