@@ -4,9 +4,9 @@ Compiles ``native.cpp`` with g++ on first import (cached as a .so next to
 the source, keyed by a hash of the source and the compiler flags) and
 binds it via ctypes.  The build targets the baseline ISA, not the
 building host's: the .so is git-ignored but travels with a copied tree,
-so it must load on a machine with a different CPU.  The call site
-(models/tokenizer.py) has a pure-Python fallback, ~130x slower, which
-warns when it engages.
+so it must load on a machine with a different CPU.  The call sites
+(models/tokenizer.py, io/fs) have pure-Python fallbacks (the tokenizer's
+~130x slower) which warn when they engage.
 """
 
 from __future__ import annotations
@@ -16,16 +16,22 @@ import hashlib
 import os
 import subprocess
 import tempfile
+from typing import Iterator
 
 import numpy as np
 
-__all__ = ["hash_bytes", "tokenize_batch", "lib", "ABI_VERSION"]
+__all__ = [
+    "hash_bytes", "tokenize_batch", "walk_dir", "read_files", "lib",
+    "ABI_VERSION",
+]
 
-ABI_VERSION = 1
+ABI_VERSION = 2
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "native.cpp")
-_CXXFLAGS = ("-O3", "-shared", "-fPIC", "-std=c++17")
+# no fused multiply-add: pw_fs_walk's st_mtime has to be the very float
+# os.stat computes (sec + 1e-9 * nsec, rounded twice)
+_CXXFLAGS = ("-O3", "-shared", "-fPIC", "-std=c++17", "-ffp-contract=off")
 
 
 def _build() -> str:
@@ -85,6 +91,41 @@ lib.pw_tokenize_batch.argtypes = [
 ]
 
 
+class _FsWalk(ctypes.Structure):
+    _fields_ = [
+        ("n", ctypes.c_int64), ("entries", ctypes.c_int64),
+        ("paths_len", ctypes.c_int64), ("paths", ctypes.c_void_p),
+        ("mtimes", ctypes.c_void_p), ("sizes", ctypes.c_void_p),
+    ]
+
+
+class _FsRead(ctypes.Structure):
+    _fields_ = [
+        ("n_read", ctypes.c_int64), ("data_len", ctypes.c_int64),
+        ("data", ctypes.c_void_p), ("ends", ctypes.c_void_p),
+        ("errs", ctypes.c_void_p),
+    ]
+
+
+lib.pw_fs_walk.argtypes = [ctypes.c_char_p, ctypes.c_char_p]
+lib.pw_fs_walk.restype = ctypes.POINTER(_FsWalk)
+lib.pw_fs_walk_free.argtypes = [ctypes.POINTER(_FsWalk)]
+lib.pw_fs_walk_free.restype = None
+lib.pw_fs_read.argtypes = [ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64]
+lib.pw_fs_read.restype = ctypes.POINTER(_FsRead)
+lib.pw_fs_read_free.argtypes = [ctypes.POINTER(_FsRead)]
+lib.pw_fs_read_free.restype = None
+
+
+def _list_at(dtype: str, address: int | None, n: int) -> list:
+    """``n`` numbers at ``address`` as Python floats or ints."""
+    if not n:
+        return []
+    return np.frombuffer(
+        ctypes.string_at(address, n * np.dtype(dtype).itemsize), dtype
+    ).tolist()
+
+
 def hash_bytes(data: bytes) -> int:
     """128-bit BLAKE2b of ``data`` as an int (little-endian), identical to
     ``int.from_bytes(hashlib.blake2b(data, digest_size=16).digest(),
@@ -124,3 +165,61 @@ def tokenize_batch(
         mask.ctypes.data_as(ctypes.c_void_p),
     )
     return ids, mask
+
+
+def walk_dir(root: bytes, pattern: bytes) -> tuple[bytes, list[float], list[int], int]:
+    """The regular files under the directory ``root`` (ending with ``/``)
+    whose base name matches ``pattern`` (``*`` and ``?`` only), as
+    ``glob.glob(root + b"**/" + pattern, recursive=True)`` finds them:
+    ``(paths joined by NUL in sorted order, st_mtime of each, st_size of
+    each, directory entries read)``; the last is -1 when ``root`` cannot
+    be listed (it is a single file, or not there).  One call without the
+    interpreter lock; see native.cpp for what exactly it mirrors."""
+    res = lib.pw_fs_walk(root, pattern)
+    if not res:
+        raise MemoryError("pw_fs_walk")
+    try:
+        w = res.contents
+        return (
+            ctypes.string_at(w.paths, w.paths_len) if w.paths_len else b"",
+            _list_at("f8", w.mtimes, w.n),
+            _list_at("i8", w.sizes, w.n),
+            w.entries,
+        )
+    finally:
+        lib.pw_fs_walk_free(res)
+
+
+#: bytes one ``pw_fs_read`` call may hold before it hands back
+READ_BUDGET = 64 << 20
+
+
+def read_files(paths: list[bytes]) -> Iterator[bytes | OSError]:
+    """The bytes of each file in turn, or the ``OSError`` that kept them:
+    what ``open(path, "rb").read()`` gives.  The files are read ahead
+    without the interpreter lock, ``READ_BUDGET`` bytes a call, so the
+    calls grow with the bytes read and not with the files."""
+    done = 0
+    while done < len(paths):
+        rest = paths[done:] if done else paths
+        res = lib.pw_fs_read(b"\0".join(rest) + b"\0", len(rest), READ_BUDGET)
+        if not res:
+            raise MemoryError("pw_fs_read")
+        try:
+            r = res.contents
+            ends = _list_at("i8", r.ends, r.n_read)
+            errs = _list_at("i4", r.errs, r.n_read)
+            # a slice of the array is a copy: the only one Python makes
+            data = (ctypes.c_char * r.data_len).from_address(r.data or 0) if r.data_len else b""
+            chunk: list[bytes | OSError] = []
+            start = 0
+            for path, end, err in zip(rest, ends, errs):
+                chunk.append(
+                    OSError(err, os.strerror(err), os.fsdecode(path))
+                    if err else data[start:end]
+                )
+                start = end
+        finally:
+            lib.pw_fs_read_free(res)
+        done += len(chunk)
+        yield from chunk

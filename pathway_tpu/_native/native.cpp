@@ -6,12 +6,29 @@
 // the hashing tokenizer's batch encode (models/tokenizer.py), which
 // dominates host time in the embedding ingest path.
 //
+// And the watched-directory pass of io/fs (pw_fs_walk, pw_fs_read): one poll
+// walks the tree under a directory, matches base names against a `*`/`?`
+// pattern the way glob.glob("<dir>/**/<pattern>", recursive=True) does,
+// stats every regular file and reads the files the caller names, all with
+// the interpreter lock released.  The Python lister of io/fs is its
+// fallback, and is also what io/fs takes when the path is a single file or
+// a glob, or the pattern holds a path separator or a bracket expression.
+//
 // Built by pathway_tpu/_native/__init__.py with g++ -O3 -shared -fPIC;
 // every exported function has a pure-Python fallback with identical
 // semantics, so the library is an accelerator, never a requirement.
 
+#include <dirent.h>
+#include <fcntl.h>
+#include <sys/stat.h>
+#include <unistd.h>
+
+#include <algorithm>
+#include <cerrno>
 #include <cstdint>
 #include <cstring>
+#include <string>
+#include <vector>
 
 // ---------------------------------------------------------------------------
 // BLAKE2b (RFC 7693), fixed 16-byte digest, no key — matches
@@ -209,7 +226,268 @@ extern "C" void pw_tokenize_batch(
 }
 
 // ---------------------------------------------------------------------------
+// Watched-directory pass (io/fs).  Mirrors, for a directory `root` and a
+// base-name pattern without `/` or `[`:
+//   sorted(f for f in glob.glob(root + "/**/" + pattern, recursive=True)
+//          if os.path.isfile(f))  +  os.stat(f) of each
+// `**` enters every directory whose name does not start with `.`, symlinked
+// ones too; a pattern with `*` or `?` skips names that start with `.` unless
+// it starts with `.` itself, and a pattern without either is compared as it
+// is; a name is kept when stat (links followed) says regular file.  A
+// directory is opened by its whole path, as os.scandir opens it, so a loop of
+// symlinks ends where the kernel ends it for Python (ELOOP, ENAMETOOLONG).
+// ---------------------------------------------------------------------------
+
+namespace {
+
+// Bytes of the UTF-8 sequence at p: what a Python str holds as one
+// character.  An undecodable byte counts as one, as os.fsdecode's
+// surrogateescape makes it.
+inline size_t unit_len(const uint8_t* p, size_t n) {
+  uint8_t c = p[0];
+  if (c < 0x80) return 1;
+  size_t more;
+  uint8_t lo = 0x80, hi = 0xBF;
+  if (c >= 0xC2 && c <= 0xDF) {
+    more = 1;
+  } else if (c >= 0xE0 && c <= 0xEF) {
+    more = 2;
+    if (c == 0xE0) lo = 0xA0;
+    if (c == 0xED) hi = 0x9F;
+  } else if (c >= 0xF0 && c <= 0xF4) {
+    more = 3;
+    if (c == 0xF0) lo = 0x90;
+    if (c == 0xF4) hi = 0x8F;
+  } else {
+    return 1;
+  }
+  if (n <= more || p[1] < lo || p[1] > hi) return 1;
+  for (size_t i = 2; i <= more; i++)
+    if ((p[i] & 0xC0) != 0x80) return 1;
+  return more + 1;
+}
+
+// fnmatch.fnmatchcase for a pattern without `[`: `*` any run of characters,
+// `?` one character, anything else itself; the whole name has to match.
+bool name_matches(const uint8_t* pat, size_t np, const uint8_t* s, size_t ns) {
+  size_t pi = 0, si = 0, star_pi = SIZE_MAX, star_si = 0;
+  while (si < ns) {
+    if (pi < np && pat[pi] == '*') {
+      star_pi = ++pi;
+      star_si = si;
+      continue;
+    }
+    size_t sl = unit_len(s + si, ns - si);
+    if (pi < np) {
+      if (pat[pi] == '?') {
+        pi++;
+        si += sl;
+        continue;
+      }
+      size_t pl = unit_len(pat + pi, np - pi);
+      if (pl == sl && std::memcmp(pat + pi, s + si, sl) == 0) {
+        pi += pl;
+        si += sl;
+        continue;
+      }
+    }
+    if (star_pi == SIZE_MAX) return false;
+    star_si += unit_len(s + star_si, ns - star_si);  // the star takes one more
+    si = star_si;
+    pi = star_pi;
+  }
+  while (pi < np && pat[pi] == '*') pi++;
+  return pi == np;
+}
+
+struct FsFile {
+  std::string path;
+  double mtime;
+  int64_t size;
+};
+
+struct FsPattern {
+  const uint8_t* bytes;
+  size_t len;
+  bool magic;   // holds `*` or `?`: matched, and hidden names skipped
+  bool hidden;  // starts with `.`: hidden names are not skipped
+};
+
+// false when `dir` (which ends with '/') cannot be listed
+bool walk_dir(const std::string& dir, const FsPattern& pat,
+              std::vector<FsFile>* files, int64_t* entries) {
+  DIR* d = opendir(dir.c_str());
+  if (d == nullptr) return false;
+  const int fd = dirfd(d);
+  std::vector<std::string> subdirs;
+  while (struct dirent* e = readdir(d)) {
+    const char* name = e->d_name;
+    if (name[0] == '.' && (name[1] == 0 || (name[1] == '.' && name[2] == 0)))
+      continue;
+    ++*entries;
+    const size_t len = std::strlen(name);
+    const bool hidden = name[0] == '.';
+    const bool wanted =
+        pat.magic ? ((!hidden || pat.hidden) &&
+                     name_matches(pat.bytes, pat.len, (const uint8_t*)name, len))
+                  : (len == pat.len && std::memcmp(name, pat.bytes, len) == 0);
+    const unsigned char type = e->d_type;
+    // a wanted name is stat'ed for its time and size; any other only where
+    // the directory entry does not say whether `**` enters it
+    const bool ask = wanted ? type != DT_DIR
+                            : (!hidden && (type == DT_LNK || type == DT_UNKNOWN));
+    struct stat st;
+    const bool known = ask && fstatat(fd, name, &st, 0) == 0;
+    if (type == DT_DIR || (known && S_ISDIR(st.st_mode))) {
+      if (!hidden) subdirs.emplace_back(name, len);  // `**` enters no hidden one
+    } else if (wanted && known && S_ISREG(st.st_mode)) {
+      // the float os.stat gives: st_mtime = sec + 1e-9 * nsec
+      files->push_back({dir + name,
+                        (double)st.st_mtim.tv_sec + 1e-9 * (double)st.st_mtim.tv_nsec,
+                        (int64_t)st.st_size});
+    }
+  }
+  closedir(d);  // before the children: one descriptor however deep the tree
+  for (const std::string& sub : subdirs)
+    walk_dir(dir + sub + "/", pat, files, entries);  // as glob: unlistable is empty
+  return true;
+}
+
+}  // namespace
+
+// What the caller sees of a walk: `n` regular files sorted by path (byte
+// order, which is str order for UTF-8), their paths joined by NUL, and per
+// file st_mtime and st_size; `entries` counts the directory entries read and
+// is -1 when `root` itself cannot be listed (a single file, or nothing).
+struct PwFsWalk {
+  int64_t n;
+  int64_t entries;
+  int64_t paths_len;
+  const char* paths;
+  const double* mtimes;
+  const int64_t* sizes;
+};
+
+namespace {
+struct FsWalkOwner {
+  PwFsWalk view;  // first: the pointer handed out is the owner's
+  std::string paths;
+  std::vector<double> mtimes;
+  std::vector<int64_t> sizes;
+};
+}  // namespace
+
+// `root` ends with '/'.  Returns nullptr only when memory ran out.
+extern "C" PwFsWalk* pw_fs_walk(const char* root, const char* pattern) {
+  try {
+    FsPattern pat{(const uint8_t*)pattern, std::strlen(pattern), false,
+                  pattern[0] == '.'};
+    pat.magic = std::strpbrk(pattern, "*?") != nullptr;
+    std::vector<FsFile> files;
+    int64_t entries = 0;
+    if (!walk_dir(root, pat, &files, &entries)) entries = -1;
+    std::sort(files.begin(), files.end(),
+              [](const FsFile& a, const FsFile& b) { return a.path < b.path; });
+    auto* out = new FsWalkOwner();
+    out->mtimes.reserve(files.size());
+    out->sizes.reserve(files.size());
+    for (const FsFile& f : files) {
+      if (!out->paths.empty()) out->paths.push_back('\0');
+      out->paths += f.path;
+      out->mtimes.push_back(f.mtime);
+      out->sizes.push_back(f.size);
+    }
+    out->view = {(int64_t)files.size(), entries, (int64_t)out->paths.size(),
+                 out->paths.data(), out->mtimes.data(), out->sizes.data()};
+    return &out->view;
+  } catch (...) {
+    return nullptr;
+  }
+}
+
+extern "C" void pw_fs_walk_free(PwFsWalk* walk) {
+  delete reinterpret_cast<FsWalkOwner*>(walk);
+}
+
+// The bytes of the first `n_read` of `n` files (paths joined by NUL), one
+// after the other: file i ends at `ends[i]` and failed with `errs[i]` (an
+// errno, 0 for none; a failed file holds no bytes).  Reading stops after the
+// file that brings the total to `budget` bytes, so that a first poll of a
+// large directory is not held in memory twice.
+struct PwFsRead {
+  int64_t n_read;
+  int64_t data_len;
+  const uint8_t* data;
+  const int64_t* ends;
+  const int32_t* errs;
+};
+
+namespace {
+struct FsReadOwner {
+  PwFsRead view;
+  std::vector<uint8_t> data;
+  std::vector<int64_t> ends;
+  std::vector<int32_t> errs;
+};
+
+struct Fd {
+  int fd;
+  ~Fd() {
+    if (fd >= 0) close(fd);
+  }
+};
+
+// Appends the file's bytes to `data`; an errno (and no bytes) if it failed.
+int read_whole(const char* path, std::vector<uint8_t>* data) {
+  const Fd f{open(path, O_RDONLY | O_CLOEXEC)};
+  if (f.fd < 0) return errno;
+  const size_t start = data->size();
+  struct stat st;
+  size_t room = (fstat(f.fd, &st) == 0 && st.st_size > 0) ? (size_t)st.st_size + 1 : 4096;
+  for (size_t at = start;;) {
+    data->resize(at + room);
+    const ssize_t got = read(f.fd, data->data() + at, room);
+    if (got < 0 && errno == EINTR) continue;
+    if (got < 0) {
+      const int err = errno;
+      data->resize(start);
+      return err;
+    }
+    at += (size_t)got;
+    if (got == 0) {
+      data->resize(at);
+      return 0;
+    }
+    // a short read is most likely the end; a full one outgrew what fstat said
+    room = (size_t)got < room ? 4096 : std::max<size_t>(4096, at - start);
+  }
+}
+}  // namespace
+
+extern "C" PwFsRead* pw_fs_read(const char* paths, int64_t n, int64_t budget) {
+  try {
+    auto* out = new FsReadOwner();
+    const char* path = paths;
+    for (int64_t i = 0; i < n; i++) {
+      out->errs.push_back(read_whole(path, &out->data));
+      out->ends.push_back((int64_t)out->data.size());
+      path += std::strlen(path) + 1;
+      if ((int64_t)out->data.size() >= budget) break;
+    }
+    out->view = {(int64_t)out->ends.size(), (int64_t)out->data.size(),
+                 out->data.data(), out->ends.data(), out->errs.data()};
+    return &out->view;
+  } catch (...) {
+    return nullptr;
+  }
+}
+
+extern "C" void pw_fs_read_free(PwFsRead* r) {
+  delete reinterpret_cast<FsReadOwner*>(r);
+}
+
+// ---------------------------------------------------------------------------
 // version stamp so the loader can invalidate stale cached builds
 // ---------------------------------------------------------------------------
 
-extern "C" int pw_native_abi_version() { return 1; }
+extern "C" int pw_native_abi_version() { return 2; }

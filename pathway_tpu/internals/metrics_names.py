@@ -34,6 +34,10 @@ METRICS: dict[str, tuple[str, str]] = {
     # connector plane (internals/monitoring.py)
     "pathway_connector_messages_total": ("counter", "messages committed per connector"),
     "pathway_connector_finished": ("gauge", "1 once a finite connector closed"),
+    "pathway_connector_scans_total": (
+        "counter",
+        "polls of a watched path per connector and lister (native|python)",
+    ),
     # serving scheduler (xpacks/llm/_scheduler.py)
     "pathway_scheduler_submitted_total": ("counter", "work items admitted"),
     "pathway_scheduler_completed_total": ("counter", "work items completed"),

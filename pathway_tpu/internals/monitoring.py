@@ -36,6 +36,8 @@ __all__ = [
     "exposition",
     "FreshnessTracker",
     "get_freshness",
+    "record_connector_scan",
+    "connector_scans",
 ]
 
 
@@ -108,6 +110,24 @@ def exposition() -> str:
             _exposition_monitor = StatsMonitor()
         monitor = _exposition_monitor
     return monitor.openmetrics()
+
+
+#: polls of a watched path per (connector label, lister): process-wide like
+#: the freshness tracker, because the scanning thread holds no monitor
+_connector_scans: dict[tuple[str, str], int] = defaultdict(int)
+_connector_scans_lock = threading.Lock()
+
+
+def record_connector_scan(connector: str, lister: str) -> None:
+    """One poll by ``pw.io.fs``; ``lister`` is ``"native"`` or ``"python"``
+    (the fallback, which a deployment should never see in steady state)."""
+    with _connector_scans_lock:
+        _connector_scans[(connector, lister)] += 1
+
+
+def connector_scans() -> dict[tuple[str, str], int]:
+    with _connector_scans_lock:
+        return dict(_connector_scans)
 
 
 #: flush-latency histogram bucket upper bounds (milliseconds)
@@ -272,6 +292,14 @@ class StatsMonitor:
                 f'pathway_connector_finished{{connector="{safe}"}} '
                 f'{1 if st["finished"] else 0}'
             )
+        scans = sorted(connector_scans().items())
+        if scans:
+            lines.append("# TYPE pathway_connector_scans_total counter")
+            for (name, lister), n in scans:
+                lines.append(
+                    "pathway_connector_scans_total"
+                    f'{{connector="{escape_label_value(name)}",lister="{lister}"}} {n}'
+                )
         for _name, provider in list(_metrics_providers.items()):
             try:
                 lines.extend(provider.openmetrics_lines())

@@ -9,17 +9,31 @@ Here the scanner is a ``ConnectorSubject``: in streaming mode it polls the
 path, diffing the (path → mtime,size) snapshot; a changed file retracts
 every row it previously produced and re-emits — the upsert/delete diff
 mechanism the HBM index consumes downstream (SURVEY §3.4).
+
+One poll is snapshot, diff, emit, commit.  The snapshot of a directory and
+the bytes of its new and changed files come from the native core
+(``_native`` ``walk_dir`` / ``read_files``: two calls a poll, the
+interpreter lock released), so the Python work of a poll follows the files
+that changed; every known file is still compared by (mtime, size) on every
+poll.  The Python lister (``glob`` + ``os.stat`` + ``open``) gives the same
+snapshot and takes over where :meth:`_FsSubject._native_walk_args` says it
+must.
 """
 
 from __future__ import annotations
 
 import csv as _csv
 import glob as _glob
+import io as _io
 import json as _json
 import os
+import subprocess
+import sys
+import threading
 import time as _time
+import warnings
 from pathlib import Path
-from typing import Any, Iterable
+from typing import Any, Iterable, Iterator
 
 from ...internals.schema import SchemaMetaclass, schema_from_types
 from ...internals.table import Table
@@ -31,14 +45,39 @@ from ...internals.keys import ref_scalar
 __all__ = ["read", "write"]
 
 
-def _file_metadata(path: str) -> dict:
-    st = os.stat(path)
-    return {
-        "path": os.fspath(path),
-        "size": st.st_size,
-        "modified_at": int(st.st_mtime),
-        "seen_at": int(_time.time()),
-    }
+_NOT_LOADED = object()
+#: ``pathway_tpu._native`` once a subject asked for it; ``None`` if it did
+#: not build or load (warned once), and then every poll lists in Python
+_native_core: Any = _NOT_LOADED
+_native_core_lock = threading.Lock()
+
+
+def _load_native() -> Any:
+    global _native_core
+    with _native_core_lock:
+        if _native_core is _NOT_LOADED:
+            try:
+                from ... import _native as core
+            except (OSError, ImportError, subprocess.CalledProcessError) as exc:
+                warnings.warn(
+                    f"native directory lister unavailable ({type(exc).__name__}: "
+                    f"{exc}); pw.io.fs polls with its pure-Python lister: three "
+                    "system calls under the interpreter lock per directory entry",
+                    stacklevel=2,
+                )
+                core = None
+            _native_core = core
+        return _native_core
+
+
+def _read_files_python(paths: list[str]) -> Iterator[bytes | OSError]:
+    """The Python side of ``_native.read_files``."""
+    for path in paths:
+        try:
+            with open(path, "rb") as f:
+                yield f.read()
+        except OSError as exc:
+            yield exc
 
 
 class _FsSubject(ConnectorSubject):
@@ -79,6 +118,7 @@ class _FsSubject(ConnectorSubject):
         self._line_counts: dict[str, int] = {}
         # path -> (mtime, size, [row keys])
         self._seen: dict[str, tuple[float, int, list]] = {}
+        self._native_args = self._native_walk_args()
 
     # offsets = the whole scan state: restoring it suppresses re-emission of
     # unchanged files and lets later modifications retract the exact rows the
@@ -91,6 +131,34 @@ class _FsSubject(ConnectorSubject):
         if offsets:
             self._seen = dict(offsets)
 
+    def _native_walk_args(self) -> tuple[bytes, bytes] | None:
+        """``(root, pattern)`` for ``_native.walk_dir``, or ``None`` where
+        only the Python lister gives ``glob``'s answer: ``path`` is itself
+        a glob, or ``object_pattern`` is more than ``*``, ``?`` and plain
+        characters of one base name (a separator, a bracket expression,
+        ``**``, nothing), or file names do not decode as UTF-8 here.  A
+        ``path`` that is a single file, or is not there, is the walk's own
+        to report (``entries`` < 0)."""
+        pattern = self.object_pattern
+        if (
+            _glob.has_magic(self.path)
+            or not pattern
+            or pattern == "**"
+            or "[" in pattern
+            or os.sep in pattern
+            or sys.getfilesystemencoding() != "utf-8"
+        ):
+            return None
+        # glob joins its results to the path less its trailing separators
+        root = self.path.rstrip(os.sep) or os.sep
+        try:
+            return (
+                os.fsencode(root if root.endswith(os.sep) else root + os.sep),
+                pattern.encode("utf-8"),
+            )
+        except UnicodeEncodeError:
+            return None
+
     def _list_files(self) -> list[str]:
         p = self.path
         if os.path.isfile(p):
@@ -102,52 +170,90 @@ class _FsSubject(ConnectorSubject):
             )
         return sorted(f for f in _glob.glob(p) if os.path.isfile(f))
 
-    def _rows_of_file(self, path: str) -> Iterable[tuple[Any, dict]]:
-        """Yield (key_material, column dict) per record."""
-        meta = _file_metadata(path) if self.with_metadata else None
+    def _snapshot(self) -> tuple[list[str], list[float], list[int], int, Any]:
+        """The files of the path now: sorted paths, ``st_mtime`` and
+        ``st_size`` of each, the directory entries it took to find them,
+        and the native core if it did the listing (else ``None``)."""
+        core = _load_native() if self._native_args is not None else None
+        if core is not None:
+            blob, mtimes, sizes, entries = core.walk_dir(*self._native_args)
+            if entries >= 0:  # the path is a directory
+                try:
+                    paths = blob.decode("utf-8").split("\0") if blob else []
+                    return paths, mtimes, sizes, entries, core
+                except UnicodeDecodeError:
+                    # a name os.fsdecode escapes sorts elsewhere as a str
+                    pass
+        paths, mtimes, sizes = [], [], []
+        for path in self._list_files():
+            try:
+                st = os.stat(path)
+            except OSError:
+                continue
+            paths.append(path)
+            mtimes.append(st.st_mtime)
+            sizes.append(st.st_size)
+        return paths, mtimes, sizes, len(paths), None
+
+    def _metadata_of(self, path: str, mtime: float, size: int) -> dict | None:
+        if not self.with_metadata:
+            return None
+        return {
+            "path": os.fspath(path),
+            "size": size,
+            "modified_at": int(mtime),
+            "seen_at": int(_time.time()),
+        }
+
+    def _rows_of_file(
+        self, path: str, data: bytes, meta: dict | None
+    ) -> Iterable[tuple[Any, dict]]:
+        """Yield (key_material, column dict) per record of the file whose
+        bytes are ``data``."""
 
         def attach(d: dict) -> dict:
             if meta is not None:
                 d["_metadata"] = Json(meta)
             return d
 
+        def text(**kwargs: Any) -> _io.TextIOWrapper:
+            # what open(path, **kwargs) reads: the same default encoding
+            # and the same newline handling, from the same class
+            return _io.TextIOWrapper(_io.BytesIO(data), **kwargs)
+
         if self.fmt == "binary":
-            with open(path, "rb") as f:
-                yield (path,), attach({"data": f.read()})
+            yield (path,), attach({"data": data})
         elif self.fmt in ("plaintext_by_file",):
-            with open(path, "r", errors="replace") as f:
-                yield (path,), attach({"data": f.read()})
+            yield (path,), attach({"data": text(errors="replace").read()})
         elif self.fmt == "plaintext":
-            with open(path, "r", errors="replace") as f:
-                for i, line in enumerate(f):
-                    yield (path, i), attach({"data": line.rstrip("\n")})
+            for i, line in enumerate(text(errors="replace")):
+                yield (path, i), attach({"data": line.rstrip("\n")})
         elif self.fmt == "csv":
             settings = self.csv_settings
             reader_kwargs = settings.reader_kwargs() if settings else {}
             comment = settings.comment_character if settings else None
-            with open(path, newline="") as f:
-                lines = (
-                    (ln for ln in f if not ln.lstrip().startswith(comment))
-                    if comment
-                    else f
-                )
-                for i, rec in enumerate(_csv.DictReader(lines, **reader_kwargs)):
-                    yield (path, i), attach(coerce_row(self.schema_for_rows, rec))
+            f = text(newline="")
+            lines = (
+                (ln for ln in f if not ln.lstrip().startswith(comment))
+                if comment
+                else f
+            )
+            for i, rec in enumerate(_csv.DictReader(lines, **reader_kwargs)):
+                yield (path, i), attach(coerce_row(self.schema_for_rows, rec))
         elif self.fmt in ("json", "jsonlines"):
-            with open(path) as f:
-                for i, line in enumerate(f):
-                    line = line.strip()
-                    if not line:
-                        continue
-                    rec = _json.loads(line)
-                    yield (path, i), attach(coerce_row(self.schema_for_rows, rec))
+            for i, line in enumerate(text()):
+                line = line.strip()
+                if not line:
+                    continue
+                rec = _json.loads(line)
+                yield (path, i), attach(coerce_row(self.schema_for_rows, rec))
         else:
             raise ValueError(f"unknown format {self.fmt!r}")
 
-    def _emit_file(self, path: str) -> list:
+    def _emit_file(self, path: str, data: bytes, meta: dict | None) -> list:
         keys = []
         pk_cols = self._primary_key
-        for key_material, row in self._rows_of_file(path):
+        for key_material, row in self._rows_of_file(path, data, meta):
             values = tuple(row.get(n) for n in self._column_names)
             if pk_cols:
                 key = ref_scalar(*[row.get(c) for c in pk_cols])
@@ -161,8 +267,8 @@ class _FsSubject(ConnectorSubject):
         from ...internals.flight_recorder import span
 
         with span("connector.scan", "connector", record=False) as timed:
-            changed, emitted = self._scan_and_emit()
-            timed.set(files=emitted)
+            changed, attrs = self._scan_and_emit()
+            timed.set(**attrs)
             if changed:
                 # an empty poll is no part of a document's way: it shows
                 # in a profiler session only, not in the ring or the stage
@@ -170,50 +276,74 @@ class _FsSubject(ConnectorSubject):
                 timed.stage = "connector.scan"
         return changed
 
-    def _scan_and_emit(self) -> tuple[bool, int]:
-        """One pass over the path: ``(anything changed, files emitted)``."""
+    def _scan_and_emit(self) -> tuple[bool, dict]:
+        """One poll of the path: snapshot, diff against ``_seen``, emit,
+        commit.  Returns ``(anything changed, the scan span's attrs)``."""
+        from ...internals.monitoring import record_connector_scan
+
+        t_start = _time.perf_counter()
+        paths, mtimes, sizes, entries, core = self._snapshot()
+        t_walked = _time.perf_counter()
         changed = False
         emitted = 0
-        current = {}
-        for path in self._list_files():
-            try:
-                st = os.stat(path)
-            except OSError:
-                continue
-            current[path] = (st.st_mtime, st.st_size)
+        # the diff: no system call in this loop
+        seen = self._seen
+        known = 0
+        todo = []
+        for path, mtime, size in zip(paths, mtimes, sizes):
+            old = seen.get(path)
+            if old is not None:
+                known += 1
+                if old[0] == mtime and old[1] == size:
+                    continue
+            todo.append((path, mtime, size, old))
         # deletions
-        for path in list(self._seen):
-            if path not in current:
-                _, _, keys = self._seen.pop(path)
+        if known < len(seen):
+            current = set(paths)
+            for path in [p for p in seen if p not in current]:
+                _, _, keys = seen.pop(path)
                 self._append_state_clear(path)
                 for key, values in keys:
                     self._remove(key, values)
                 changed = True
         # additions / modifications
-        for path, (mtime, size) in current.items():
-            old = self._seen.get(path)
-            if old is not None and (old[0], old[1]) == (mtime, size):
-                continue
-            if self.append_only and self.fmt in (
-                "plaintext", "json", "jsonlines"
-            ):
+        if self.append_only and self.fmt in ("plaintext", "json", "jsonlines"):
+            for path, mtime, size, old in todo:
                 if self._scan_append_mode(path, old, mtime, size):
                     changed = True
                     emitted += 1
-                continue
-            if old is not None:
-                for key, values in old[2]:
-                    self._remove(key, values)
-            try:
-                keys = self._emit_file(path)
-            except OSError:
-                continue
-            self._seen[path] = (mtime, size, keys)
-            changed = True
-            emitted += 1
+        elif todo:
+            names = [t[0] for t in todo]
+            if core is not None:
+                contents = core.read_files([os.fsencode(n) for n in names])
+            else:
+                contents = _read_files_python(names)
+            for (path, mtime, size, old), data in zip(todo, contents):
+                if isinstance(data, OSError):
+                    continue  # gone or unreadable since the walk: next poll
+                if old is not None:
+                    for key, values in old[2]:
+                        self._remove(key, values)
+                keys = self._emit_file(
+                    path, data, self._metadata_of(path, mtime, size)
+                )
+                seen[path] = (mtime, size, keys)
+                changed = True
+                emitted += 1
         if changed:
             self.commit()
-        return changed, emitted
+        t_end = _time.perf_counter()
+        record_connector_scan(
+            self._metrics_label or f"{self._datasource_name}-0",
+            "native" if core is not None else "python",
+        )
+        return changed, {
+            "files": emitted,
+            "native": core is not None,
+            "entries": entries,
+            "walk_ms": round((t_walked - t_start) * 1e3, 3),
+            "emit_ms": round((t_end - t_walked) * 1e3, 3),
+        }
 
     # ---- append-only tailing (opt-in log mode) --------------------------
 
@@ -252,7 +382,7 @@ class _FsSubject(ConnectorSubject):
         if grown:
             keys = old[2]
             try:
-                if self._read_line_region(path, keys):
+                if self._read_line_region(path, keys, mtime, size):
                     self._seen[path] = (mtime, size, keys)
                     return True
             except OSError:
@@ -264,13 +394,15 @@ class _FsSubject(ConnectorSubject):
         self._append_state_clear(path)
         keys: list = []
         try:
-            self._read_line_region(path, keys)
+            self._read_line_region(path, keys, mtime, size)
         except OSError:
             return old is not None
         self._seen[path] = (mtime, size, keys)
         return True
 
-    def _read_line_region(self, path: str, keys: list) -> bool:
+    def _read_line_region(
+        self, path: str, keys: list, mtime: float, size: int
+    ) -> bool:
         """Consume complete lines from ``_consumed[path]`` (0 when fresh),
         emitting rows keyed by file line index; updates consumed offset,
         line count, and the overlap snapshot.  Returns False when the
@@ -296,7 +428,7 @@ class _FsSubject(ConnectorSubject):
         if cut < 0:
             return True  # grew, but no complete new line yet
         block = new_data[: cut + 1]
-        meta = _file_metadata(path) if self.with_metadata else None
+        meta = self._metadata_of(path, mtime, size)
         for line in block.decode("utf-8", errors="replace").split("\n")[:-1]:
             if line.endswith("\r"):
                 # text-mode universal newlines give the full-read path
@@ -351,6 +483,14 @@ def read(
     format: "csv" | "json" (jsonlines) | "plaintext" (row per line) |
     "plaintext_by_file" | "binary".  mode: "streaming" polls for
     new/changed/deleted files; "static" reads once at build time.
+
+    A poll of a directory lists it, stats every file and reads the new and
+    changed ones in native code with the interpreter lock released; every
+    known file is compared by (mtime, size) on every poll.  The Python
+    lister (``glob``) does the poll when the native core did not load, when
+    ``path`` is a single file or a glob, or when ``object_pattern`` holds a
+    path separator or a bracket expression; rows, keys and offsets are the
+    same, and ``pathway_connector_scans_total{lister=}`` says which it was.
 
     ``append_only=True`` (plaintext/jsonlines): grown files emit only
     their new complete lines instead of retract + full re-read — linear

@@ -89,6 +89,9 @@ class ConnectorSubject:
     #: re-enterable (emits non-idempotent rows without dedup/upsert).
     _supervised: bool = True
     _max_restarts: int | None = None
+    #: the ``connector`` label of this subject's metric series
+    #: (``<datasource>-<occurrence>``); the streaming driver sets it
+    _metrics_label: str | None = None
     #: request-scoped sources (REST handlers) whose rows are in-flight
     #: client requests: nothing to restore on restart (clients retry), so
     #: OPERATOR_PERSISTING's seekability coverage check exempts them
@@ -1136,9 +1139,8 @@ class StreamingDriver:
         for n, (subject, _src) in enumerate(self.subject_src):
             if data_event is not None:
                 subject._data_event = data_event
-            supervisor = ConnectorSupervisor(
-                subject, self._connector_label(subject)
-            )
+            subject._metrics_label = self._connector_label(subject)
+            supervisor = ConnectorSupervisor(subject, subject._metrics_label)
             self.supervisors[id(subject)] = supervisor
 
             def runner(s=subject, sup=supervisor, name=f"pw-conn-{n}"):

@@ -36,6 +36,11 @@ def test_phases_at_tiny_geometry_with_interpreted_kernels(monkeypatch):
     # path runs the Pallas bodies (interpreted) end to end
     monkeypatch.setenv("PATHWAY_SERVING_KERNEL", "pallas")
     monkeypatch.setenv("PATHWAY_DECODE_KERNEL", "pallas")
+    # the smoke reads process-wide counters and expects a process of its own;
+    # under xdist this worker may have run the fault-containment tests first
+    from pathway_tpu.generation import engine
+
+    monkeypatch.setattr(engine, "_COUNTERS", dict.fromkeys(engine._COUNTERS, 0))
     smoke = chip_smoke.Smoke(TINY, require_tpu=False)
     summary = smoke.run()
     assert summary["ok"] is True and summary["claim"] is None

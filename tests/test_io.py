@@ -291,3 +291,367 @@ def test_fs_append_only_jsonlines_blank_lines_and_rotation(tmp_path):
     # rotation retracted the pre-truncation rows
     removed = [v for _, v, add in events if not add]
     assert set(removed) >= {1, 2, 3}
+
+
+# ---------------------------------------------------------------------------
+# pw.io.fs: the native directory pass against the Python lister.  Both are
+# driven poll by poll over ONE directory, so they see the same files with
+# the same times; the Python lister is forced the way a failed build leaves
+# the module (the loaded core is None), not by a switch.
+# ---------------------------------------------------------------------------
+
+import builtins  # noqa: E402
+import glob as glob_mod  # noqa: E402
+import os  # noqa: E402
+import pickle  # noqa: E402
+import warnings  # noqa: E402
+
+from pathway_tpu.internals import flight_recorder  # noqa: E402
+from pathway_tpu.internals.monitoring import (  # noqa: E402
+    connector_scans,
+    exposition,
+)
+from pathway_tpu.io import fs as fs_mod  # noqa: E402
+
+try:
+    from pathway_tpu import _native as _built_core
+except Exception:  # noqa: BLE001 - no compiler here: the Python lister's tests still run
+    _built_core = None
+needs_native = pytest.mark.skipif(_built_core is None, reason="native core did not build")
+
+FS_FORMATS = ("binary", "plaintext_by_file", "plaintext", "csv", "json")
+
+
+class _Person(pw.Schema):
+    name: str
+    age: int
+
+
+def _body(fmt: str, tag: str) -> bytes:
+    """A file of the format; equal-length tags give equal-length files.
+    The text formats carry \\r\\n and a byte that is not UTF-8."""
+    if fmt == "csv":
+        return f"name,age\r\n{tag},30\nbo {tag},25\n".encode()
+    if fmt == "json":
+        return (f'{{"name": "{tag}", "age": 30}}\n\n'
+                f'{{"name": "bo {tag}", "age": 25}}\r\n').encode()
+    return f"h\xe9llo {tag}\r\nworld\n\nend {tag}".encode("latin-1")
+
+
+def _put(path, data: bytes) -> None:
+    """Write beside, rename in: the reader never sees half a file."""
+    os.makedirs(os.path.dirname(path), exist_ok=True)
+    tmp = os.path.join(os.path.dirname(path), ".putting")
+    with builtins.open(tmp, "wb") as f:
+        f.write(data)
+    os.replace(tmp, path)
+
+
+def _fs_subject(path, fmt: str, **kwargs):
+    schema = _Person if fmt in ("csv", "json", "jsonlines") else None
+    t = pw.io.fs.read(path, format=fmt, schema=schema, mode="streaming",
+                      with_metadata=True, **kwargs)
+    return t._operator.params["subject"]
+
+
+def _poll(subject, native: bool, monkeypatch) -> list:
+    """One poll under the named lister: the (op, key, values) it committed."""
+    with monkeypatch.context() as m:
+        if not native:
+            m.setattr(fs_mod, "_native_core", None)
+        _changed, attrs = subject._scan_and_emit()
+    assert attrs["native"] is native
+    with subject._lock:
+        batches, subject._committed = subject._committed, []
+    return [event for batch in batches for event in batch]
+
+
+def _case_new_file(d, fmt, outside):
+    _put(f"{d}/a.txt", _body(fmt, "a1"))
+    yield
+    _put(f"{d}/b.txt", _body(fmt, "b1"))
+
+
+def _case_edit_same_size(d, fmt, outside):
+    _put(f"{d}/a.txt", _body(fmt, "a1"))
+    _put(f"{d}/b.txt", _body(fmt, "b1"))
+    yield
+    st = os.stat(f"{d}/a.txt")
+    with builtins.open(f"{d}/a.txt", "r+b") as f:  # in place: same inode, same size
+        f.write(_body(fmt, "a2"))
+    os.utime(f"{d}/a.txt", ns=(st.st_atime_ns, st.st_mtime_ns + 1_000_000))
+    assert os.stat(f"{d}/a.txt").st_size == st.st_size
+
+
+def _case_grown(d, fmt, outside):
+    _put(f"{d}/a.txt", _body(fmt, "a1"))
+    yield
+    with builtins.open(f"{d}/a.txt", "ab") as f:
+        f.write(b"\n" + _body(fmt, "a2").split(b"\n", 1)[1])
+
+
+def _case_deleted(d, fmt, outside):
+    _put(f"{d}/a.txt", _body(fmt, "a1"))
+    _put(f"{d}/b.txt", _body(fmt, "b1"))
+    _put(f"{d}/c.txt", _body(fmt, "c1"))
+    yield
+    os.unlink(f"{d}/b.txt")
+
+
+def _case_replaced_by_rename(d, fmt, outside):
+    _put(f"{d}/a.txt", _body(fmt, "a1"))
+    yield
+    _put(f"{d}/a.txt", _body(fmt, "a2 longer"))
+
+
+def _case_nested(d, fmt, outside):
+    _put(f"{d}/a.txt", _body(fmt, "a1"))
+    _put(f"{d}/sub/b.txt", _body(fmt, "b1"))
+    _put(f"{d}/sub/deep/c.txt", _body(fmt, "c1"))
+    _put(f"{d}/a-b/d.txt", _body(fmt, "d1"))  # '-' sorts before '/'
+    yield
+    _put(f"{d}/sub/deep/e.txt", _body(fmt, "e1"))
+    os.unlink(f"{d}/sub/b.txt")
+
+
+def _case_hidden(d, fmt, outside):
+    _put(f"{d}/a.txt", _body(fmt, "a1"))
+    _put(f"{d}/.hidden.txt", _body(fmt, "h1"))
+    _put(f"{d}/.hdir/x.txt", _body(fmt, "x1"))
+    yield
+    _put(f"{d}/.hdir/y.txt", _body(fmt, "y1"))
+    _put(f"{d}/.later.txt", _body(fmt, "l1"))
+    _put(f"{d}/b.txt", _body(fmt, "b1"))
+
+
+def _case_symlinks(d, fmt, outside):
+    _put(f"{outside}/target.txt", _body(fmt, "t1"))
+    _put(f"{outside}/tree/in.txt", _body(fmt, "i1"))
+    _put(f"{d}/a.txt", _body(fmt, "a1"))
+    os.symlink(f"{outside}/target.txt", f"{d}/link.txt")
+    os.symlink(f"{outside}/tree", f"{d}/linkdir")
+    os.symlink(f"{outside}/nothing", f"{d}/broken.txt")
+    yield
+    _put(f"{outside}/target.txt", _body(fmt, "t2 longer"))
+    _put(f"{outside}/tree/more.txt", _body(fmt, "m1"))
+
+
+def _case_pattern_txt(d, fmt, outside):
+    _put(f"{d}/a.txt", _body(fmt, "a1"))
+    _put(f"{d}/b.dat", _body(fmt, "b1"))
+    _put(f"{d}/sub/c.txt", _body(fmt, "c1"))
+    _put(f"{d}/txt", _body(fmt, "n1"))
+    yield
+    _put(f"{d}/d.dat", _body(fmt, "d1"))
+    _put(f"{d}/e.txt", _body(fmt, "e1"))
+    _put(f"{d}/sub/.f.txt", _body(fmt, "f1"))
+
+
+FS_CASES = {
+    "new_file": (_case_new_file, "*"),
+    "edit_same_size": (_case_edit_same_size, "*"),
+    "grown": (_case_grown, "*"),
+    "deleted": (_case_deleted, "*"),
+    "replaced_by_rename": (_case_replaced_by_rename, "*"),
+    "nested": (_case_nested, "*"),
+    "hidden": (_case_hidden, "*"),
+    "symlinks": (_case_symlinks, "*"),
+    "pattern_txt": (_case_pattern_txt, "*.txt"),
+}
+
+
+def _both_listers(tmp_path, monkeypatch, fmt, steps, **kwargs):
+    """Drive one subject per lister over the same directory through
+    ``steps`` (a generator that changes the directory between yields);
+    returns the two event streams, poll by poll, and the two subjects."""
+    d = tmp_path / "watched"
+    d.mkdir()
+    monkeypatch.setattr(fs_mod._time, "time", lambda: 1_700_000_000.5)  # seen_at
+    native = _fs_subject(d, fmt, **kwargs)
+    python = _fs_subject(d, fmt, **kwargs)
+    assert native._native_args is not None
+    streams = ([], [])
+    for _ in steps(str(d)):
+        streams[0].append(_poll(native, True, monkeypatch))
+        streams[1].append(_poll(python, False, monkeypatch))
+    for subject, stream, is_native in ((native, streams[0], True),
+                                      (python, streams[1], False)):
+        stream.append(_poll(subject, is_native, monkeypatch))  # after the last step
+        stream.append(_poll(subject, is_native, monkeypatch))  # and nothing since
+    return streams, native, python
+
+
+@needs_native
+@pytest.mark.parametrize("case", FS_CASES)
+@pytest.mark.parametrize("fmt", FS_FORMATS)
+def test_fs_native_lister_matches_python_lister(tmp_path, monkeypatch, fmt, case):
+    change, pattern = FS_CASES[case]
+    outside = tmp_path / "outside"
+
+    (got, want), native, python = _both_listers(
+        tmp_path, monkeypatch, fmt,
+        lambda d: change(d, fmt, str(outside)), object_pattern=pattern)
+    assert got == want  # same (op, key, values), same order, poll by poll
+    assert got[0] and got[-2], "the case made no event"
+    assert got[-1] == []  # an unchanged directory emits nothing
+    assert native._seen == python._seen
+    assert list(native._seen) == list(python._seen)
+    assert native.current_offsets() == python.current_offsets()
+    # the first poll after a change saw it: nothing is left for a later one
+    for path, (mtime, size, _keys) in native._seen.items():
+        st = os.stat(path)
+        assert (mtime, size) == (st.st_mtime, st.st_size)
+
+
+@needs_native
+@pytest.mark.parametrize("fmt", ["plaintext", "jsonlines"])
+def test_fs_native_lister_matches_python_lister_append_only(
+        tmp_path, monkeypatch, fmt):
+    line = (lambda i: f"line {i}") if fmt == "plaintext" else (
+        lambda i: json.dumps({"name": f"n{i}", "age": i}))
+
+    def steps(d):
+        log = f"{d}/app.log"
+        with builtins.open(log, "w") as f:
+            f.write(line(0) + "\n" + line(1) + "\n")
+        yield
+        with builtins.open(log, "a") as f:
+            f.write(line(2) + "\r\n" + line(3)[:4])  # a partial last line
+        yield
+        with builtins.open(log, "a") as f:
+            f.write(line(3)[4:] + "\n")
+        yield
+        with builtins.open(log, "w") as f:  # rotation
+            f.write(line(9) + "\n")
+        _put(f"{d}/sub/other.log", (line(7) + "\n").encode())
+        yield
+
+    (got, want), native, python = _both_listers(
+        tmp_path, monkeypatch, fmt, steps, append_only=True)
+    assert got == want
+    assert [len(events) for events in got] == [2, 1, 1, 6, 0, 0]
+    assert native._seen == python._seen
+    assert native._consumed == python._consumed
+
+
+@needs_native
+def test_fs_snapshot_of_python_lister_restores_under_native(tmp_path, monkeypatch):
+    """The persisted offsets are ``_seen`` itself: the floats the native walk
+    hands over are the ones ``os.stat`` gave the run that persisted them."""
+    d = tmp_path / "watched"
+    for i in range(40):
+        _put(f"{d}/sub{i % 3}/doc_{i:03d}.txt", _body("plaintext", f"t{i}"))
+    before = _fs_subject(d, "plaintext")
+    first = _poll(before, False, monkeypatch)
+    assert len(first) == 40 * 4
+    offsets = pickle.loads(pickle.dumps(before.current_offsets()))
+
+    restored = _fs_subject(d, "plaintext")
+    restored.seek(offsets)
+    assert _poll(restored, True, monkeypatch) == []  # nothing is emitted again
+    assert restored.current_offsets() == offsets
+    # and a later edit retracts exactly the rows the earlier run produced
+    edited = f"{d}/sub1/doc_004.txt"
+    _put(edited, _body("plaintext", "edited"))
+    events = _poll(restored, True, monkeypatch)
+    old_rows = [(key, values) for op, key, values in first
+                if values[1].value["path"] == edited]
+    assert [(key, values) for op, key, values in events if op == "delete"] == old_rows
+    assert len([e for e in events if e[0] == "insert"]) == 4
+
+
+@needs_native
+def test_fs_native_poll_costs_what_changed(tmp_path, monkeypatch):
+    """The contract, without a clock: a poll by the native lister makes no
+    Python-level file-system call per file that is there, and the span of a
+    scan that emits says so."""
+    d = tmp_path / "watched"
+    for i in range(2000):
+        _put(f"{d}/passage_{i:07d}.txt", b"w%d " % i * 8)
+    subject = _fs_subject(d, "binary")
+    assert len(_poll(subject, True, monkeypatch)) == 2000
+
+    calls = {"os.stat": 0, "os.path.isfile": 0, "glob.glob": 0, "open": 0}
+
+    def counting(name, fn):
+        def wrapper(*args, **kwargs):
+            calls[name] += 1
+            return fn(*args, **kwargs)
+        return wrapper
+
+    monkeypatch.setattr(os, "stat", counting("os.stat", os.stat))
+    monkeypatch.setattr(os.path, "isfile", counting("os.path.isfile", os.path.isfile))
+    monkeypatch.setattr(glob_mod, "glob", counting("glob.glob", glob_mod.glob))
+    monkeypatch.setattr(builtins, "open", counting("open", builtins.open))
+
+    assert _poll(subject, True, monkeypatch) == []
+    assert calls == {"os.stat": 0, "os.path.isfile": 0, "glob.glob": 0, "open": 0}
+
+    for i in range(2000, 2007):
+        _put(f"{d}/passage_{i:07d}.txt", b"w%d " % i * 8)
+    calls.update(dict.fromkeys(calls, 0))  # _put opened seven files itself
+    flight_recorder.reset_recorder()
+    assert subject._scan_once() is True
+    assert calls == {"os.stat": 0, "os.path.isfile": 0, "glob.glob": 0, "open": 0}
+    with subject._lock:
+        (batch,), subject._committed = subject._committed, []
+    assert len(batch) == 7
+    (scan,) = [s for s in flight_recorder.get_recorder().spans(category="connector")
+               if s.name == "connector.scan"]
+    assert scan.attrs["native"] is True
+    assert scan.attrs["files"] == 7
+    assert scan.attrs["entries"] == 2007
+    assert scan.attrs["walk_ms"] >= 0 and scan.attrs["emit_ms"] >= 0
+
+    # the Python lister on the same directory pays per file that is there
+    assert _poll(subject, False, monkeypatch) == []
+    assert calls["os.stat"] >= 2007 and calls["os.path.isfile"] >= 2007
+
+
+def test_fs_python_lister_warns_once_and_is_counted(tmp_path, monkeypatch):
+    """A core that does not load: one warning for the process, every poll
+    under ``lister="python"``, the same rows."""
+    import sys
+
+    d = tmp_path / "watched"
+    _put(f"{d}/a.txt", b"alpha")
+    monkeypatch.setattr(fs_mod, "_native_core", fs_mod._NOT_LOADED)
+    monkeypatch.setitem(sys.modules, "pathway_tpu._native", None)  # import fails
+    monkeypatch.delattr(pw, "_native")
+    subjects = [_fs_subject(d, "binary"), _fs_subject(d, "binary")]
+    label = f"{subjects[0]._datasource_name}-0"
+    before = connector_scans().get((label, "python"), 0)
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        for subject in subjects:
+            for _ in range(3):
+                subject._scan_once()
+    told = [w for w in caught if "native directory lister unavailable" in str(w.message)]
+    assert len(told) == 1
+    assert fs_mod._native_core is None
+    assert [len(s._seen) for s in subjects] == [1, 1]
+    assert connector_scans()[(label, "python")] == before + 6
+    assert (f'pathway_connector_scans_total{{connector="{label}",lister="python"}} '
+            f"{before + 6}") in exposition()
+
+
+@pytest.mark.parametrize("path_of,pattern", [
+    (lambda d: f"{d}/a.txt", "*"),        # a single file
+    (lambda d: f"{d}/*.txt", "*"),        # itself a glob
+    (lambda d: d, "sub/*.txt"),           # a separator in the pattern
+    (lambda d: d, "[ab].txt"),            # a bracket expression
+    (lambda d: f"{d}/missing", "*"),      # nothing there (yet)
+], ids=["single_file", "glob_path", "separator", "bracket", "missing"])
+def test_fs_python_lister_is_taken_where_it_must(tmp_path, monkeypatch, path_of, pattern):
+    d = tmp_path / "watched"
+    _put(f"{d}/a.txt", b"alpha")
+    _put(f"{d}/b.txt", b"beta")
+    _put(f"{d}/sub/c.txt", b"gamma")
+    subject = _fs_subject(path_of(str(d)), "binary", object_pattern=pattern)
+    changed, attrs = subject._scan_and_emit()
+    assert attrs["native"] is False
+    monkeypatch.setattr(fs_mod, "_native_core", None)
+    twin = _fs_subject(path_of(str(d)), "binary", object_pattern=pattern)
+    twin._scan_and_emit()
+    assert subject._seen == twin._seen
+    assert changed is bool(subject._seen)
