@@ -18,11 +18,13 @@ import jax.numpy as jnp
 from checks import minilm
 
 
-def prompt_ids(prompt: str, vocab_size: int, max_len: int, max_new_tokens: int) -> list[int]:
+def prompt_ids(prompt: str, decoder: dict, max_new_tokens: int) -> list[int]:
     """``[CLS] tokens [SEP]`` of the prompt by the hash tokenizer, cut to the
     model's positions, of which the tail that leaves room for the new tokens
-    is kept (as the decode session keeps it)."""
-    ids = minilm.tokenize(prompt, vocab_size, max_len)
+    is kept (as the decode session keeps it).  ``decoder`` is the
+    configuration's group of that name."""
+    max_len = decoder["n_positions"]
+    ids = minilm.tokenize(prompt, decoder["vocab_size"], max_len)
     return ids[-max(1, max_len - max_new_tokens):]
 
 
@@ -66,11 +68,11 @@ def forward(params, ids, *, heads: int, eps: float, lowered: bool = False):
     return mm("td,vd->tv", x, params["wte"]["embedding"])
 
 
-def logits(params, ids: list[int], *, heads: int, eps: float, lowered: bool = False,
-           pad_to: int = 128):
+def logits(params, ids: list[int], decoder: dict, lowered: bool = False, pad_to: int = 128):
     """Logits of every position of ``ids``, computed at a length padded to
     a multiple of ``pad_to`` (causal, so the padding changes nothing before
     it) so that few shapes compile."""
+    heads, eps = decoder["n_head"], decoder["layer_norm_epsilon"]
     n = len(ids)
     padded = ids + [0] * (-n % pad_to)
     fwd = jax.jit(forward, static_argnames=("heads", "eps", "lowered"))

@@ -16,6 +16,9 @@ import seeded
 import textgen
 from checks import retrieve as rcheck
 
+#: the group of the configuration file that holds this check's limits
+LIMITS = "limits_ingest"
+
 
 def check(ctx: dict) -> dict:
     config, traffic, seed = ctx["config"], ctx["traffic"], ctx["seed"]
@@ -50,7 +53,7 @@ def check(ctx: dict) -> dict:
     else:
         out.update({"score_gap": 1e30, "rank_shortfall": 1e30})
     out["answers_compared"] = len(answers)
-    limits = config["limits_ingest"]
+    limits = config[LIMITS]
     return {name: {"value": value, "limit": limits.get(name)} for name, value in out.items()}
 
 

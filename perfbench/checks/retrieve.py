@@ -34,6 +34,9 @@ import seeded
 import textgen
 from checks import minilm
 
+#: the group of the configuration file that holds this check's limits
+LIMITS = "limits"
+
 _PASSAGE = re.compile(r"passage_(\d+)\.txt$")
 
 
@@ -209,7 +212,7 @@ def check(ctx: dict) -> dict:
                                 int(traffic["max_words"]))
     params = seeded.encoder_params(config, seed)
     n_passages = int(config["ingested_passages"])
-    limits = config["limits"]
+    limits = config[LIMITS]
     out = structural(records, config, traffic, seed)
     picks = pick_sample(records, lambda i: len(texts[i].split()),
                         int(traffic["check_sample"]), seed)

@@ -58,3 +58,16 @@ def decoder_flops(tokens: int, context: int, *, hidden: int, layers: int,
                           + 2 * 2 * context * hidden)
     heads = tokens if head_tokens is None else head_tokens
     return tokens * per_token + heads * 2 * hidden * vocab
+
+
+def decode_step_bytes(matrix_params: int, kv_values_per_token: int, live_tokens: float,
+                      itemsize: int = 2) -> float:
+    """Least HBM traffic of one decode step: every matrix the step multiplies
+    by once (blocks and output head; ``matrix_params``) and the cached keys
+    and values of every live position of the rows in the step
+    (``live_tokens`` x ``kv_values_per_token``), each value at ``itemsize``
+    bytes: 2, the bfloat16 the configurations state as the decoder's compute
+    and KV type.  A program that keeps float32 matrices reads twice that and
+    shows as under half of its roofline; activations, the new token's
+    embedding row and the logits are thousands of bytes and left out."""
+    return itemsize * (matrix_params + kv_values_per_token * live_tokens)

@@ -1,12 +1,14 @@
-"""Sequences advanced per decode-step launch: tokens generated over the
-window / ``decode_step`` launches (``pathway_decode_tokens_total`` less one
-prefill token per answer, over ``pathway_decode_launch_ms_count``)."""
+"""Sequences in one launch of the decode session that advances rows (a
+single-token ``decode_step`` or a multi-token ``verify`` launch, which carries
+the rows that decode beside the rows whose prompt tail is still ingested):
+``pathway_decode_batch_rows`` sum over count of both kinds, difference over
+the window.  Nothing when neither launched."""
+
+KINDS = ("decode_step", "verify")
 
 
 def read(ctx):
     d = ctx["delta"]
-    steps = d.get('om.pathway_decode_launch_ms_count{kind="decode_step"}', 0)
-    if not steps:
-        return None
-    answered = sum(1 for r in ctx["records"] if not r["failed"])
-    return max(0.0, d.get("om.pathway_decode_tokens_total", 0) - answered) / steps
+    launches = sum(d.get(f'om.pathway_decode_batch_rows_count{{kind="{k}"}}', 0) for k in KINDS)
+    rows = sum(d.get(f'om.pathway_decode_batch_rows_sum{{kind="{k}"}}', 0) for k in KINDS)
+    return rows / launches if launches else None

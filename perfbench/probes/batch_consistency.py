@@ -3,9 +3,13 @@
 the tick?  Starts the cell's deployment, then hands the retrieve plane's batch
 handler the same distinct queries in ticks of different sizes and compares
 each query's best score with the one it gets alone.  Found in PR 25: see
-PERF.md, Open questions.
+PERF.md, Open questions.  In an answering deployment the plane probed is the
+answerer's own (``qa._stream_retrieve_plane()``), which takes the same path.
+The combine is also tried alone at every pad the 2-, 4- and 8-row launches
+can carry (PR 28).
 
     python3 perfbench/probes/batch_consistency.py --workload retrieve-steady --seed 1001
+    python3 perfbench/probes/batch_consistency.py --workload answers-steady --seed 1001 --sizes 1,2,3,4,5,6,7,8
 """
 
 from __future__ import annotations
@@ -39,7 +43,8 @@ def main() -> int:
     try:
         server = run.load_module("servers", config["server"]).start(
             config, args.seed, workdir, run.log)
-        plane = server.vs._retrieve_plane
+        qa = getattr(server, "qa", None)
+        plane = qa._stream_retrieve_plane() if qa is not None else server.vs._retrieve_plane
         k = int(traffic["k"])
         texts = textgen.query_texts(256, args.seed + 77, int(traffic["min_words"]),
                                     int(traffic["max_words"]))
@@ -47,7 +52,9 @@ def main() -> int:
         # scattered into a zero batch, pad rows sent out of bounds with mode="drop"
         import jax.numpy as jnp
 
-        for rows, fresh_rows, n in ((4, 4, 3), (8, 8, 5), (8, 4, 3), (32, 32, 9), (2, 2, 1)):
+        for rows, fresh_rows, n in ((2, 2, 1), (2, 1, 1), (4, 4, 3), (4, 4, 2), (4, 4, 1), (4, 2, 1),
+                                    (4, 2, 2), (8, 8, 5), (8, 8, 7), (8, 4, 3), (8, 2, 1),
+                                    (32, 32, 9)):
             fresh = jnp.arange(1, fresh_rows + 1, dtype=jnp.float32)[:, None] * jnp.ones(
                 (fresh_rows, server.inner.dim), jnp.float32)
             idx = np.full((fresh_rows,), rows, np.int32)

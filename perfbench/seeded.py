@@ -105,13 +105,6 @@ def gpt2_params(key, *, vocab, hidden, layers, ffn, positions):
     return params
 
 
-def decoder_params(config: dict, seed: int):
-    """The decoder's weights for a configuration file's ``decoder`` group."""
-    d = config["decoder"]
-    return gpt2_params(key_of(seed, 2), vocab=d["vocab_size"], hidden=d["n_embd"],
-                       layers=d["n_layer"], ffn=d["n_inner"], positions=d["n_positions"])
-
-
 def encoder_params(config: dict, seed: int):
     """The encoder's weights for a configuration file's ``encoder`` group."""
     e = config["encoder"]

@@ -6,9 +6,12 @@ short window per rate, each with its own generator process.
         --rates 100,200,300,400,500 --seconds 15 [--out perfbench/out]
 
 A rate is sustained when nothing failed or was refused, the generator was not
-late, and the tail did not grow through the window (the p95 of the last third
-is within twice that of the first third).  The cell's traffic file then gets
-four fifths of the highest sustained rate, by hand: a run never searches.
+late, and the tail did not grow through the window: by default the p95 of the
+last third is within twice that of the first third; a traffic file sets its own
+under ``sustained`` (``key``, ``pct``, ``parts``, ``ratio``: answers take the
+p90 of ``ttft_ms``, second half within 1.25x of the first).  The cell's traffic
+file then gets four fifths of the highest sustained rate, by hand: a run never
+searches.
 """
 
 from __future__ import annotations
@@ -52,31 +55,50 @@ def main() -> int:
         server = run.load_module("servers", config["server"]).start(
             config, args.seed, workdir, run.log)
         server.warm_up(traffic)
+        if float(traffic.get("warm_s", 0)) > 0:  # shapes only the mix drives, as run.py
+            run.run_window(server, traffic, args.seed ^ 0x5EED, float(traffic["warm_s"]),
+                           workdir, compiles)
         for n, rate in enumerate(float(r) for r in args.rates.split(",")):
+            seed = args.seed + n
             w = run.run_window(server, dict(traffic, **{args.rate_key: rate}),
-                               args.seed + n, args.seconds, workdir, compiles)
+                               seed, args.seconds, workdir, compiles)
             recs = w["records"]
             ok = [r for r in recs if not r["failed"]]
-            third = max(1, len(recs) // 3)
             key = traffic.get("latency_key", "latency_ms")
-            first = [r[key] for r in recs[:third] if not r["failed"]]
-            last = [r[key] for r in recs[-third:] if not r["failed"]]
+            rule = {"key": key, "pct": 95, "parts": 3, "ratio": 2.0,
+                    **traffic.get("sustained", {})}
+            part = max(1, len(recs) // int(rule["parts"]))
+            first = [r[rule["key"]] for r in recs[:part] if not r["failed"]]
+            last = [r[rule["key"]] for r in recs[-part:] if not r["failed"]]
             lat = [r[key] for r in ok]
             d = w["delta"]
             step = {
-                "rate": rate, "attempted": len(recs), "failed": len(recs) - len(ok),
+                "rate": rate, "seed": seed, "attempted": len(recs), "failed": len(recs) - len(ok),
                 "p50_ms": rank(lat, 50), "p95_ms": rank(lat, 95), "p99_ms": rank(lat, 99),
-                "p95_first_third_ms": rank(first, 95), "p95_last_third_ms": rank(last, 95),
+                "tail_first_part_ms": rank(first, rule["pct"]),
+                "tail_last_part_ms": rank(last, rule["pct"]), "rule": rule,
                 "late_p95_ms": rank([r["late_ms"] for r in recs if r["late_ms"] is not None], 95),
                 "closed_after_s": w["wall_s"], "compiles": w["compiles"],
                 "ticks": d.get("ticks_total"), "collab_embeds": d.get("collab.embeds_total"),
                 "completed": d.get("runtime.interactive.completed_total"),
+                "retrieve_ticks": d.get("sched.batches_total"),
+                "retrieve_ticks_of_several": d.get("sched.multi_item_batches_total"),
+                "verifies": d.get('om.pathway_decode_launch_ms_count{kind="verify"}'),
+                "verify_ms": d.get('om.pathway_decode_launch_ms_sum{kind="verify"}'),
+                "statuses": {str(s): sum(1 for r in recs if r["status"] == s)
+                             for s in sorted({r["status"] for r in recs})},
+                "why_failed": sorted({str(r["answer"])[:80] for r in recs if r["failed"]})[:4],
+                "decode_steps": d.get('om.pathway_decode_launch_ms_count{kind="decode_step"}'),
+                "decode_step_ms": d.get('om.pathway_decode_launch_ms_sum{kind="decode_step"}'),
+                "prefills": d.get('om.pathway_decode_launch_ms_count{kind="prefill"}'),
+                "prefill_ms": d.get('om.pathway_decode_launch_ms_sum{kind="prefill"}'),
+                "memory_peak_bytes": w["peak"],
                 "ttft_p90_ms": rank([r["ttft_ms"] for r in ok if r.get("ttft_ms")], 90),
                 "tpot_p90_ms": rank([r["tpot_ms"] for r in ok if r.get("tpot_ms")], 90),
             }
             step["sustained"] = bool(
                 step["failed"] == 0 and step["late_p95_ms"] < 5.0
-                and step["p95_last_third_ms"] <= 2 * step["p95_first_third_ms"])
+                and step["tail_last_part_ms"] <= rule["ratio"] * step["tail_first_part_ms"])
             steps.append(step)
             print("perfbench-sweep " + json.dumps(step), flush=True)
     finally:

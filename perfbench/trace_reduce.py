@@ -90,6 +90,16 @@ def _covering_host_span(host: dict, lo: int, hi: int) -> str:
     return best
 
 
+def program_time(trace: dict, names: tuple) -> tuple[float, float]:
+    """(device seconds, launches) of the programs of a reduced trace whose
+    name starts with one of ``names``."""
+    names = tuple(names)
+    if not names:
+        return 0.0, 0.0
+    return (sum(s for name, s in trace["programs"].items() if name.startswith(names)),
+            sum(n for name, n in trace["launches"].items() if name.startswith(names)))
+
+
 def reduce(planes: dict, top: int = 10) -> dict:
     """``busy_s`` and ``window_s`` (mean over the chips that ran something),
     device seconds by program and by operation, launches by program, and
