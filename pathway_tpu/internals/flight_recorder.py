@@ -93,6 +93,8 @@ __all__ = [
     "record_ingest_docs",
     "record_tokenizer_cache",
     "ingest_stats",
+    "record_moe_launch",
+    "moe_stats",
     "observability_metrics_lines",
 ]
 
@@ -984,6 +986,61 @@ def ingest_stats() -> dict[str, Any]:
 
 
 # ---------------------------------------------------------------------------
+# routed-expert counters (pathway_moe_*): computed on the device by the
+# forward that routes, they come back with its result and are added up here
+# once the launch has finished: recording one never waits for the device
+# ---------------------------------------------------------------------------
+
+_moe_lock = threading.Lock()
+_moe_pending: deque = deque()
+_moe_counters = {
+    "launches_total": 0,
+    "routed_tokens_total": 0,
+    "experts_touched_total": 0,
+    "max_expert_tokens_sum": 0,
+    "max_expert_tokens": 0,
+}
+
+
+def _moe_drain(wait: bool) -> None:
+    import numpy as np
+
+    while _moe_pending:
+        counters = _moe_pending[0]
+        if not wait and not counters.is_ready():
+            return
+        _moe_pending.popleft()
+        routed, touched, fullest_sum, fullest = (
+            int(v) for v in np.asarray(counters)
+        )
+        _moe_counters["launches_total"] += 1
+        _moe_counters["routed_tokens_total"] += routed
+        _moe_counters["experts_touched_total"] += touched
+        _moe_counters["max_expert_tokens_sum"] += fullest_sum
+        _moe_counters["max_expert_tokens"] = fullest
+
+
+def record_moe_launch(counters: Any) -> None:
+    """One launch of a forward with routed experts.  ``counters`` is the
+    int32 device array the forward returned beside its result: token-expert
+    pairs routed and experts that got a token (summed over the routed
+    layers), each layer's fullest expert summed, and the fullest of all.  Launches that have finished are added up; this one waits in
+    line until a later call or :func:`moe_stats`."""
+    with _moe_lock:
+        _moe_pending.append(counters)
+        _moe_drain(wait=False)
+
+
+def moe_stats(wait: bool = True) -> dict[str, int]:
+    """The ``pathway_moe_*`` counters over every launch so far.  ``wait``
+    waits for the launches still in flight; a scrape does not (it holds
+    the lock the launching thread takes) and counts them the next time."""
+    with _moe_lock:
+        _moe_drain(wait=wait)
+        return dict(_moe_counters)
+
+
+# ---------------------------------------------------------------------------
 # XLA compile counters (pathway_xla_compile_total{site=...})
 # ---------------------------------------------------------------------------
 
@@ -1086,6 +1143,15 @@ def observability_metrics_lines() -> list[str]:
         "pathway_embed_intra_bucket_efficiency "
         f"{ing['intra_bucket_efficiency']:.4f}"
     )
+    moe = moe_stats(wait=False)
+    if moe["launches_total"]:
+        for name, kind in (
+            ("launches_total", "counter"), ("routed_tokens_total", "counter"),
+            ("experts_touched_total", "counter"),
+            ("max_expert_tokens_sum", "counter"), ("max_expert_tokens", "gauge"),
+        ):
+            lines.append(f"# TYPE pathway_moe_{name} {kind}")
+            lines.append(f"pathway_moe_{name} {moe[name]}")
     impls = attention_impl_stats()
     if impls:
         lines.append("# TYPE pathway_attention_impl gauge")

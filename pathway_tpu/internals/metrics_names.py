@@ -217,6 +217,23 @@ METRICS: dict[str, tuple[str, str]] = {
         "real tokens / row-layout tokens: token padding INSIDE buckets only "
         "(~0.906 packed-bucket, ~1.0 ragged)",
     ),
+    # routed-expert counters of a forward with experts (ops/routed_experts.py
+    # launch_counters, added up by flight_recorder.record_moe_launch)
+    "pathway_moe_launches_total": (
+        "counter", "launches of a forward with routed experts",
+    ),
+    "pathway_moe_routed_tokens_total": (
+        "counter", "(token, expert) pairs routed, summed over the routed layers",
+    ),
+    "pathway_moe_experts_touched_total": (
+        "counter", "experts that got a token, summed over the routed layers",
+    ),
+    "pathway_moe_max_expert_tokens_sum": (
+        "counter", "each routed layer's fullest expert, summed over layers and launches",
+    ),
+    "pathway_moe_max_expert_tokens": (
+        "gauge", "the fullest expert of the last launch",
+    ),
     "pathway_attention_impl": (
         "gauge",
         "encoders built per attention implementation (flax/fused/pallas/ragged)",

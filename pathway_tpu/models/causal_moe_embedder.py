@@ -1,0 +1,386 @@
+"""A causal language model as a sentence embedder: window and full attention
+mixed, grouped queries, rotary positions, routed and shared experts, the
+final-norm state of the last token as the vector.
+
+The forward of a decoder-only model with nothing generated (no output head,
+no cache), the way language-model embedders are deployed (E5-Mistral,
+arXiv:2401.00368).  :class:`SentenceEncoder` builds it from a
+:class:`CausalMoeEmbedderConfig` as it builds the BERT encoder from an
+``EncoderConfig``; tokenizing, bucketing, dispatch and the spans around them
+are the ones every encoder takes.
+
+Layer ``l`` (``x`` [T, D]; ``H_l`` query heads, ``KV`` key/value heads of size
+``hd``; no bias; RMS norms):
+
+1. ``a = rmsnorm(x)``; ``q = a Wq`` [T, H_l, hd], ``k = a Wk``, ``v = a Wv``
+   [T, KV, hd].
+2. rotary on the first ``rotary_factor * hd`` dimensions of ``q`` and ``k``
+   (half-split pairing), plain or YaRN, by the layer's kind.
+3. query head ``h`` reads KV head ``h // (H_l / KV)``; causal scores
+   ``q_i . k_j / sqrt(hd)``, on window layers only ``i - j < window``;
+   softmax in float32.
+4. ``g = sigmoid(a Wg)`` [T, H_l]; ``x += concat_h(g_h o_h) Wo``.
+5. ``b = rmsnorm(x)``; dense layers: ``x += (silu(b Wg) * (b Wu)) Wd``; sparse
+   layers: ``x += routed_experts(b) + shared_expert(b)``
+   (:mod:`pathway_tpu.ops.routed_experts`).
+6. after the last layer ``rmsnorm``; a row's vector is the state of its last
+   real token (the index normalises it).
+
+Precision: weights are held in ``param_dtype`` (bfloat16) and products take
+``dtype`` (bfloat16) operands with float32 accumulation; the residual
+stream, norms, rotary tables, softmax, the router and the combine are
+float32.
+
+Attention is XLA over blocks of ``q_block`` queries against the keys a
+block can see (the block itself and what precedes it, on window layers
+only as far back as the window reaches): no [T, T] score matrix is ever
+held, and a window layer's work grows with T, not T^2.
+
+Two layouts over one parameter tree, as the BERT encoder has them: the
+dense forward ([batch, seq] ids and mask, padding behind the text) and the
+packed ragged forward (rows concatenated along one token axis with segment
+ids and positions).  A padding token is routed to no expert, and under a
+causal mask no real token sees one.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+from typing import Any, ClassVar
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..ops.routed_experts import launch_counters, routed_experts
+
+__all__ = ["RotarySpec", "CausalMoeEmbedderConfig", "CausalMoeEmbedder",
+           "rotary_inv_freq", "init_params", "count_params"]
+
+@dataclasses.dataclass(frozen=True)
+class RotarySpec:
+    """Rotary positions of one kind of layer.  ``yarn_factor`` > 1 selects
+    YaRN (arXiv:2309.00071) as ``transformers`` computes it."""
+
+    theta: float = 10_000.0
+    rotary_factor: float = 1.0
+    yarn_factor: float = 1.0
+    original_max_len: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    #: multiplies cosine and sine; None: ``0.1 ln(yarn_factor) + 1``
+    attention_factor: float | None = None
+
+
+@dataclasses.dataclass(frozen=True)
+class CausalMoeEmbedderConfig:
+    """Laguna-XS.2's widths by default, at the five leading layers (the
+    dense one and one period of three window layers and a full one)."""
+
+    vocab_size: int = 100_352
+    hidden_dim: int = 2048
+    head_dim: int = 128
+    num_kv_heads: int = 8
+    #: per layer: "full" or "window"; its query heads; "dense" or "sparse"
+    layer_types: tuple[str, ...] = ("full", "window", "window", "window", "full")
+    heads_per_layer: tuple[int, ...] = (48, 64, 64, 64, 48)
+    mlp_types: tuple[str, ...] = ("dense", "sparse", "sparse", "sparse", "sparse")
+    window: int = 512
+    full_rotary: RotarySpec = RotarySpec(
+        theta=500_000.0, rotary_factor=0.5, yarn_factor=64.0,
+        original_max_len=4096, beta_fast=64.0, beta_slow=1.0,
+        attention_factor=1.4158883083359672)
+    window_rotary: RotarySpec = RotarySpec(theta=10_000.0)
+    dense_mlp_dim: int = 8192
+    num_experts: int = 256
+    top_k: int = 8
+    expert_dim: int = 512
+    shared_expert_dim: int = 512
+    routed_scaling: float = 2.5
+    rms_eps: float = 1e-6
+    #: longest row the dispatch takes; rotary positions need no table
+    max_len: int = 2048
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+    seq_buckets: tuple[int, ...] = (32, 64, 128, 256, 512, 1024, 2048)
+    #: queries a block of attention takes at once
+    q_block: int = 512
+    #: "xla" (the dense [batch, seq] dispatch) or "ragged" (packed launches)
+    attention_impl: str = "xla"
+
+    program_name: ClassVar[str] = "pw_moe_embedder_forward"
+    emb_dim: ClassVar[None] = None  # the vector is the hidden state
+
+    def __post_init__(self):
+        n = len(self.layer_types)
+        if not (len(self.heads_per_layer) == len(self.mlp_types) == n):
+            raise ValueError("layer_types, heads_per_layer and mlp_types "
+                             "must name the same layers")
+        if any(h % self.num_kv_heads for h in self.heads_per_layer):
+            raise ValueError("each layer's query heads must be a multiple "
+                             f"of num_kv_heads={self.num_kv_heads}")
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.layer_types)
+
+    def build_models(self):
+        return CausalMoeEmbedder(self), CausalMoeEmbedder(self, packed=True)
+
+
+def rotary_inv_freq(spec: RotarySpec, head_dim: int) -> tuple[np.ndarray, float]:
+    """Inverse frequencies [rotary_dim / 2] (float64) and the factor that
+    multiplies cosine and sine, as ``transformers``
+    ``_compute_default_rope_parameters`` / ``_compute_yarn_parameters``."""
+    dim = int(head_dim * spec.rotary_factor)
+    pos_freqs = spec.theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if spec.yarn_factor <= 1.0:
+        return 1.0 / pos_freqs, 1.0
+    extrapolation = 1.0 / pos_freqs
+    interpolation = 1.0 / (spec.yarn_factor * pos_freqs)
+
+    def correction_dim(rotations: float) -> float:
+        return (dim * math.log(spec.original_max_len / (rotations * 2 * math.pi))
+                / (2 * math.log(spec.theta)))
+
+    low = max(math.floor(correction_dim(spec.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(spec.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / (high - low), 0, 1)
+    extrapolated = 1.0 - ramp
+    inv_freq = interpolation * (1 - extrapolated) + extrapolation * extrapolated
+    factor = spec.attention_factor
+    if factor is None:
+        factor = 0.1 * math.log(spec.yarn_factor) + 1.0
+    return inv_freq, float(factor)
+
+
+def _rms_norm(x, scale, eps: float):
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) \
+        * scale.astype(jnp.float32)
+
+
+def _rotate(x, pos, spec: RotarySpec):
+    """``x`` [T, H, hd], ``pos`` [T] -> rotated, float32."""
+    inv_freq, factor = rotary_inv_freq(spec, x.shape[-1])
+    rot = 2 * inv_freq.shape[0]
+    angles = pos.astype(jnp.float32)[:, None] * jnp.asarray(inv_freq, jnp.float32)[None, :]
+    cos = (jnp.cos(angles) * factor)[:, None, :]
+    sin = (jnp.sin(angles) * factor)[:, None, :]
+    x = x.astype(jnp.float32)
+    x1, x2, rest = x[..., : rot // 2], x[..., rot // 2: rot], x[..., rot:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
+
+
+def _attention(q, k, v, pos, seg, *, window: int | None, q_block: int):
+    """Causal grouped-query attention of one token axis.  ``q`` [T, H, hd],
+    ``k``/``v`` [T, KV, hd] in the compute dtype, ``pos`` [T] positions in
+    the row, ``seg`` [T] row of each token (None: one row).  Returns
+    [T, H, hd] float32.  Query block ``i`` is scored against the key blocks
+    it can see only."""
+    t, h, hd = q.shape
+    kv = k.shape[1]
+    q = q.reshape(t, kv, h // kv, hd)
+    bq = min(q_block, t)
+    back = 0 if window is None else -(-(window - 1) // bq)  # key blocks behind
+    token = jnp.arange(t)
+    outs = []
+    for lo in range(0, t, bq):
+        hi = min(lo + bq, t)
+        k_lo = 0 if window is None else max(0, lo - back * bq)
+        s = jnp.einsum("qkgd,tkd->kgqt", q[lo:hi], k[k_lo:hi],
+                       preferred_element_type=jnp.float32) / math.sqrt(hd)
+        qi, kj = token[lo:hi, None], token[None, k_lo:hi]
+        see = kj <= qi
+        if seg is not None:
+            see &= seg[lo:hi, None] == seg[None, k_lo:hi]
+        if window is not None:
+            see &= pos[lo:hi, None] - pos[None, k_lo:hi] < window
+        p = jax.nn.softmax(jnp.where(see[None, None], s, -1e30), axis=-1)
+        outs.append(jnp.einsum("kgqt,tkd->qkgd", p.astype(v.dtype), v[k_lo:hi],
+                               preferred_element_type=jnp.float32))
+    return jnp.concatenate(outs, axis=0).reshape(t, h, hd)
+
+
+def _gated_mlp(x, w_gate_up, w_down):
+    """``(silu(x Wg) * (x Wu)) Wd`` -> float32; gate columns first."""
+    h = jnp.dot(x, w_gate_up, preferred_element_type=jnp.float32)
+    f = h.shape[-1] // 2
+    act = (jax.nn.silu(h[..., :f]) * h[..., f:]).astype(x.dtype)
+    return jnp.dot(act, w_down, preferred_element_type=jnp.float32)
+
+
+def _attend_row(cfg: CausalMoeEmbedderConfig, i: int, p, a, pos, seg):
+    """Steps 1-4 of layer ``i`` for one token axis: ``a`` [T, D] the normed
+    input (float32) -> the attention's addition to the residual, float32."""
+    dt = cfg.dtype
+    full = cfg.layer_types[i] == "full"
+    spec = cfg.full_rotary if full else cfg.window_rotary
+    ad = a.astype(dt)
+    q = jnp.einsum("td,dhe->the", ad, p["wq"], preferred_element_type=jnp.float32)
+    k = jnp.einsum("td,dhe->the", ad, p["wk"], preferred_element_type=jnp.float32)
+    v = jnp.einsum("td,dhe->the", ad, p["wv"], preferred_element_type=jnp.float32)
+    q, k = _rotate(q, pos, spec), _rotate(k, pos, spec)
+    o = _attention(q.astype(dt), k.astype(dt), v.astype(dt), pos, seg,
+                   window=None if full else cfg.window, q_block=cfg.q_block)
+    gate = jax.nn.sigmoid(jnp.dot(ad, p["wg"], preferred_element_type=jnp.float32))
+    o = (o * gate[:, :, None]).astype(dt)
+    return jnp.einsum("the,hed->td", o, p["wo"], preferred_element_type=jnp.float32)
+
+
+def _layer(cfg: CausalMoeEmbedderConfig, i: int, p, x, pos, seg, valid):
+    """One block: ``x`` [B, T, D] float32 (attention is a row's own; the
+    routed layer takes all B*T tokens as one axis, so an expert's weights
+    are read once for the launch)."""
+    a = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
+    attend = functools.partial(_attend_row, cfg, i, p)
+    if seg is None:
+        x = x + jax.vmap(lambda a_, pos_: attend(a_, pos_, None))(a, pos)
+    else:
+        x = x + jax.vmap(attend)(a, pos, seg)
+    b = _rms_norm(x, p["mlp_norm"], cfg.rms_eps)
+    bd = b.astype(cfg.dtype)
+    if cfg.mlp_types[i] == "dense":
+        return x + _gated_mlp(bd, p["mlp"]["w_gate_up"], p["mlp"]["w_down"]), None
+    m = p["moe"]
+    flat = (x.shape[0] * x.shape[1],)
+    # the router reads the float32 norm: a score rounded to bfloat16 on the
+    # way in flips an expert as one rounded on the way out does
+    routed, group_sizes = routed_experts(
+        bd.reshape(flat + bd.shape[2:]), valid.reshape(flat), m["router"],
+        m["w_gate_up"], m["w_down"], top_k=cfg.top_k, scaling=cfg.routed_scaling,
+        router_input=b.reshape(flat + b.shape[2:]))
+    shared = _gated_mlp(bd, m["shared"]["w_gate_up"], m["shared"]["w_down"])
+    return x + routed.reshape(x.shape) + shared, group_sizes
+
+
+def _tokens_forward(cfg, params, ids, pos, seg, valid):
+    """Blocks and final norm: ``ids``, ``pos``, ``valid`` [B, T], ``seg``
+    [B, T] or None (a row is one text) -> ([B, T, D] float32, the routed
+    layers' group sizes)."""
+    x = params["tok_emb"][ids].astype(jnp.float32)
+    sizes = []
+    for i in range(cfg.num_layers):
+        x, group_sizes = _layer(cfg, i, params[f"layer_{i}"], x, pos, seg, valid)
+        if group_sizes is not None:
+            sizes.append(group_sizes)
+    return _rms_norm(x, params["final_norm"], cfg.rms_eps), sizes
+
+
+def _counters(sizes: list):
+    if not sizes:
+        return jnp.zeros((4,), jnp.int32)
+    return launch_counters(sizes)
+
+
+class CausalMoeEmbedder:
+    """The model as :class:`SentenceEncoder` takes one: ``init`` and
+    ``apply`` over ``{"params": tree}``.  ``apply`` returns (vectors
+    float32, the launch's routed-expert counters); ``record_launch`` is
+    where the encoder sends the second."""
+
+    def __init__(self, cfg: CausalMoeEmbedderConfig, packed: bool = False):
+        self.cfg = cfg
+        self.packed = packed
+
+    @staticmethod
+    def record_launch(counters) -> None:
+        from ..internals.flight_recorder import record_moe_launch
+
+        record_moe_launch(counters)
+
+    def init(self, key, *_example):
+        return {"params": init_params(self.cfg, key)}
+
+    def layer(self, layer_params, i: int, x):
+        """Block ``i`` alone over one text's states ``x`` [T, D] ->
+        [T, D] float32, every token real: what a check calls to feed the
+        program a reference's own input to that block."""
+        t = x.shape[0]
+        out, _sizes = _layer(self.cfg, i, layer_params, jnp.asarray(x, jnp.float32)[None],
+                             jnp.arange(t)[None], None, jnp.ones((1, t), bool))
+        return out[0]
+
+    def apply(self, variables, *args, **kwargs):
+        params = variables["params"]
+        if self.packed:
+            return self._apply_packed(params, *args, **kwargs)
+        return self._apply_dense(params, *args)
+
+    def _apply_dense(self, params, ids, mask):
+        """[B, S] ids and mask (padding behind the text) -> [B, D].  The
+        mask alone says what is a token (an id says nothing: id 0 is a
+        real token of many vocabularies).  The dispatcher marks the first
+        position of a padding ROW as real, so that one token a padding row
+        is routed like any other, and counted by the launch's counters."""
+        cfg = self.cfg
+        ids = ids.astype(jnp.int32)
+        mask = mask.astype(jnp.int32)
+        valid = mask > 0
+        b, s = ids.shape
+        pos = jnp.broadcast_to(jnp.arange(s), (b, s))
+        x, sizes = _tokens_forward(cfg, params, ids, pos, None, valid)
+        last = jnp.maximum(jnp.sum(mask, axis=1) - 1, 0)
+        return x[jnp.arange(b), last], _counters(sizes)
+
+    def _apply_packed(self, params, ids, pos, seg, starts, bounds=None, *,
+                      dense_s: int | None = None):
+        """Rows concatenated along one token axis (``ragged_prepare``):
+        ``seg`` names each token's row, the pad tail carries
+        ``seg == rows``; ``starts`` [rows] is where each row begins.
+        ``bounds`` and ``dense_s`` serve the BERT encoder's kernel."""
+        cfg = self.cfg
+        ids, pos, seg = (a.astype(jnp.int32) for a in (ids, pos, seg))
+        rows = starts.shape[0]
+        valid = seg < rows
+        x, sizes = _tokens_forward(
+            cfg, params, ids[None], pos[None], seg[None], valid[None])
+        lengths = jnp.zeros((rows + 1,), jnp.int32).at[seg].add(1)[:rows]
+        last = starts.astype(jnp.int32) + jnp.maximum(lengths - 1, 0)
+        return x[0][last], _counters(sizes)
+
+
+def init_params(cfg: CausalMoeEmbedderConfig, key):
+    """A parameter tree drawn layer by layer (a tensor of experts is a
+    gigabyte): token embeddings at unit scale, matrices at
+    1/sqrt(fan-in), norms at one."""
+    pd, d, hd, kv = cfg.param_dtype, cfg.hidden_dim, cfg.head_dim, cfg.num_kv_heads
+
+    def normal(key, shape, fan_in):
+        return (jax.random.normal(key, shape, jnp.float32) * fan_in ** -0.5).astype(pd)
+
+    def mlp(key, shape_in, width):
+        k1, k2 = jax.random.split(key)
+        return {"w_gate_up": normal(k1, shape_in + (d, 2 * width), d),
+                "w_down": normal(k2, shape_in + (width, d), width)}
+
+    keys = jax.random.split(key, cfg.num_layers + 1)
+    params = {"tok_emb": jax.random.normal(keys[0], (cfg.vocab_size, d), jnp.float32).astype(pd),
+              "final_norm": jnp.ones((d,), pd)}
+    for i in range(cfg.num_layers):
+        h = cfg.heads_per_layer[i]
+        k = jax.random.split(keys[i + 1], 8)
+        layer = {
+            "attn_norm": jnp.ones((d,), pd), "mlp_norm": jnp.ones((d,), pd),
+            "wq": normal(k[0], (d, h, hd), d), "wk": normal(k[1], (d, kv, hd), d),
+            "wv": normal(k[2], (d, kv, hd), d), "wg": normal(k[3], (d, h), d),
+            "wo": normal(k[4], (h, hd, d), h * hd),
+        }
+        if cfg.mlp_types[i] == "dense":
+            layer["mlp"] = mlp(k[5], (), cfg.dense_mlp_dim)
+        else:
+            layer["moe"] = {
+                "router": normal(k[5], (d, cfg.num_experts), d),
+                **mlp(k[6], (cfg.num_experts,), cfg.expert_dim),
+                "shared": mlp(k[7], (), cfg.shared_expert_dim),
+            }
+        params[f"layer_{i}"] = layer
+    return params
+
+
+def count_params(params) -> int:
+    return sum(int(np.prod(p.shape)) for p in jax.tree_util.tree_leaves(params))

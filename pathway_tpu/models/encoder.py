@@ -18,7 +18,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import os
-from typing import Any, Sequence
+from typing import Any, ClassVar, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -79,6 +79,21 @@ class EncoderConfig:
     #: ops/ragged_attention.py, near-zero padding).  Process default via
     #: PATHWAY_ATTENTION_IMPL (see :func:`default_attention_impl`).
     attention_impl: str = "flax"
+    #: sequence buckets of the dispatch grid and the dtype the parameters
+    #: are held in: the model's own, because a language-model embedder
+    #: takes documents of thousands of tokens and cannot hold float32
+    #: weights (models/causal_moe_embedder.py)
+    seq_buckets: tuple[int, ...] = SEQ_BUCKETS
+    param_dtype: Any = jnp.float32
+
+    #: what :class:`SentenceEncoder` asks of any encoder config: the name
+    #: of its jitted programs in a device trace, and its two forwards
+    program_name: ClassVar[str] = "pw_encoder_forward"
+
+    def build_models(self):
+        """(dense [batch, seq] forward, packed ragged forward) over one
+        parameter tree."""
+        return TransformerEncoder(self), PackedTransformerEncoder(self)
 
 
 def _fused_attention_fn(query, key, value, bias=None, mask=None, **_kw):
@@ -163,14 +178,14 @@ class Block(nn.Module):
         h = nn.MultiHeadDotProductAttention(
             num_heads=cfg.num_heads,
             dtype=cfg.dtype,
-            param_dtype=jnp.float32,
+            param_dtype=cfg.param_dtype,
             name="attention",
             **attn_kwargs,
         )(x, x, mask=mask)
         x = nn.LayerNorm(dtype=jnp.float32, epsilon=cfg.ln_eps, name="ln1")(x + h)
-        h = nn.Dense(cfg.mlp_dim, dtype=cfg.dtype, param_dtype=jnp.float32, name="mlp_in")(x)
+        h = nn.Dense(cfg.mlp_dim, dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="mlp_in")(x)
         h = nn.gelu(h, approximate=False)  # BERT's erf gelu (HF ACT2FN["gelu"])
-        h = nn.Dense(cfg.hidden_dim, dtype=cfg.dtype, param_dtype=jnp.float32, name="mlp_out")(h)
+        h = nn.Dense(cfg.hidden_dim, dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="mlp_out")(h)
         x = nn.LayerNorm(dtype=jnp.float32, epsilon=cfg.ln_eps, name="ln2")(x + h)
         return x
 
@@ -190,17 +205,17 @@ class TransformerEncoder(nn.Module):
         if type_ids is not None:
             type_ids = type_ids.astype(jnp.int32)
         x = nn.Embed(
-            cfg.vocab_size, cfg.hidden_dim, param_dtype=jnp.float32, name="tok_emb"
+            cfg.vocab_size, cfg.hidden_dim, param_dtype=cfg.param_dtype, name="tok_emb"
         )(ids).astype(cfg.dtype)
         pos = nn.Embed(
-            cfg.max_len, cfg.hidden_dim, param_dtype=jnp.float32, name="pos_emb"
+            cfg.max_len, cfg.hidden_dim, param_dtype=cfg.param_dtype, name="pos_emb"
         )(jnp.arange(ids.shape[1])[None, :]).astype(cfg.dtype)
         x = x + pos
         if cfg.type_vocab_size:
             if type_ids is None:
                 type_ids = jnp.zeros_like(ids)
             x = x + nn.Embed(
-                cfg.type_vocab_size, cfg.hidden_dim, param_dtype=jnp.float32,
+                cfg.type_vocab_size, cfg.hidden_dim, param_dtype=cfg.param_dtype,
                 name="type_emb",
             )(type_ids).astype(cfg.dtype)
         x = nn.LayerNorm(dtype=jnp.float32, epsilon=cfg.ln_eps, name="ln_emb")(x)
@@ -260,14 +275,14 @@ class PackedBlock(nn.Module):
         h = nn.MultiHeadDotProductAttention(
             num_heads=cfg.num_heads,
             dtype=cfg.dtype,
-            param_dtype=jnp.float32,
+            param_dtype=cfg.param_dtype,
             name="attention",
             attention_fn=fn,
         )(x, x)
         x = nn.LayerNorm(dtype=jnp.float32, epsilon=cfg.ln_eps, name="ln1")(x + h)
-        h = nn.Dense(cfg.mlp_dim, dtype=cfg.dtype, param_dtype=jnp.float32, name="mlp_in")(x)
+        h = nn.Dense(cfg.mlp_dim, dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="mlp_in")(x)
         h = nn.gelu(h, approximate=False)
-        h = nn.Dense(cfg.hidden_dim, dtype=cfg.dtype, param_dtype=jnp.float32, name="mlp_out")(h)
+        h = nn.Dense(cfg.hidden_dim, dtype=cfg.dtype, param_dtype=cfg.param_dtype, name="mlp_out")(h)
         x = nn.LayerNorm(dtype=jnp.float32, epsilon=cfg.ln_eps, name="ln2")(x + h)
         return x
 
@@ -299,10 +314,10 @@ class PackedTransformerEncoder(nn.Module):
         seg = seg.astype(jnp.int32)
         num_rows = starts.shape[0]
         x = nn.Embed(
-            cfg.vocab_size, cfg.hidden_dim, param_dtype=jnp.float32, name="tok_emb"
+            cfg.vocab_size, cfg.hidden_dim, param_dtype=cfg.param_dtype, name="tok_emb"
         )(ids[None, :]).astype(cfg.dtype)
         x = x + nn.Embed(
-            cfg.max_len, cfg.hidden_dim, param_dtype=jnp.float32, name="pos_emb"
+            cfg.max_len, cfg.hidden_dim, param_dtype=cfg.param_dtype, name="pos_emb"
         )(pos[None, :]).astype(cfg.dtype)
         if cfg.type_vocab_size:
             tids = (
@@ -310,7 +325,7 @@ class PackedTransformerEncoder(nn.Module):
                 else type_ids.astype(jnp.int32)
             )
             x = x + nn.Embed(
-                cfg.type_vocab_size, cfg.hidden_dim, param_dtype=jnp.float32,
+                cfg.type_vocab_size, cfg.hidden_dim, param_dtype=cfg.param_dtype,
                 name="type_emb",
             )(tids[None, :]).astype(cfg.dtype)
         x = nn.LayerNorm(dtype=jnp.float32, epsilon=cfg.ln_eps, name="ln_emb")(x)
@@ -459,6 +474,7 @@ def packed_plan(
     max_length: int,
     batch_multiple: int = 1,
     max_tokens: int | None = None,
+    seq_buckets: Sequence[int] = SEQ_BUCKETS,
 ) -> list[tuple[int, int, np.ndarray]]:
     """Packing plan for per-row token counts: rows grouped by their OWN
     seq bucket (not the batch max), each group chunked to batch buckets.
@@ -467,7 +483,7 @@ def packed_plan(
     lengths = np.asarray(lengths)
     groups: dict[int, list[int]] = {}
     for i, ln in enumerate(lengths):
-        seq = min(_bucket(max(int(ln), 1), SEQ_BUCKETS), max_length)
+        seq = min(_bucket(max(int(ln), 1), seq_buckets), max_length)
         groups.setdefault(seq, []).append(i)
     plan: list[tuple[int, int, np.ndarray]] = []
     for seq in sorted(groups):
@@ -490,6 +506,7 @@ def packed_prepare(
     vocab_size: int = 1 << 31,
     batch_multiple: int = 1,
     max_tokens: int | None = None,
+    seq_buckets: Sequence[int] = SEQ_BUCKETS,
 ) -> tuple[list[tuple], dict]:
     """Host half of the packed dispatch: tokenized rows → padded
     ``(ids, mask, tids, rows)`` chunks ready for device transfer, plus
@@ -501,7 +518,7 @@ def packed_prepare(
     padded_tokens = 0
     row_tokens = 0
     for seq, bb, rows in packed_plan(
-        lengths, max_length, batch_multiple, max_tokens
+        lengths, max_length, batch_multiple, max_tokens, seq_buckets
     ):
         ids, mask, tids = pad_chunk(
             ids_all[rows][:, :seq],
@@ -602,6 +619,7 @@ def ragged_plan(
     max_length: int,
     max_tokens: int | None = None,
     mix_buckets: bool | None = None,
+    seq_buckets: Sequence[int] = SEQ_BUCKETS,
 ) -> list[np.ndarray]:
     """Launch plan for the ragged layout: rows greedily packed until the
     token budget (``max_tokens``, capped by the kernel's VMEM bound) or
@@ -620,7 +638,7 @@ def ragged_plan(
     # the ring path, never through a single-device launch)
     lengths = np.minimum(
         np.maximum(np.asarray(lengths, dtype=np.int64), 1),
-        min(max_length, SEQ_BUCKETS[-1]),
+        min(max_length, seq_buckets[-1]),
     )
     cap = MAX_PACKED_TOKENS if max_tokens is None else min(
         int(max_tokens), MAX_PACKED_TOKENS
@@ -650,7 +668,7 @@ def ragged_plan(
     # no pad rows (a 64-row group must not round to a 128-row unpack)
     by_bucket: dict[int, list[int]] = {}
     for i, ln in enumerate(lengths):
-        seq = min(_bucket(int(ln), SEQ_BUCKETS), max_length)
+        seq = min(_bucket(int(ln), seq_buckets), max_length)
         by_bucket.setdefault(seq, []).append(i)
     for seq in sorted(by_bucket):
         rows = np.asarray(by_bucket[seq], dtype=np.int64)
@@ -674,6 +692,7 @@ def ragged_prepare(
     vocab_size: int = 1 << 31,
     max_tokens: int | None = None,
     mix_buckets: bool | None = None,
+    seq_buckets: Sequence[int] = SEQ_BUCKETS,
 ) -> tuple[list[tuple], dict]:
     """Host half of the ragged dispatch: tokenized rows → packed
     ``(RaggedChunk, rows, tokens)`` launches plus padding stats.  Every
@@ -684,17 +703,19 @@ def ragged_prepare(
 
     lengths = np.minimum(
         np.maximum(np.asarray(mask_all.sum(axis=1), dtype=np.int64), 1),
-        min(max_length, SEQ_BUCKETS[-1]),
+        min(max_length, seq_buckets[-1]),
     )
     ids_dtype = dispatch_dtype(vocab_size)
     prepared: list[tuple] = []
     padded_tokens = 0
-    for rows in ragged_plan(lengths, max_length, max_tokens, mix_buckets):
+    for rows in ragged_plan(
+        lengths, max_length, max_tokens, mix_buckets, seq_buckets
+    ):
         t_real = int(lengths[rows].sum())
         t_bucket = _bucket(t_real, TOKEN_BUCKETS)
         n_rows = _bucket(len(rows), BATCH_BUCKETS)
         dense_s = min(
-            _bucket(int(lengths[rows].max()), SEQ_BUCKETS), max_length
+            _bucket(int(lengths[rows].max()), seq_buckets), max_length
         )
         ids = np.zeros(t_bucket, ids_dtype)
         pos = np.zeros(t_bucket, np.uint16)
@@ -758,6 +779,22 @@ def named_jit(fn, name: str, **jit_kwargs):
     return jax.jit(call, **jit_kwargs)
 
 
+def _peel_launch_counters(apply_fn, record):
+    """``apply_fn`` of a model whose forward returns ``(embeddings,
+    counters)``: hand the counters (a device array, not waited for) to
+    ``record`` and return the embeddings alone.  No ``record``: as it is."""
+    if record is None:
+        return apply_fn
+
+    @functools.wraps(apply_fn)
+    def call(*args, **kwargs):
+        out, counters = apply_fn(*args, **kwargs)
+        record(counters)
+        return out
+
+    return call
+
+
 def _collect_rows(pending, n: int) -> np.ndarray:
     """Host half after the launches: wait for each device result and put
     its real rows in submission order (the host's wait for the device)."""
@@ -778,6 +815,7 @@ def bucketed_dispatch(
     apply_fn, ids_all, mask_all, max_length: int, type_ids_all=None,
     vocab_size: int = 1 << 31, batch_multiple: int = 1,
     packed: bool | None = None, max_tokens: int | None = None,
+    seq_buckets: Sequence[int] = SEQ_BUCKETS,
 ) -> np.ndarray:
     """Pad (batch, seq) to buckets and dispatch chunks through a jitted
     ``apply_fn(ids, mask[, type_ids])`` — one compilation per
@@ -803,6 +841,7 @@ def bucketed_dispatch(
             ids_all, mask_all, max_length,
             type_ids_all=type_ids_all, vocab_size=vocab_size,
             batch_multiple=batch_multiple, max_tokens=max_tokens,
+            seq_buckets=seq_buckets,
         )
         record_padding(
             stats["real_tokens"], stats["padded_tokens"], stats["row_tokens"]
@@ -820,7 +859,7 @@ def bucketed_dispatch(
     # tests (PATHWAY_PACKED_DISPATCH=0 / packed=False)
     longest = int(mask_all.sum(axis=1).max())
     real_tokens = int(mask_all.sum())
-    seq = min(_bucket(longest, SEQ_BUCKETS), max_length)
+    seq = min(_bucket(longest, seq_buckets), max_length)
     ids_all, mask_all = ids_all[:, :seq], mask_all[:, :seq]
     if type_ids_all is not None:
         type_ids_all = type_ids_all[:, :seq]
@@ -897,13 +936,15 @@ class SentenceEncoder:
         extend_positions: int | None = None,
         max_tokens: int | None = None,
         packed: bool | None = None,
+        params: Any = None,
     ):
         #: token budget per device launch (None = PATHWAY_EMBED_MAX_TOKENS)
         self.max_tokens = max_tokens if max_tokens is not None else embed_max_tokens()
         #: per-seq-bucket packed dispatch (None = PATHWAY_PACKED_DISPATCH)
         self.packed = packed
         self.pretrained = False
-        params = None
+        # ``params``: a ready parameter tree of ``cfg``'s model, so that a
+        # model of gigabytes is never drawn twice
         # attention impl: explicit cfg wins; otherwise the process-wide
         # PATHWAY_ATTENTION_IMPL knob (checkpoints pin geometry, never
         # the kernel choice)
@@ -929,7 +970,7 @@ class SentenceEncoder:
         self.cfg = cfg or EncoderConfig(attention_impl=impl)
         if (
             extend_positions is not None
-            and extend_positions > SEQ_BUCKETS[-1]
+            and extend_positions > self.cfg.seq_buckets[-1]
             and mesh is None
         ):
             import warnings
@@ -937,7 +978,7 @@ class SentenceEncoder:
             warnings.warn(
                 f"extend_positions={extend_positions} without a mesh: the "
                 f"single-device dispatch caps sequences at "
-                f"{SEQ_BUCKETS[-1]} tokens, so longer documents will be "
+                f"{self.cfg.seq_buckets[-1]} tokens, so longer documents will be "
                 "truncated — pass mesh= to embed them sequence-parallel",
                 stacklevel=2,
             )
@@ -959,7 +1000,7 @@ class SentenceEncoder:
             self.cfg = dataclasses.replace(self.cfg, max_len=extend_positions)
         self.max_length = min(max_length, self.cfg.max_len)
         self.tokenizer = load_tokenizer(model_name, vocab_size=self.cfg.vocab_size)
-        self.model = TransformerEncoder(self.cfg)
+        self.model, self._packed_model = self.cfg.build_models()
         if params is not None:
             self.params = jax.tree_util.tree_map(jnp.asarray, params)
         else:
@@ -1002,19 +1043,27 @@ class SentenceEncoder:
             self,
             _encoder_params_nbytes,
         )
-        self._apply = instrument_jit(
-            named_jit(self._forward, "pw_encoder_forward"), "encoder.forward"
+        name = self.cfg.program_name
+        # a model whose forward also returns its launch's counters says
+        # where they go (``record_launch``); they stay on the device until
+        # somebody reads the counters
+        record = getattr(self.model, "record_launch", None)
+        self._apply = _peel_launch_counters(
+            instrument_jit(named_jit(self._forward, name), "encoder.forward"),
+            record,
         )
         # packed ragged forward: same params, concatenated-token layout —
         # built unconditionally (construction is free until first trace)
         # so probes can A/B both layouts on one encoder
-        self._packed_model = PackedTransformerEncoder(self.cfg)
-        self._apply_ragged = instrument_jit(
-            named_jit(
-                self._forward_ragged, "pw_encoder_forward_ragged",
-                static_argnames=("dense_s",),
+        self._apply_ragged = _peel_launch_counters(
+            instrument_jit(
+                named_jit(
+                    self._forward_ragged, name + "_ragged",
+                    static_argnames=("dense_s",),
+                ),
+                "encoder.forward_ragged",
             ),
-            "encoder.forward_ragged",
+            record,
         )
 
     def _forward(self, params, ids, mask):
@@ -1047,9 +1096,9 @@ class SentenceEncoder:
             return np.zeros((0, self.dim), dtype=np.float32)
         ids_all, mask_all = self._tokenize(texts)
 
-        if self.mesh is not None and self.max_length > SEQ_BUCKETS[-1]:
+        if self.mesh is not None and self.max_length > self.cfg.seq_buckets[-1]:
             lengths = mask_all.sum(axis=1)
-            long_rows = lengths > SEQ_BUCKETS[-1]
+            long_rows = lengths > self.cfg.seq_buckets[-1]
             if long_rows.any():
                 out = np.zeros((len(texts), self.dim), dtype=np.float32)
                 short = np.where(~long_rows)[0]
@@ -1103,6 +1152,7 @@ class SentenceEncoder:
             batch_multiple=self._batch_multiple,
             packed=self.packed,
             max_tokens=self.max_tokens,
+            seq_buckets=self.cfg.seq_buckets,
         )
 
     def encode_tokenized(self, ids_all, mask_all) -> np.ndarray:
@@ -1129,12 +1179,14 @@ class SentenceEncoder:
             return ragged_prepare(
                 ids_all, mask_all, self.max_length,
                 vocab_size=self.cfg.vocab_size, max_tokens=max_tokens,
+                seq_buckets=self.cfg.seq_buckets,
             )
         prepared, stats = packed_prepare(
             ids_all, mask_all, self.max_length,
             vocab_size=self.cfg.vocab_size,
             batch_multiple=self._batch_multiple,
             max_tokens=max_tokens,
+            seq_buckets=self.cfg.seq_buckets,
         )
         return (
             [
@@ -1177,6 +1229,7 @@ class SentenceEncoder:
         prepared, stats = ragged_prepare(
             ids_all, mask_all, self.max_length,
             vocab_size=self.cfg.vocab_size, max_tokens=self.max_tokens,
+            seq_buckets=self.cfg.seq_buckets,
         )
         record_padding(
             stats["real_tokens"], stats["padded_tokens"], stats["row_tokens"]
@@ -1214,9 +1267,11 @@ class SentenceEncoder:
         if self.cfg.attention_impl == "ragged":
             return self._encode_padded_ragged(ids_all, mask_all, n)
         longest = int(mask_all.sum(axis=1).max())
-        if self.mesh is not None and longest > SEQ_BUCKETS[-1]:
+        if self.mesh is not None and longest > self.cfg.seq_buckets[-1]:
             raise ValueError("batch needs the sequence-parallel ring path")
-        seq = min(_bucket(max(longest, 1), SEQ_BUCKETS), self.max_length)
+        seq = min(
+            _bucket(max(longest, 1), self.cfg.seq_buckets), self.max_length
+        )
         bb = round_batch_to_multiple(
             _bucket(n, BATCH_BUCKETS), self._batch_multiple
         )
@@ -1254,7 +1309,7 @@ class SentenceEncoder:
         from ..internals.flight_recorder import record_padding
 
         longest = int(mask_all.sum(axis=1).max())
-        if self.mesh is not None and longest > SEQ_BUCKETS[-1]:
+        if self.mesh is not None and longest > self.cfg.seq_buckets[-1]:
             # same refusal as the bucketed tick: over-cap documents go
             # sequence-parallel, not silently truncated
             raise ValueError("batch needs the sequence-parallel ring path")
@@ -1264,6 +1319,7 @@ class SentenceEncoder:
             # the fused tick IS the one-launch case — never split it by
             # seq bucket (the whole-tick launch is the contract)
             mix_buckets=True,
+            seq_buckets=self.cfg.seq_buckets,
         )
         if len(prepared) != 1:
             # a tick too big for one launch (token budget / VMEM cap)

@@ -385,6 +385,11 @@ def _merge_topk(cand_s, cand_i, k: int):
 # ---------------------------------------------------------------------------
 
 
+#: the largest corpus block the dense megakernel runs under the compiler's
+#: default VMEM limit (16 MiB): beyond it the call asks for its own
+_DEFAULT_VMEM_BLOCK_BYTES = 2 << 20
+
+
 def pallas_fused_topk(
     q: jax.Array,  # [q_b, D] f32 (widened+padded by the jit wrapper)
     vectors: jax.Array,  # [N, D] f32/bf16
@@ -446,6 +451,19 @@ def pallas_fused_topk(
             s_ref[:] = best_s
 
     grid = (q_b // block_q, n // block_n)
+    # a corpus block is held twice by the pipeline and once more,
+    # transposed, by the product: 1,024 rows of 384 values fit the
+    # compiler's own VMEM limit, rows of 2,048 values (8 MiB a block) do
+    # not.  The block cannot shrink instead: the one-dimensional mask is
+    # tiled by 1,024.  Narrow rows keep the default, and their program.
+    block_bytes = block_n * d * vectors.dtype.itemsize
+    extra = {}
+    if block_bytes > _DEFAULT_VMEM_BLOCK_BYTES and not interpret:
+        from jax.experimental.pallas import tpu as pltpu
+
+        extra["compiler_params"] = pltpu.CompilerParams(
+            vmem_limit_bytes=4 * block_bytes + (16 << 20)
+        )
     return pl.pallas_call(
         kernel,
         out_shape=(
@@ -454,6 +472,7 @@ def pallas_fused_topk(
             jax.ShapeDtypeStruct((q_b, k), jnp.int32),
         ),
         grid=grid,
+        **extra,
         in_specs=[
             pl.BlockSpec((block_q, d), lambda i, j: (i, 0)),
             pl.BlockSpec((block_n, d), lambda i, j: (j, 0)),

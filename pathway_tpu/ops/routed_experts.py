@@ -1,0 +1,102 @@
+"""Routed experts: a float32 router, tokens grouped by expert without
+dropping any, one grouped matrix product over the experts that got tokens,
+the weighted combine.
+
+The layer of a sparse mixture-of-experts block as the Qwen-MoE lineage
+defines it (softmax scores over all experts, the ``top_k`` largest kept,
+their scores renormalised and scaled), for a flat axis of tokens:
+
+* every token is routed and computed: there is no capacity and no drop, so
+  a launch of several documents gives each the result it gets alone;
+* a token that is not ``valid`` (padding) is routed nowhere: it belongs to
+  no group of the grouped product, reads no expert's weights and gets 0;
+* the grouped product is :func:`jax.lax.ragged_dot`, which XLA lowers on a
+  TPU to a grouped-matmul kernel of its own (the device trace shows it as
+  ``ragged-dot``) that visits only the experts whose group is not empty;
+* the router's scores, the choice and the weights are float32 at
+  ``highest`` precision whatever the weights are held in: a score rounded to
+  bfloat16 flips an expert wherever two lie within 2^-8 of each other.
+
+The launch's counters (:func:`launch_counters`) are computed here, from the
+group sizes, and ride back with the forward's result.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+
+__all__ = ["route", "group_tokens", "grouped_matmul", "routed_experts",
+           "launch_counters"]
+
+
+def route(x, router, *, top_k: int, scaling: float):
+    """``x`` [T, D] (any float dtype), ``router`` [D, E] -> the chosen
+    experts [T, top_k] (int32, by falling score) and their weights
+    [T, top_k] (float32): softmax over all E experts in float32, the
+    ``top_k`` largest, divided by their sum, times ``scaling``."""
+    logits = jnp.dot(
+        x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    scores = jax.nn.softmax(logits, axis=-1)
+    top, experts = jax.lax.top_k(scores, top_k)
+    return experts.astype(jnp.int32), top / jnp.sum(top, axis=-1, keepdims=True) * scaling
+
+
+def group_tokens(experts, valid, num_experts: int):
+    """The routed (token, expert) pairs sorted by expert.
+
+    ``experts`` [T, K], ``valid`` [T] bool.  Returns ``order`` [T*K] (the
+    pairs by expert, pairs of padding last), ``group_sizes`` [E] (valid
+    pairs of each expert) and ``inverse`` [T*K] (where each pair went)."""
+    t, k = experts.shape
+    flat = jnp.where(valid[:, None], experts, num_experts).reshape(t * k)
+    order = jnp.argsort(flat, stable=True)
+    group_sizes = jnp.zeros((num_experts + 1,), jnp.int32).at[flat].add(1)[:num_experts]
+    inverse = jnp.zeros((t * k,), jnp.int32).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32))
+    return order, group_sizes, inverse
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """``lhs`` [M, K] rows sorted by group, ``rhs`` [G, K, N] -> [M, N]
+    float32: rows of group ``g`` times ``rhs[g]``.  Rows past the groups'
+    total are not defined: the caller masks them."""
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                              preferred_element_type=jnp.float32)
+
+
+def routed_experts(x, valid, router, w_gate_up, w_down, *, top_k: int,
+                   scaling: float, router_input=None):
+    """Sum over each token's experts of ``weight * E(x)`` with ``E`` a gated
+    MLP (``silu(x Wg) * (x Wu)) Wd``.
+
+    ``x`` [T, D] in the compute dtype, ``valid`` [T] bool, ``router``
+    [D, E], ``w_gate_up`` [E, D, 2F] (gate columns first), ``w_down``
+    [E, F, D]; ``router_input`` is what the router reads where ``x`` is a
+    rounded copy of it.  Returns ([T, D] float32, group sizes [E])."""
+    num_experts, _, two_f = w_gate_up.shape
+    experts, weights = route(x if router_input is None else router_input, router,
+                             top_k=top_k, scaling=scaling)
+    order, group_sizes, inverse = group_tokens(experts, valid, num_experts)
+    rows = x[order // top_k]
+    h = grouped_matmul(rows, w_gate_up, group_sizes)
+    act = (jax.nn.silu(h[:, : two_f // 2]) * h[:, two_f // 2:]).astype(x.dtype)
+    y = grouped_matmul(act, w_down, group_sizes)
+    y = y[inverse].reshape(experts.shape + (y.shape[-1],))
+    keep = valid[:, None, None]
+    out = jnp.sum(jnp.where(keep, y * weights[:, :, None], 0.0), axis=1)
+    return out, group_sizes
+
+
+def launch_counters(group_sizes: list):
+    """int32 [4] of one launch from the group sizes [E] of its routed
+    layers: token-expert pairs routed, experts that got a token (both
+    summed over the layers), each layer's fullest expert summed, the
+    fullest of all (``flight_recorder.record_moe_launch`` adds them up)."""
+    sizes = jnp.stack(group_sizes)
+    fullest = jnp.max(sizes, axis=1)
+    return jnp.stack([
+        jnp.sum(sizes), jnp.sum(sizes > 0), jnp.sum(fullest), jnp.max(fullest),
+    ]).astype(jnp.int32)

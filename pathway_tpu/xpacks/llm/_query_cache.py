@@ -310,6 +310,21 @@ class ResultCache(BoundedLru):
 # ---------------------------------------------------------------------------
 
 
+#: the largest encoder that gets a host twin.  A short query costs the host
+#: about two FLOPs a parameter a token: 16 tokens through 150M parameters
+#: are 5 GFLOP, tens of milliseconds on a few cores, which is as long as a
+#: query waits for the device behind a deep queue.  Past that the twin is
+#: slower than the wait it was built to avoid, and its copy of the weights
+#: (gigabytes, for a language-model embedder) is host memory for nothing.
+COLLAB_MAX_PARAMS = 150_000_000
+
+
+def _param_count(encoder: Any) -> int:
+    import jax
+
+    return sum(int(p.size) for p in jax.tree_util.tree_leaves(encoder.params))
+
+
 class CollabEncoder:
     """CPU twin of a :class:`~pathway_tpu.models.encoder.SentenceEncoder`:
     the SAME flax module applied on the CPU backend over the EXACT param
@@ -340,12 +355,11 @@ class CollabEncoder:
 
             import jax
 
-            from ...models.encoder import TransformerEncoder
-
             cfg = self.encoder.cfg
             if cfg.attention_impl in ("pallas", "ragged"):
                 cfg = dataclasses.replace(cfg, attention_impl="fused")
-            model = TransformerEncoder(cfg)
+            model = cfg.build_models()[0]
+            record = getattr(model, "record_launch", None)
             self._cpu_device = jax.devices("cpu")[0]
             # one D2H per param, once — afterwards the twin never touches
             # the accelerator
@@ -355,7 +369,10 @@ class CollabEncoder:
             )
 
             def forward(params, ids, mask):
-                return model.apply({"params": params}, ids, mask)
+                out = model.apply({"params": params}, ids, mask)
+                # a forward that also returns launch counters: the twin's
+                # launches are not the device's, its counters are dropped
+                return out if record is None else out[0]
 
             self._apply = jax.jit(forward)
 
@@ -369,7 +386,6 @@ class CollabEncoder:
 
         from ...models.encoder import (
             BATCH_BUCKETS,
-            SEQ_BUCKETS,
             _bucket,
             dispatch_dtype,
             pad_chunk,
@@ -377,7 +393,9 @@ class CollabEncoder:
 
         n = ids_all.shape[0]
         longest = max(int(mask_all.sum(axis=1).max()), 1)
-        seq = min(_bucket(longest, SEQ_BUCKETS), ids_all.shape[1])
+        seq = min(
+            _bucket(longest, self.encoder.cfg.seq_buckets), ids_all.shape[1]
+        )
         bb = _bucket(n, BATCH_BUCKETS)
         ids, mask, _ = pad_chunk(
             ids_all[:, :seq], mask_all[:, :seq], bb, seq,
@@ -486,7 +504,11 @@ class QueryCacheStack:
         self._has_encoder = ensure is not None
         self.collab: CollabEncoder | None = None
         if self._has_encoder and self.collab_depth > 0:
-            self.collab = CollabEncoder(ensure())
+            enc = ensure()
+            # a twin pays only where the host embeds a short query in
+            # about a device tick: decided from the parameter count
+            if _param_count(enc) <= COLLAB_MAX_PARAMS:
+                self.collab = CollabEncoder(enc)
         #: queue-depth signal (overridable in tests); reads the runtime's
         #: INTERACTIVE backlog without spawning its thread
         self._depth_fn = self._runtime_depth
