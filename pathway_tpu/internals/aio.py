@@ -16,9 +16,9 @@ import asyncio
 import atexit
 import threading
 from concurrent.futures import Future
-from typing import Any, Coroutine
+from typing import Any, Awaitable, Callable, Coroutine, Iterable
 
-__all__ = ["get_loop", "submit"]
+__all__ = ["get_loop", "submit", "gather_bounded"]
 
 _lock = threading.Lock()
 _loop: asyncio.AbstractEventLoop | None = None
@@ -52,6 +52,25 @@ def submit(coro: Coroutine[Any, Any, Any]) -> Future:
     """Schedule ``coro`` on the persistent loop; returns a concurrent
     Future resolvable from any thread."""
     return asyncio.run_coroutine_threadsafe(coro, get_loop())
+
+
+async def gather_bounded(
+    fn: Callable[[Any], Awaitable[Any]], items: Iterable[Any],
+    capacity: int | None = None,
+) -> list:
+    """``await fn(item)`` for every item at once, in order, at most
+    ``capacity`` of them in flight (an async UDF's ``capacity``): what lets
+    every row of an engine batch be pending in the device-tick runtime
+    before any is awaited, so that they ride one tick."""
+    if not capacity:
+        return await asyncio.gather(*[fn(item) for item in items])
+    sem = asyncio.Semaphore(capacity)
+
+    async def one(item):
+        async with sem:
+            return await fn(item)
+
+    return await asyncio.gather(*[one(item) for item in items])
 
 
 def _shutdown() -> None:

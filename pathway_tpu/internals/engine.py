@@ -27,7 +27,6 @@ flushing a node's input ports in ascending port order.
 
 from __future__ import annotations
 
-import asyncio
 import contextlib
 import gc
 import itertools
@@ -1330,20 +1329,9 @@ class AsyncMapNode(Node):
         self._scheduled: set[tuple] = set()
 
     def _dispatch(self, rows: list):
-        from .aio import submit
+        from .aio import gather_bounded, submit
 
-        async def runner():
-            sem = asyncio.Semaphore(self.capacity) if self.capacity else None
-
-            async def one(row):
-                if sem is None:
-                    return await self.async_fn(row)
-                async with sem:
-                    return await self.async_fn(row)
-
-            return await asyncio.gather(*[one(r) for r in rows])
-
-        return submit(runner())
+        return submit(gather_bounded(self.async_fn, rows, self.capacity))
 
     def flush(self, time: int) -> list[Entry]:
         entries = self.take(0)

@@ -124,8 +124,11 @@ def compile_expression(
         return run_unary
 
     if isinstance(e, (expr_mod.ApplyExpression,)):
-        # Async applies are handled at the operator level (AsyncMapNode);
-        # when reached here they run synchronously via the event loop.
+        # Async applies are lifted out of an operator's expressions where
+        # the operator can gather them (runtime.py ``AsyncSlots``: select
+        # and filter through AsyncMapNode, the external index a whole
+        # flush at once); one that is reached here, inside a join or a
+        # reduce, runs alone on an event loop of its own.
         arg_fns = [rec(a) for a in e.args]
         kwarg_fns = {k: rec(v) for k, v in e.kwargs.items()}
         fun = e.fun

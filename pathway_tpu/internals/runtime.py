@@ -8,8 +8,9 @@ of a PyO3-bridged Rust engine.
 
 from __future__ import annotations
 
-from typing import Any, Callable
+from typing import Any, Callable, Iterable
 
+from ..testing import faults
 from . import dtype as dt
 from .engine import (
     AsyncMapNode,
@@ -27,12 +28,13 @@ from .engine import (
     UpdateRowsNode,
     ZipNode,
 )
-from .evaluator import compile_expression
+from .evaluator import EvalContext, compile_expression
 from .expression import (
     AsyncApplyExpression,
     ColumnConstExpression,
     ColumnExpression,
     ColumnReference,
+    FullyAsyncApplyExpression,
     IdExpression,
     ApplyExpression,
 )
@@ -57,16 +59,111 @@ class _SlotExpression(ColumnExpression):
         return self._slot_dtype
 
 
-def _contains_async(e: ColumnExpression) -> bool:
-    if isinstance(e, AsyncApplyExpression):
-        return True
-    return any(_contains_async(d) for d in e._deps())
-
-
 def _contains_nondeterministic(e: ColumnExpression) -> bool:
     if isinstance(e, ApplyExpression) and not e.deterministic:
         return True
     return any(_contains_nondeterministic(d) for d in e._deps())
+
+
+class AsyncSlots:
+    """The async applies of an operator's expressions, lifted out of them.
+
+    Each :class:`AsyncApplyExpression` (found once, by identity) becomes a
+    slot: ``extend`` evaluates one row's slots and appends their results to
+    the row, and ``substitute`` rewrites an expression to read slot ``i``
+    at ``base_width + i`` of such a row, so that what is left of it
+    compiles synchronously.  ``select``/``filter`` run ``extend`` through
+    an :class:`AsyncMapNode`; the external index
+    (stdlib/indexing/lowering.py) through ``extend_all``, every row of a
+    flush in one gather on the process's persistent loop."""
+
+    def __init__(
+        self,
+        exprs: Iterable[ColumnExpression],
+        resolve: Callable,
+        base_width: int,
+        op_name: str,
+    ):
+        self.slots: list[AsyncApplyExpression] = []
+
+        def collect(e: ColumnExpression) -> None:
+            if isinstance(e, AsyncApplyExpression):
+                if not any(e is s for s in self.slots):
+                    self.slots.append(e)
+                return
+            for d in e._deps():
+                collect(d)
+
+        for e in exprs:
+            collect(e)
+        self.base_width = base_width
+        self.op_name = op_name
+        #: any fully_async slot makes a select pipelined (results land one
+        #: engine step later; device work overlaps host ingest)
+        self.pipelined = any(
+            isinstance(s, FullyAsyncApplyExpression) for s in self.slots
+        )
+        self.deterministic = all(s.deterministic for s in self.slots)
+        #: rows in flight at once: the tightest ``capacity`` of the slots
+        caps = [c for c in (getattr(s, "capacity", None) for s in self.slots) if c]
+        self.capacity: int | None = min(caps) if caps else None
+        self._slot_fns = [
+            (
+                s.fun,
+                [compile_expression(a, resolve) for a in s.args],
+                {k: compile_expression(v, resolve) for k, v in s.kwargs.items()},
+                s.propagate_none,
+            )
+            for s in self.slots
+        ]
+
+    def __len__(self) -> int:
+        return len(self.slots)
+
+    def substitute(self, e: ColumnExpression) -> ColumnExpression:
+        def subst(node: ColumnExpression) -> ColumnExpression | None:
+            for i, s in enumerate(self.slots):
+                if node is s:
+                    return _SlotExpression(self.base_width + i, s.return_type)
+            return None
+
+        return e._substitute(subst)
+
+    async def extend(self, ctx: tuple) -> tuple:
+        """``(key, values)`` -> ``(key, values + slot results)``."""
+        key, values = ctx
+        results = []
+        for fun, arg_fns, kwarg_fns, propagate_none in self._slot_fns:
+            args = [f(ctx) for f in arg_fns]
+            kwargs = {k: f(ctx) for k, f in kwarg_fns.items()}
+            if any(a is ERROR for a in args) or any(
+                v is ERROR for v in kwargs.values()
+            ):
+                results.append(ERROR)
+                continue
+            if propagate_none and any(a is None for a in args):
+                results.append(None)
+                continue
+            # failure domain: an async UDF whose retries are exhausted
+            # must not tear down the engine loop — under
+            # terminate_on_error=False its own row carries ERROR and the
+            # failure lands in the global error log
+            try:
+                if faults.enabled:
+                    faults.perturb("udf")
+                results.append(await fun(*args, **kwargs))
+            except Exception as exc:  # noqa: BLE001 — routed
+                results.append(
+                    EvalContext.handle(exc, kind="udf", operator=self.op_name)
+                )
+        return (key, tuple(values) + tuple(results))
+
+    def extend_all(self, ctxs: list[tuple]) -> list[tuple]:
+        """``extend`` for every row at once, blocking: all of them are
+        pending (at most ``capacity`` in flight) before any is awaited."""
+        from .aio import gather_bounded, submit
+
+        return submit(gather_bounded(self.extend, ctxs, self.capacity)).result()
 
 
 class _TableLayout:
@@ -97,7 +194,7 @@ class _TableLayout:
             return None if idx is None else off + idx
         return None
 
-    def resolver(self, extra_slots: int = 0):
+    def resolver(self):
         def resolve(ref: ColumnReference) -> Callable:
             if isinstance(ref, _SlotExpression):
                 idx = ref.flat_idx
@@ -217,76 +314,10 @@ class GraphRunner:
             self.engine.add(zip_node)
             self._connect_inputs(op, zip_node)
             upstream = zip_node
-        # async slots
-        async_slots: list[AsyncApplyExpression] = []
-
-        def collect_async(e: ColumnExpression):
-            if isinstance(e, AsyncApplyExpression):
-                if not any(e is s for s in async_slots):
-                    async_slots.append(e)
-                return
-            for d in e._deps():
-                collect_async(d)
-
-        for e in exprs.values():
-            collect_async(e)
-
-        extra = 0
-        if async_slots:
-            from .expression import FullyAsyncApplyExpression
-
-            # any fully_async slot makes the whole node pipelined (results
-            # land one engine step later; device work overlaps host ingest)
-            pipelined = any(
-                isinstance(s, FullyAsyncApplyExpression) for s in async_slots
-            )
-            resolve = layout.resolver()
-            slot_fns = []
-            capacity = None
-            for s in async_slots:
-                arg_fns = [compile_expression(a, resolve) for a in s.args]
-                kwarg_fns = {k: compile_expression(v, resolve) for k, v in s.kwargs.items()}
-                fun = s.fun
-                slot_fns.append((fun, arg_fns, kwarg_fns, s.propagate_none))
-                cap = getattr(s, "capacity", None)
-                if cap is not None:
-                    capacity = cap if capacity is None else min(capacity, cap)
-
-            op_name = f"async#{op.id}"
-
-            async def async_fn(row, _slot_fns=slot_fns, _op=op_name):
-                from ..testing import faults
-                from .evaluator import EvalContext
-                from .value import ERROR
-
-                key, values = row
-                ctx = (key, values)
-                results = []
-                for fun, arg_fns, kwarg_fns, propagate_none in _slot_fns:
-                    args = [f(ctx) for f in arg_fns]
-                    kwargs = {k: f(ctx) for k, f in kwarg_fns.items()}
-                    if any(a is ERROR for a in args) or any(
-                        v is ERROR for v in kwargs.values()
-                    ):
-                        results.append(ERROR)
-                        continue
-                    if propagate_none and any(a is None for a in args):
-                        results.append(None)
-                        continue
-                    # failure domain: an async UDF whose retries are
-                    # exhausted must not tear down the engine loop — under
-                    # terminate_on_error=False the row carries ERROR and
-                    # the failure lands in the global error log
-                    try:
-                        if faults.enabled:
-                            faults.perturb("udf")
-                        results.append(await fun(*args, **kwargs))
-                    except Exception as exc:  # noqa: BLE001 — routed
-                        results.append(
-                            EvalContext.handle(exc, kind="udf", operator=_op)
-                        )
-                return (key, tuple(values) + tuple(results))
-
+        slots = AsyncSlots(
+            exprs.values(), layout.resolver(), layout.width, f"async#{op.id}"
+        )
+        if slots:
             # AsyncMapNode operates on rows; we need key in ctx, so wrap rows
             wrap_in = RowwiseNode(
                 lambda key, row, diff: [(key, ((key, row),), diff)],
@@ -298,9 +329,9 @@ class GraphRunner:
             else:
                 upstream.downstream.append((wrap_in, 0))
             amap = AsyncMapNode(
-                lambda row: async_fn(row[0]),
-                capacity=capacity,
-                pipelined=pipelined,
+                lambda row: slots.extend(row[0]),
+                capacity=slots.capacity,
+                pipelined=slots.pipelined,
                 name=f"async#{op.id}",
             )
             # recovery-plane coverage: the node's only cross-step state is
@@ -308,9 +339,7 @@ class GraphRunner:
             # post-restart retraction recomputes the identical value, so
             # an empty memo is safe and OPERATOR_PERSISTING may cover the
             # graph (non-deterministic slots keep the refusal)
-            amap._slots_deterministic = all(
-                s.deterministic for s in async_slots
-            )
+            amap._slots_deterministic = slots.deterministic
             self.engine.add(amap)
             wrap_in.downstream.append((amap, 0))
             unwrap = RowwiseNode(
@@ -320,19 +349,9 @@ class GraphRunner:
             self.engine.add(unwrap)
             amap.downstream.append((unwrap, 0))
             upstream = unwrap
-            # substitute async subtrees with slot refs
-            base_width = layout.width
+            exprs = {n: slots.substitute(e) for n, e in exprs.items()}
 
-            def subst(node: ColumnExpression) -> ColumnExpression | None:
-                for i, s in enumerate(async_slots):
-                    if node is s:
-                        return _SlotExpression(base_width + i, s.return_type)
-                return None
-
-            exprs = {n: e._substitute(subst) for n, e in exprs.items()}
-            extra = len(async_slots)
-
-        resolve = layout.resolver(extra)
+        resolve = layout.resolver()
         fns = [compile_expression(e, resolve) for e in exprs.values()]
         final = final_builder(fns, layout)
         self.engine.add(final)

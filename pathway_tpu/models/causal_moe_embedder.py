@@ -105,6 +105,10 @@ class CausalMoeEmbedderConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.bfloat16
     seq_buckets: tuple[int, ...] = (32, 64, 128, 256, 512, 1024, 2048)
+    #: one row a launch: each (rows, sequence) program of a model this size
+    #: takes seconds to compile and about a second to load, and the rows of
+    #: a batch go out back to back before any result is collected
+    batch_buckets: tuple[int, ...] = (1,)
     #: queries a block of attention takes at once
     q_block: int = 512
     #: "xla" (the dense [batch, seq] dispatch) or "ragged" (packed launches)

@@ -51,6 +51,10 @@ SEQ_BUCKETS = (32, 64, 128, 256, 512)
 # 2/4 steps keep low-occupancy ticks pay-for-what-you-use at the cost of
 # two extra compiles per sequence bucket
 BATCH_BUCKETS = (1, 2, 4, 8, 32, 128, 256, 512, 1024)
+#: a group of rows goes out in exact-fill launches while this many remain
+#: (``_chunk_sizes``); what is left, or a group that never had as many, is
+#: its tail
+TAIL_ROWS = 32
 
 
 @dataclasses.dataclass(frozen=True)
@@ -85,6 +89,11 @@ class EncoderConfig:
     #: weights (models/causal_moe_embedder.py)
     seq_buckets: tuple[int, ...] = SEQ_BUCKETS
     param_dtype: Any = jnp.float32
+    #: row counts a launch may take, the model's own like the sequence
+    #: buckets: every (rows, sequence) pair is one compiled program, which a
+    #: bulk load amortises for a BERT and nothing does for a model of
+    #: gigabytes (models/causal_moe_embedder.py)
+    batch_buckets: tuple[int, ...] = BATCH_BUCKETS
 
     #: what :class:`SentenceEncoder` asks of any encoder config: the name
     #: of its jitted programs in a device trace, and its two forwards
@@ -438,7 +447,9 @@ def embed_max_tokens() -> int | None:
 
 
 def _chunk_sizes(
-    n: int, seq: int, batch_multiple: int, max_tokens: int | None
+    n: int, seq: int, batch_multiple: int, max_tokens: int | None,
+    batch_buckets: Sequence[int] = BATCH_BUCKETS,
+    lone_tail: bool = False,
 ) -> list[int]:
     """Batch-bucket decomposition of an ``n``-row group at seq bucket
     ``seq``: exact-fill with the largest admissible bucket while at least
@@ -446,20 +457,24 @@ def _chunk_sizes(
     512-padded launch), then one padded launch for the small tail (the
     1/2/4/8 buckets exist precisely to keep tiny groups cheap).  A token
     budget caps the bucket at ``max_tokens // seq`` so batch size adapts
-    to document length."""
-    allowed = list(BATCH_BUCKETS)
+    to document length.  With ``lone_tail`` the tail goes out in launches
+    of the smallest bucket instead, one row each: the programs a lone row
+    has compiled, whatever else arrived with it."""
+    allowed = list(batch_buckets)
     if max_tokens is not None:
         cap = max(max_tokens // max(seq, 1), 1)
         capped = [b for b in allowed if b <= cap]
         allowed = capped or allowed[:1]
     out: list[int] = []
     remaining = n
-    while remaining >= 32 and allowed[-1] >= 32:
+    while remaining >= TAIL_ROWS and allowed[-1] >= TAIL_ROWS:
         bb = max(b for b in allowed if b <= remaining) if remaining >= allowed[0] else allowed[0]
-        if bb < 32:
+        if bb < TAIL_ROWS:
             break
         out.append(bb)
         remaining -= bb
+    if lone_tail and remaining < TAIL_ROWS:
+        allowed = allowed[:1]
     while remaining > 0:
         bb = _bucket(remaining, allowed)
         out.append(bb)
@@ -475,6 +490,8 @@ def packed_plan(
     batch_multiple: int = 1,
     max_tokens: int | None = None,
     seq_buckets: Sequence[int] = SEQ_BUCKETS,
+    batch_buckets: Sequence[int] = BATCH_BUCKETS,
+    lone_tail: bool = False,
 ) -> list[tuple[int, int, np.ndarray]]:
     """Packing plan for per-row token counts: rows grouped by their OWN
     seq bucket (not the batch max), each group chunked to batch buckets.
@@ -489,7 +506,10 @@ def packed_plan(
     for seq in sorted(groups):
         rows = np.asarray(groups[seq], dtype=np.int64)
         start = 0
-        for bb in _chunk_sizes(len(rows), seq, batch_multiple, max_tokens):
+        for bb in _chunk_sizes(
+            len(rows), seq, batch_multiple, max_tokens, batch_buckets,
+            lone_tail,
+        ):
             take = min(bb, len(rows) - start)
             plan.append((seq, bb, rows[start : start + take]))
             start += take
@@ -507,6 +527,8 @@ def packed_prepare(
     batch_multiple: int = 1,
     max_tokens: int | None = None,
     seq_buckets: Sequence[int] = SEQ_BUCKETS,
+    batch_buckets: Sequence[int] = BATCH_BUCKETS,
+    lone_tail: bool = False,
 ) -> tuple[list[tuple], dict]:
     """Host half of the packed dispatch: tokenized rows → padded
     ``(ids, mask, tids, rows)`` chunks ready for device transfer, plus
@@ -518,7 +540,8 @@ def packed_prepare(
     padded_tokens = 0
     row_tokens = 0
     for seq, bb, rows in packed_plan(
-        lengths, max_length, batch_multiple, max_tokens, seq_buckets
+        lengths, max_length, batch_multiple, max_tokens, seq_buckets,
+        batch_buckets, lone_tail,
     ):
         ids, mask, tids = pad_chunk(
             ids_all[rows][:, :seq],
@@ -620,6 +643,7 @@ def ragged_plan(
     max_tokens: int | None = None,
     mix_buckets: bool | None = None,
     seq_buckets: Sequence[int] = SEQ_BUCKETS,
+    batch_buckets: Sequence[int] = BATCH_BUCKETS,
 ) -> list[np.ndarray]:
     """Launch plan for the ragged layout: rows greedily packed until the
     token budget (``max_tokens``, capped by the kernel's VMEM bound) or
@@ -654,7 +678,7 @@ def ragged_plan(
         for j, r in enumerate(rows):
             if j > start and (
                 total + int(lengths[r]) > cap
-                or j - start >= BATCH_BUCKETS[-1]
+                or j - start >= batch_buckets[-1]
             ):
                 groups.append(rows[start:j])
                 start, total = j, 0
@@ -675,7 +699,7 @@ def ragged_plan(
         # bb*seq bounds the chunk's real tokens, so the VMEM/budget cap
         # holds a fortiori on the ragged axis
         start = 0
-        for bb in _chunk_sizes(len(rows), seq, 1, cap):
+        for bb in _chunk_sizes(len(rows), seq, 1, cap, batch_buckets):
             take = min(bb, len(rows) - start)
             groups.append(rows[start : start + take])
             start += take
@@ -693,6 +717,7 @@ def ragged_prepare(
     max_tokens: int | None = None,
     mix_buckets: bool | None = None,
     seq_buckets: Sequence[int] = SEQ_BUCKETS,
+    batch_buckets: Sequence[int] = BATCH_BUCKETS,
 ) -> tuple[list[tuple], dict]:
     """Host half of the ragged dispatch: tokenized rows → packed
     ``(RaggedChunk, rows, tokens)`` launches plus padding stats.  Every
@@ -709,11 +734,12 @@ def ragged_prepare(
     prepared: list[tuple] = []
     padded_tokens = 0
     for rows in ragged_plan(
-        lengths, max_length, max_tokens, mix_buckets, seq_buckets
+        lengths, max_length, max_tokens, mix_buckets, seq_buckets,
+        batch_buckets,
     ):
         t_real = int(lengths[rows].sum())
         t_bucket = _bucket(t_real, TOKEN_BUCKETS)
-        n_rows = _bucket(len(rows), BATCH_BUCKETS)
+        n_rows = _bucket(len(rows), batch_buckets)
         dense_s = min(
             _bucket(int(lengths[rows].max()), seq_buckets), max_length
         )
@@ -801,6 +827,12 @@ def _collect_rows(pending, n: int) -> np.ndarray:
     from ..internals.flight_recorder import span
 
     with span("embed.d2h_wait", "encoder", stage="embed.d2h_wait", rows=n):
+        # ask for every copy before waiting for the first: the results of
+        # many small launches then come back together, not a trip each
+        for res, _rows in pending:
+            start_copy = getattr(res, "copy_to_host_async", None)
+            if start_copy is not None:
+                start_copy()
         out: np.ndarray | None = None
         for res, rows in pending:
             res = np.asarray(res, dtype=np.float32)
@@ -816,6 +848,8 @@ def bucketed_dispatch(
     vocab_size: int = 1 << 31, batch_multiple: int = 1,
     packed: bool | None = None, max_tokens: int | None = None,
     seq_buckets: Sequence[int] = SEQ_BUCKETS,
+    batch_buckets: Sequence[int] = BATCH_BUCKETS,
+    lone_tail: bool = False,
 ) -> np.ndarray:
     """Pad (batch, seq) to buckets and dispatch chunks through a jitted
     ``apply_fn(ids, mask[, type_ids])`` — one compilation per
@@ -841,7 +875,8 @@ def bucketed_dispatch(
             ids_all, mask_all, max_length,
             type_ids_all=type_ids_all, vocab_size=vocab_size,
             batch_multiple=batch_multiple, max_tokens=max_tokens,
-            seq_buckets=seq_buckets,
+            seq_buckets=seq_buckets, batch_buckets=batch_buckets,
+            lone_tail=lone_tail,
         )
         record_padding(
             stats["real_tokens"], stats["padded_tokens"], stats["row_tokens"]
@@ -864,7 +899,7 @@ def bucketed_dispatch(
     if type_ids_all is not None:
         type_ids_all = type_ids_all[:, :seq]
     b = ids_all.shape[0]
-    bb = _bucket(b, BATCH_BUCKETS)
+    bb = _bucket(b, batch_buckets)
     if bb % batch_multiple:
         # legacy path rounds UNCONDITIONALLY (pre-PR8 behavior, kept as
         # the A/B reference) — the conditional shard-vs-replicate policy
@@ -1143,6 +1178,13 @@ class SentenceEncoder:
                 mask = jax.device_put(mask, sharding)
             return self._apply(self.params, ids, mask)
 
+        # under TAIL_ROWS rows of a sequence bucket (the files of one scan,
+        # the queries of one engine step, what a bulk load leaves over) go
+        # out one row a launch, back to back: which small multi-row bucket
+        # they would hit depends on what arrived together, and each
+        # (rows, sequence) program takes half a second to load from the
+        # compile cache and seconds to compile, in set-up or, worse, under
+        # live traffic.  Thirty-two rows and more take the row buckets
         return bucketed_dispatch(
             dispatch,
             ids_all,
@@ -1153,6 +1195,8 @@ class SentenceEncoder:
             packed=self.packed,
             max_tokens=self.max_tokens,
             seq_buckets=self.cfg.seq_buckets,
+            batch_buckets=self.cfg.batch_buckets,
+            lone_tail=True,
         )
 
     def encode_tokenized(self, ids_all, mask_all) -> np.ndarray:
@@ -1180,6 +1224,7 @@ class SentenceEncoder:
                 ids_all, mask_all, self.max_length,
                 vocab_size=self.cfg.vocab_size, max_tokens=max_tokens,
                 seq_buckets=self.cfg.seq_buckets,
+                batch_buckets=self.cfg.batch_buckets,
             )
         prepared, stats = packed_prepare(
             ids_all, mask_all, self.max_length,
@@ -1187,6 +1232,7 @@ class SentenceEncoder:
             batch_multiple=self._batch_multiple,
             max_tokens=max_tokens,
             seq_buckets=self.cfg.seq_buckets,
+            batch_buckets=self.cfg.batch_buckets,
         )
         return (
             [
@@ -1230,6 +1276,7 @@ class SentenceEncoder:
             ids_all, mask_all, self.max_length,
             vocab_size=self.cfg.vocab_size, max_tokens=self.max_tokens,
             seq_buckets=self.cfg.seq_buckets,
+            batch_buckets=self.cfg.batch_buckets,
         )
         record_padding(
             stats["real_tokens"], stats["padded_tokens"], stats["row_tokens"]
@@ -1261,7 +1308,7 @@ class SentenceEncoder:
         Raises ``ValueError`` when the batch exceeds the largest dispatch
         bucket (callers fall back to :meth:`encode`)."""
         n = len(texts)
-        if n == 0 or n > BATCH_BUCKETS[-1]:
+        if n == 0 or n > self.cfg.batch_buckets[-1]:
             raise ValueError(f"batch of {n} outside the dispatch buckets")
         ids_all, mask_all = self._tokenize(texts)
         if self.cfg.attention_impl == "ragged":
@@ -1273,7 +1320,7 @@ class SentenceEncoder:
             _bucket(max(longest, 1), self.cfg.seq_buckets), self.max_length
         )
         bb = round_batch_to_multiple(
-            _bucket(n, BATCH_BUCKETS), self._batch_multiple
+            _bucket(n, self.cfg.batch_buckets), self._batch_multiple
         )
         if self.max_tokens is not None and bb * seq > self.max_tokens:
             # the token budget bounds EVERY launch's padded mass
@@ -1320,6 +1367,7 @@ class SentenceEncoder:
             # seq bucket (the whole-tick launch is the contract)
             mix_buckets=True,
             seq_buckets=self.cfg.seq_buckets,
+            batch_buckets=self.cfg.batch_buckets,
         )
         if len(prepared) != 1:
             # a tick too big for one launch (token budget / VMEM cap)

@@ -25,7 +25,7 @@ import numpy as np
 from ...internals.engine import Entry, Node, consolidate
 from ...internals.evaluator import compile_expression
 from ...internals.value import ERROR
-from ...internals.runtime import GraphRunner, _TableLayout
+from ...internals.runtime import AsyncSlots, GraphRunner, _TableLayout
 from ...internals.graph import Operator
 
 __all__ = [
@@ -67,8 +67,15 @@ class ExternalIndexNode(Node):
         doc_payload_fn,
         mode: str = "asof_now",
         name: str = "external_index",
+        doc_slots=None,
+        query_slots=None,
     ):
         super().__init__(n_inputs=2, name=name)
+        #: the async applies (an embedder) lifted out of the document and
+        #: query expressions (``AsyncSlots``; empty or None where they hold
+        #: none): the ``*_fn`` then read their results from the row
+        self.doc_slots = doc_slots
+        self.query_slots = query_slots
         self.index = index
         self.doc_data_fn = doc_data_fn
         self.doc_meta_fn = doc_meta_fn
@@ -130,9 +137,10 @@ class ExternalIndexNode(Node):
             index_changed = True
             from ...internals.flight_recorder import span
 
-            # the index data expression is evaluated here, row by row: for
-            # a vector index that is the embedder, so each document waits
-            # for a device tick of its own inside this span
+            # the index data expression is evaluated here: for a vector
+            # index that is the embedder, whose calls for every row of the
+            # flush are pending in the tick runtime before any is awaited,
+            # so that the flush rides one device tick inside this span
             with span(
                 "index.doc_data", "index", stage="index.doc_data",
                 rows=len(updates),
@@ -251,8 +259,10 @@ class ExternalIndexNode(Node):
     def _collect_updates(self, updates, last: dict, payloads: dict) -> None:
         """Evaluate index data, metadata and payload of each update; within
         one timestamp a key's FINAL entry decides its state."""
-        for key, row, diff in updates:
-            ctx = (key, row)
+        ctxs = [(key, row) for key, row, _diff in updates]
+        if self.doc_slots:
+            ctxs = self.doc_slots.extend_all(ctxs)
+        for (key, _row, diff), ctx in zip(updates, ctxs):
             data = self.doc_data_fn(ctx)
             meta = self.doc_meta_fn(ctx)
             if data is ERROR or meta is ERROR:
@@ -589,8 +599,10 @@ class ExternalIndexNode(Node):
 
     def _answer(self, rows: list[tuple]) -> list[tuple]:
         queries = []
-        for row in rows:
-            ctx = (None, row)
+        ctxs = [(None, row) for row in rows]
+        if self.query_slots:
+            ctxs = self.query_slots.extend_all(ctxs)
+        for ctx in ctxs:
             q = self.query_data_fn(ctx)
             k = self.query_k_fn(ctx)
             flt = self.query_filter_fn(ctx)
@@ -645,35 +657,42 @@ class ExternalIndexNode(Node):
 
 def lower_external_index(runner: GraphRunner, op: Operator) -> None:
     docs_t, query_t = op.inputs
-    dlayout = _TableLayout([docs_t])
-    qlayout = _TableLayout([query_t])
-    dresolve = dlayout.resolver()
-    qresolve = qlayout.resolver()
-
     p = op.params
     index = p["factory"].build_inner_index()
-    doc_data_fn = compile_expression(p["index_data"], dresolve)
-    meta = p.get("index_metadata")
-    doc_meta_fn = (
-        compile_expression(meta, dresolve) if meta is not None else (lambda ctx: None)
+    name = f"index#{op.id}"
+
+    def compile_side(table, exprs: list):
+        """One input's expressions (None where the operator has none) as
+        functions of a row, their async applies (the embedder) lifted into
+        slots the ``select`` lowering's way; expressions that hold none
+        evaluate row by row as they are."""
+        layout = _TableLayout([table])
+        resolve = layout.resolver()
+        slots = AsyncSlots(
+            [e for e in exprs if e is not None], resolve, layout.width, name
+        )
+        fns = [
+            e if e is None else compile_expression(slots.substitute(e), resolve)
+            for e in exprs
+        ]
+        return fns, slots
+
+    k = p.get("k", 3)
+    (doc_data_fn, doc_meta_fn, *payload_fns), doc_slots = compile_side(
+        docs_t,
+        [p["index_data"], p.get("index_metadata"), *p.get("payload_exprs", [])],
     )
-    payload_fns = [
-        compile_expression(e, dresolve) for e in p.get("payload_exprs", [])
-    ]
+    (query_data_fn, query_k_fn, query_filter_fn), query_slots = compile_side(
+        query_t,
+        [p["query_data"], k if hasattr(k, "_dtype") else None,
+         p.get("query_filter")],
+    )
+    doc_meta_fn = doc_meta_fn or (lambda ctx: None)
+    query_k_fn = query_k_fn or (lambda ctx, _k=k: _k)
+    query_filter_fn = query_filter_fn or (lambda ctx: None)
 
     def doc_payload_fn(ctx):
         return tuple(f(ctx) for f in payload_fns)
-
-    query_data_fn = compile_expression(p["query_data"], qresolve)
-    k = p.get("k", 3)
-    if hasattr(k, "_dtype"):
-        query_k_fn = compile_expression(k, qresolve)
-    else:
-        query_k_fn = lambda ctx, _k=k: _k
-    flt = p.get("query_filter")
-    query_filter_fn = (
-        compile_expression(flt, qresolve) if flt is not None else (lambda ctx: None)
-    )
 
     node = ExternalIndexNode(
         index,
@@ -684,7 +703,9 @@ def lower_external_index(runner: GraphRunner, op: Operator) -> None:
         query_filter_fn,
         doc_payload_fn,
         mode=p.get("mode", "asof_now"),
-        name=f"index#{op.id}",
+        name=name,
+        doc_slots=doc_slots,
+        query_slots=query_slots,
     )
     runner.engine.add(node)
     runner._connect_inputs(op, node)
