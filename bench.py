@@ -95,13 +95,8 @@ def measure_device(dev, seconds: float = 10.0) -> None:
     ids_all, mask_all = enc.tokenizer.encode_batch(docs, max_length=enc.max_length)
     fwd = lambda i, m: enc._apply(enc.params, i, m)  # noqa: E731
     vocab = enc.cfg.vocab_size
-    # headline path: per-seq-bucket packed dispatch (BENCH_PACKED=0 pins
-    # the legacy whole-batch padding for A/B)
-    packed_default = os.environ.get("BENCH_PACKED", "1") != "0"
 
-    def measure(
-        batch: int, packed: bool = packed_default, ragged_enc=None
-    ) -> float:
+    def measure(batch: int, ragged_enc=None) -> float:
         """Steady-state forward throughput at one chunk size (already
         warm).  ``ragged_enc`` (or a ragged headline, BENCH_ATTN=ragged)
         routes through that encoder's own ragged dispatch —
@@ -126,7 +121,6 @@ def measure_device(dev, seconds: float = 10.0) -> None:
                         mask_all[start:stop],
                         enc.max_length,
                         vocab_size=vocab,
-                        packed=packed,
                     )
                 n_docs += stop - start
             if time.perf_counter() - t0 > seconds:
@@ -135,7 +129,7 @@ def measure_device(dev, seconds: float = 10.0) -> None:
 
     # padding accounting for the headline path: one packed_prepare pass
     # over the measurement slices tells how many padded tokens the device
-    # actually computes per real token (the packed win over whole-batch)
+    # actually computes per real token
     from pathway_tpu.models.encoder import packed_prepare
 
     def _padding_eff(batch: int) -> float:
@@ -153,7 +147,6 @@ def measure_device(dev, seconds: float = 10.0) -> None:
 
     extra: dict = {
         "corpus": "mixed_seq32/64/128",
-        "packed": packed_default,
         # every measured variant labels the attention impl it ran
         "attn_impl_by_variant": {"headline": attn},
     }
@@ -263,30 +256,19 @@ def measure_device(dev, seconds: float = 10.0) -> None:
     if attn == "ragged":
         enc.encode_tokenized(ids_all[:small], mask_all[:small])
     else:
-        bucketed_dispatch(fwd, ids_all[:small], mask_all[:small], enc.max_length, vocab_size=vocab, packed=packed_default)
-    if packed_default and attn != "ragged":
+        bucketed_dispatch(fwd, ids_all[:small], mask_all[:small], enc.max_length, vocab_size=vocab)
+    if attn != "ragged":
         extra["padding_efficiency"] = _padding_eff(small)
     docs_per_sec = _emit_device_result(measure(small), dev, attn, **extra)
-    # in-run A/B: the legacy whole-batch path over the SAME mixed corpus
-    # (one extra compile at the (bucket(small), 128) shape) pins the
-    # packed speedup to this run's conditions instead of a stale round
-    if packed_default and attn != "ragged" and time.monotonic() + 60 + seconds < deadline:
-        try:
-            bucketed_dispatch(fwd, ids_all[:small], mask_all[:small], enc.max_length, vocab_size=vocab, packed=False)
-            extra["legacy_docs_per_sec"] = round(measure(small, packed=False), 1)
-            extra["attn_impl_by_variant"]["legacy"] = attn
-        except Exception as exc:
-            extra["ab_warning"] = f"legacy A/B failed: {exc!r}"[:300]
-        _emit_device_result(docs_per_sec, dev, attn, **extra)
     big = min(1024, len(docs))
     big_warm = False
     if big > small and time.monotonic() + 180 + seconds < deadline:
         if attn == "ragged":
             enc.encode_tokenized(ids_all[:big], mask_all[:big])
         else:
-            bucketed_dispatch(fwd, ids_all[:big], mask_all[:big], enc.max_length, vocab_size=vocab, packed=packed_default)
+            bucketed_dispatch(fwd, ids_all[:big], mask_all[:big], enc.max_length, vocab_size=vocab)
         big_warm = True
-        if packed_default and attn != "ragged":
+        if attn != "ragged":
             extra["padding_efficiency"] = _padding_eff(big)
         docs_per_sec = max(docs_per_sec, measure(big))
         docs_per_sec = _emit_device_result(docs_per_sec, dev, attn, **extra)
@@ -538,9 +520,7 @@ def main() -> None:
     }
     for opt in (
         "corpus",
-        "packed",
         "padding_efficiency",
-        "legacy_docs_per_sec",
         "pallas_docs_per_sec",
         "ragged_docs_per_sec",
         "ragged_vs_packed",

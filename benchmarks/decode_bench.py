@@ -129,7 +129,7 @@ def run(geometry: str | None = None) -> dict:
                 -(-(len(p) + max_new) // 16) for p in reqs
             )
             sess = DecodeSession(
-                cfg, lm.params, auto=False, use_runtime=False,
+                cfg, lm.params, auto=False,
                 pool_tokens=16 * (need + max(2, need // 4)),
                 block_size=16,
             )
@@ -246,7 +246,7 @@ def run_speculative(
 
     def one_run(share: bool, k: int, measure: bool):
         sess = DecodeSession(
-            cfg, lm.params, auto=False, use_runtime=False,
+            cfg, lm.params, auto=False,
             pool_tokens=pool_tokens, block_size=16,
             prefix_share=share, spec_k=k,
         )

@@ -1,15 +1,12 @@
-"""Ingest-plane benchmark: packed pipelined embed→upsert vs legacy.
+"""Ingest-plane benchmark: packed pipelined embed→upsert.
 
 Measures the live-RAG product loop the serving benches don't: how fast a
-mixed-length document stream becomes QUERYABLE.  Three numbers:
+mixed-length document stream becomes QUERYABLE.  Two numbers:
 
 * ``docs_per_sec`` — tokenize → pack → encode → device-staged upsert
   through :class:`~pathway_tpu.xpacks.llm._ingest.IngestPipeline`
   (two-stage overlap, per-seq-bucket packing, device-resident
   embed→upsert);
-* ``legacy_docs_per_sec`` — the pre-PR-5 path on the same corpus:
-  whole-batch-padded encode to host numpy, then per-document
-  ``index.add`` (H2D re-stage per flush);
 * ``ingest_to_queryable_s`` — wall time from the LAST batch's submission
   to its documents answering a search, observed through the same
   :class:`FreshnessTracker` that feeds
@@ -77,20 +74,6 @@ def main() -> None:
 
     platform = jax.devices()[0].platform
 
-    # ---- legacy path: whole-batch padding, host embeddings, per-doc add
-    index_legacy = BruteForceKnnIndex(dim=enc.dim, capacity=2 * n_docs)
-    enc.packed = False
-    # warmup (compiles outside the timed window, like bench.py)
-    enc.encode(docs[:batch])
-    t0 = time.perf_counter()
-    for start in range(0, n_docs, batch):
-        embs = enc.encode(docs[start : start + batch])
-        for j, emb in enumerate(embs):
-            index_legacy.add(keys[start + j], emb, None)
-    index_legacy.search([(enc.encode([docs[-1]])[0], 1, None)])  # staged apply
-    legacy_dps = n_docs / (time.perf_counter() - t0)
-    enc.packed = None
-
     # ---- packed pipelined path: device-resident embed→upsert
     index = BruteForceKnnIndex(dim=enc.dim, capacity=2 * n_docs)
     stats_before = ingest_stats()
@@ -138,8 +121,6 @@ def main() -> None:
         "n_docs": n_docs,
         "batch": batch,
         "value": round(packed_dps, 1),
-        "legacy_docs_per_sec": round(legacy_dps, 1),
-        "speedup_vs_legacy": round(packed_dps / legacy_dps, 3) if legacy_dps else None,
         "padding_efficiency": round(d_real / d_padded, 4) if d_padded else None,
         "ingest_to_queryable_s": round(lag, 4) if lag is not None else None,
         "pipeline_depth": pipe.depth,

@@ -34,10 +34,9 @@ Contention mode (``--clients N --ingest-load D``): the unified
 device-tick runtime's reason to exist (ISSUE 7) measured — N client
 threads hammer ``/v1/retrieve`` WHILE a bulk ingest driver feeds
 documents through an :class:`IngestPipeline` sharing the same device at
-a target rate of D docs/s.  Two passes: runtime ON (ingest chunks ride
-BULK_INGEST ticks, interactive preempts at tick granularity) and
-``PATHWAY_RUNTIME=0`` legacy (the ingest device thread free-runs against
-the serving loop).  Reports per-QoS-class p50/p99, ingest throughput
+a target rate of D docs/s: ingest chunks ride BULK_INGEST ticks,
+interactive preempts at tick granularity.  Reports p50/p99 with and
+without the ingest burst (``p99_inflation``), ingest throughput
 alone vs contended (the retained share), and the runtime's preemption /
 starvation-share counters — the artifact that pins "serving p99
 survives ingest bursts".
@@ -1146,14 +1145,10 @@ class _IngestDriver:
 def run_contention(n_docs: int, clients: int, queries_per_client: int,
                    mock: bool, ingest_load: float,
                    pace_ms: float = 0.0) -> dict:
-    """Ingest+serve contention A/B: runtime ON vs PATHWAY_RUNTIME=0.
-
-    Each phase runs in its OWN subprocess: measuring phase 2 while phase
-    1's server (engine loop, fs poller, webserver) is still alive in the
-    same process skews the A/B by a steady ~10 ms of stolen CPU on a
-    small container — observed before this split as a persistent
-    phase-order bias.  The persistent XLA compile cache keeps the
-    second child's warmup cheap."""
+    """Ingest+serve contention: serving p99 alone vs under an ingest
+    burst on the same device.  The measurement runs in its OWN
+    subprocess, so nothing of this process (an earlier mode's engine
+    loop, fs poller, webserver) steals CPU from it."""
     out: dict = {
         "metric": "rag_serving_contention",
         "n_docs": n_docs,
@@ -1163,27 +1158,20 @@ def run_contention(n_docs: int, clients: int, queries_per_client: int,
         "mock_embedder": mock,
         "ingest_load_docs_per_s": ingest_load,
     }
-    for phase in ("legacy", "runtime"):
-        rec, err = _phase_child(
-            ["--contention-phase", phase, str(n_docs), str(clients),
-             str(queries_per_client), str(pace_ms), str(ingest_load),
-             "1" if mock else "0"],
-            timeout=2400,
-        )
-        if err is not None:
-            out["error"] = f"{phase}: {err}"
-            return out
-        for meta_key in ("platform", "tick_tokens", "ingest_chunk_tokens",
-                        "min_share_bulk_ingest"):
-            if meta_key in rec:
-                out[meta_key] = rec.pop(meta_key)
-        out[phase] = rec
-    # the headline: how much the runtime shaves off the contended tail
-    out["contended_p99_speedup"] = round(
-        out["legacy"]["contended_p99_ms"]
-        / max(out["runtime"]["contended_p99_ms"], 1e-9),
-        2,
+    rec, err = _phase_child(
+        ["--contention-phase", "runtime", str(n_docs), str(clients),
+         str(queries_per_client), str(pace_ms), str(ingest_load),
+         "1" if mock else "0"],
+        timeout=2400,
     )
+    if err is not None:
+        out["error"] = f"runtime: {err}"
+        return out
+    for meta_key in ("platform", "tick_tokens", "ingest_chunk_tokens",
+                    "min_share_bulk_ingest"):
+        if meta_key in rec:
+            out[meta_key] = rec.pop(meta_key)
+    out["runtime"] = rec
     return out
 
 
@@ -1199,7 +1187,6 @@ def run_contention_phase(phase: str, n_docs: int, clients: int,
     from pathway_tpu.ops.knn import DeviceKnnIndex
     from pathway_tpu.xpacks.llm._ingest import IngestPipeline
 
-    fused = phase == "runtime"
     platform = jax.devices()[0].platform
     docs = _corpus(n_docs)
     ingest_docs = _ingest_corpus(max(4 * int(ingest_load), 256))
@@ -1255,14 +1242,10 @@ def run_contention_phase(phase: str, n_docs: int, clients: int,
         _serialize_apply(enc)
         _serialize_apply(serve_enc)
     # warm the CHUNKED shapes off the measured path, through the same
-    # pipeline + max_tokens the drivers use (the legacy phase runs
-    # first — without this it would eat the compiles the runtime phase
-    # then reuses from the cache, invalidating the A/B: observed 64 vs
-    # 960 docs/s "alone" rates from compile asymmetry alone)
-    for warm_tokens in (chunk_tokens, None):  # both phases' shape sets
-        with IngestPipeline(enc, use_runtime=False,
-                            max_tokens=warm_tokens) as warm:
-            warm.submit(ingest_docs[:128]).result(timeout=600)
+    # pipeline + max_tokens the driver uses (a compile inside the
+    # "alone" window reads as a slow ingest rate)
+    with IngestPipeline(enc, max_tokens=chunk_tokens) as warm:
+        warm.submit(ingest_docs[:128]).result(timeout=600)
     res: dict = {
         "platform": platform,
         "min_share_bulk_ingest": rt_mod.runtime_settings()["min_share"][
@@ -1270,7 +1253,6 @@ def run_contention_phase(phase: str, n_docs: int, clients: int,
         ],
         **out_knobs,
     }
-    rt_mod.configure(enabled=fused)
     with tempfile.TemporaryDirectory() as base:
         # contention mode serves with a REAL (mock-mode: small
         # random-init) encoder, never the hash fake: the story under
@@ -1330,33 +1312,26 @@ def run_contention_phase(phase: str, n_docs: int, clients: int,
             return {"error": f"baseline {exc}"}
         res["baseline_p50_ms"] = round(p50, 1)
         res["baseline_p99_ms"] = round(p99, 1)
-        # 2) bulk ingest driver on the same device
-        # system-vs-system: the legacy pipeline dispatches its
-        # natural bucket-sized launches (PR 5 behavior — one
-        # ~max_batch×seq launch occupies the device un-preemptibly);
-        # the runtime phase slices ingest into tick-sized chunks,
-        # which IS the preemptibility mechanism under test
+        # 2) bulk ingest driver on the same device: ingest is sliced
+        # into tick-sized chunks, which IS the preemptibility
+        # mechanism under test
         pipeline = IngestPipeline(
             enc,
             DeviceKnnIndex(dim=enc.dim, capacity=4096),
-            use_runtime=fused,
-            max_tokens=chunk_tokens if fused else None,
+            max_tokens=chunk_tokens,
         )
         driver = _IngestDriver(
             pipeline, ingest_docs, ingest_load,
-            batch=32,  # one submission = one bucket-sized legacy
-            # launch (the un-preemptible unit the runtime slices);
-            # larger batches mostly measure the GIL cost of
-            # tokenizing them, which both phases pay identically
+            batch=32,  # larger batches mostly measure the GIL cost
+            # of tokenizing them
             flush_every=1,  # apply each batch's staged scatters as
             # it lands — many tick-sized applies, never one
-            # 100+-slice burst poisoning both phases' tails
+            # 100+-slice burst poisoning the tail
         ).start()
         res["ingest_docs_per_sec_alone"] = round(
             driver.window(2.0 if mock else 4.0), 1
         )
-        if fused:
-            rt_before = rt_mod.get_runtime().stats()
+        rt_before = rt_mod.get_runtime().stats()
         # 3) interactive load UNDER the ingest burst
         before = driver.snapshot()
         try:
@@ -1379,22 +1354,21 @@ def run_contention_phase(phase: str, n_docs: int, clients: int,
         driver.stop()
         pipeline.close()
         res["ingest_errors"] = driver.errors
-        if fused:
-            rt_after = rt_mod.get_runtime().stats()
-            res["preemptions"] = (
-                rt_after["preemptions_total"] - rt_before["preemptions_total"]
-            )
-            res["bulk_share_mean"] = (
-                round(rt_after["bulk_share_mean"], 4)
-                if rt_after["bulk_share_mean"] is not None
-                else None
-            )
-            res["interactive_completed"] = rt_after["classes"]["interactive"][
-                "completed_total"
-            ]
-            res["bulk_completed"] = rt_after["classes"]["bulk_ingest"][
-                "completed_total"
-            ]
+        rt_after = rt_mod.get_runtime().stats()
+        res["preemptions"] = (
+            rt_after["preemptions_total"] - rt_before["preemptions_total"]
+        )
+        res["bulk_share_mean"] = (
+            round(rt_after["bulk_share_mean"], 4)
+            if rt_after["bulk_share_mean"] is not None
+            else None
+        )
+        res["interactive_completed"] = rt_after["classes"]["interactive"][
+            "completed_total"
+        ]
+        res["bulk_completed"] = rt_after["classes"]["bulk_ingest"][
+            "completed_total"
+        ]
     return res
 
 

@@ -743,8 +743,8 @@ class DecodeSession:
     """Continuous-batching table over one :class:`PagedKVPool`.
 
     ``auto=True`` (default) runs a pump thread that drives one tick per
-    loop — through the shared :class:`DeviceTickRuntime` as a
-    ``GENERATE``-class item when the runtime is enabled, else directly.
+    loop through the shared :class:`DeviceTickRuntime` as a
+    ``GENERATE``-class item.
     ``auto=False`` is the test/bench mode: the caller steps with
     :meth:`tick` / :meth:`drain`.
     """
@@ -760,7 +760,6 @@ class DecodeSession:
         mode: str | None = None,
         max_live: int | None = None,
         max_pending: int | None = None,
-        use_runtime: bool | None = None,
         auto: bool = True,
         name: str = "decode",
         spec_k: int | None = None,
@@ -792,7 +791,6 @@ class DecodeSession:
         )
         self.name = name
         self._auto = bool(auto)
-        self._use_runtime = use_runtime
         self._lock = threading.Lock()
         self._work = threading.Condition(self._lock)
         self._pending: deque[_Seq] = deque()
@@ -1862,18 +1860,9 @@ class DecodeSession:
             )
             self._pump.start()
 
-    def _runtime(self):
-        from ..runtime import get_runtime, runtime_enabled
-
-        use = (
-            runtime_enabled() if self._use_runtime is None
-            else self._use_runtime
-        )
-        return get_runtime() if use else None
-
     def _pump_loop(self) -> None:
         from ..internals.flight_recorder import name_thread
-        from ..runtime import QoS, WorkGroup
+        from ..runtime import QoS, WorkGroup, get_runtime
 
         name_thread("pw-decode")
         if self._group is None:
@@ -1889,17 +1878,13 @@ class DecodeSession:
                 if self._closed:
                     return
                 live = len(self._live)
-            rt = self._runtime()
             try:
-                if rt is not None:
-                    # ONE decode step per GENERATE item: INTERACTIVE
-                    # retrieval preempts between steps, never mid-step
-                    progressed = rt.submit(
-                        self._group, None, qos=QoS.GENERATE,
-                        tokens=max(1, live), coalesce_s=0.0,
-                    ).result()
-                else:
-                    progressed = self.tick()
+                # ONE decode step per GENERATE item: INTERACTIVE
+                # retrieval preempts between steps, never mid-step
+                progressed = get_runtime().submit(
+                    self._group, None, qos=QoS.GENERATE,
+                    tokens=max(1, live), coalesce_s=0.0,
+                ).result()
             except BaseException as exc:  # noqa: BLE001 — fail waiters, keep pumping
                 self._fail_all(exc)
                 continue

@@ -73,7 +73,6 @@ class CrossEncoder:
         max_length: int = 256,
         mesh=None,
         max_tokens: int | None = None,
-        packed: bool | None = None,
     ):
         import dataclasses
 
@@ -83,7 +82,6 @@ class CrossEncoder:
         # + doc concatenated): the packed dispatch + token budget apply
         # exactly as in SentenceEncoder
         self.max_tokens = max_tokens if max_tokens is not None else embed_max_tokens()
-        self.packed = packed
 
         self.pretrained = False
         params = None
@@ -230,7 +228,6 @@ class CrossEncoder:
             type_ids_all=type_ids_all,
             vocab_size=self.cfg.vocab_size,
             batch_multiple=self._batch_multiple,
-            packed=self.packed,
             max_tokens=self.max_tokens,
         )
 

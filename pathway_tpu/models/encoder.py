@@ -34,7 +34,6 @@ __all__ = [
     "SentenceEncoder",
     "packed_plan",
     "packed_prepare",
-    "packed_dispatch_enabled",
     "embed_max_tokens",
     "default_attention_impl",
     "ragged_plan",
@@ -416,21 +415,11 @@ def pad_chunk(
 
 def dispatch_dtype(vocab_size: int):
     """ids dtype rule shared by the dispatch path and external probes:
-    u16 halves wire bytes whenever the vocab fits, else i32."""
+    u16 halves wire bytes whenever the vocab fits, else i32 (large-vocab
+    checkpoints, e.g. multilingual with 250k ids: a u16 buffer would
+    silently wrap their ids).  The choice keys on the model's vocab, not
+    batch content, so the compiled shape/dtype is stable across batches."""
     return np.uint16 if vocab_size <= 1 << 16 else np.int32
-
-
-def _env_flag(name: str, default: bool) -> bool:
-    v = os.environ.get(name)
-    if v is None:
-        return default
-    return v.strip().lower() not in ("0", "false", "off", "no", "")
-
-
-def packed_dispatch_enabled() -> bool:
-    """Per-seq-bucket packed dispatch is the default; legacy whole-batch
-    padding stays reachable for A/B runs (``PATHWAY_PACKED_DISPATCH=0``)."""
-    return _env_flag("PATHWAY_PACKED_DISPATCH", True)
 
 
 def embed_max_tokens() -> int | None:
@@ -846,7 +835,7 @@ def _collect_rows(pending, n: int) -> np.ndarray:
 def bucketed_dispatch(
     apply_fn, ids_all, mask_all, max_length: int, type_ids_all=None,
     vocab_size: int = 1 << 31, batch_multiple: int = 1,
-    packed: bool | None = None, max_tokens: int | None = None,
+    max_tokens: int | None = None,
     seq_buckets: Sequence[int] = SEQ_BUCKETS,
     batch_buckets: Sequence[int] = BATCH_BUCKETS,
     lone_tail: bool = False,
@@ -857,92 +846,33 @@ def bucketed_dispatch(
     ``batch_multiple`` rounds the batch bucket up so the batch dimension
     divides evenly over a data-parallel mesh axis.
 
-    ``packed`` (default: :func:`packed_dispatch_enabled`) selects per-row
-    seq bucketing: rows are grouped by their OWN seq bucket and each group
-    dispatched at its bucket shape, so one 256-token chunk no longer
-    inflates a batch of 64-token chunks ~4x in FLOPs.  Both per-bucket
-    shapes come from the same (BATCH_BUCKETS x SEQ_BUCKETS) grid the
-    legacy path compiles, so the compiled-executable set — and
+    Seq bucketing is per row: rows are grouped by their OWN seq bucket
+    and each group dispatched at its bucket shape, so one 256-token chunk
+    does not inflate a batch of 64-token chunks ~4x in FLOPs.  Every
+    per-bucket shape comes from the one (BATCH_BUCKETS x SEQ_BUCKETS)
+    grid, so the compiled-executable set — and
     ``pathway_xla_compile_total`` — stays flat across mixed-length
     corpora.  ``max_tokens`` caps ``batch_bucket * seq_bucket`` per
     launch (token-budget batching, ``PATHWAY_EMBED_MAX_TOKENS``)."""
     from ..internals.flight_recorder import record_padding, span
 
-    if packed is None:
-        packed = packed_dispatch_enabled()
-    if packed:
-        prepared, stats = packed_prepare(
-            ids_all, mask_all, max_length,
-            type_ids_all=type_ids_all, vocab_size=vocab_size,
-            batch_multiple=batch_multiple, max_tokens=max_tokens,
-            seq_buckets=seq_buckets, batch_buckets=batch_buckets,
-            lone_tail=lone_tail,
-        )
-        record_padding(
-            stats["real_tokens"], stats["padded_tokens"], stats["row_tokens"]
-        )
-        # H2D and dispatch, until the last launch call returns
-        with span(
-            "embed.launch", "encoder", stage="embed.launch",
-            chunks=len(prepared),
-        ):
-            pending = _dispatch_prepared(apply_fn, prepared)
-        return _collect_rows(pending, ids_all.shape[0])
-
-    # legacy whole-batch path: ONE seq bucket for the whole batch, sized
-    # by its single longest row — kept for A/B measurement and parity
-    # tests (PATHWAY_PACKED_DISPATCH=0 / packed=False)
-    longest = int(mask_all.sum(axis=1).max())
-    real_tokens = int(mask_all.sum())
-    seq = min(_bucket(longest, seq_buckets), max_length)
-    ids_all, mask_all = ids_all[:, :seq], mask_all[:, :seq]
-    if type_ids_all is not None:
-        type_ids_all = type_ids_all[:, :seq]
-    b = ids_all.shape[0]
-    bb = _bucket(b, batch_buckets)
-    if bb % batch_multiple:
-        # legacy path rounds UNCONDITIONALLY (pre-PR8 behavior, kept as
-        # the A/B reference) — the conditional shard-vs-replicate policy
-        # is round_batch_to_multiple, used by the packed path only
-        bb += batch_multiple - bb % batch_multiple
-    # dispatch every chunk before collecting any result: JAX's async
-    # dispatch queues the launches back-to-back, so device compute and
-    # host→device transfers for chunk n+1 overlap the device→host copy of
-    # chunk n — one sync at the end instead of one per chunk
-    # transfer narrow dtypes: masks and type ids fit u8, and vocab ids fit
-    # u16 when the tokenizer's id space allows it — the model widens to i32
-    # on device where it's free.  This cuts host->device input bytes
-    # 2-4x (the forward itself is unchanged).  Large-vocab checkpoints (e.g. multilingual,
-    # 250k ids) keep i32 — a u16 buffer would silently wrap their ids.
-    # The choice keys on the model's vocab, not batch content, so the
-    # compiled shape/dtype is stable across batches
-    ids_dtype = dispatch_dtype(vocab_size)
-    pending = []
-    start = 0
-    padded_tokens = 0
-    while start < b:
-        chunk = min(bb, b - start)
-        ids, mask, tids = pad_chunk(
-            ids_all[start : start + chunk],
-            mask_all[start : start + chunk],
-            bb,
-            seq,
-            type_ids=None
-            if type_ids_all is None
-            else type_ids_all[start : start + chunk],
-            ids_dtype=ids_dtype,
-        )
-        args = [jnp.asarray(ids), jnp.asarray(mask)]
-        if tids is not None:
-            args.append(jnp.asarray(tids))
-        pending.append((apply_fn(*args), chunk))
-        padded_tokens += bb * seq
-        start += chunk
-    record_padding(real_tokens, padded_tokens, b * seq)
-    outs = [
-        np.asarray(res, dtype=np.float32)[:chunk] for res, chunk in pending
-    ]
-    return np.concatenate(outs, axis=0)
+    prepared, stats = packed_prepare(
+        ids_all, mask_all, max_length,
+        type_ids_all=type_ids_all, vocab_size=vocab_size,
+        batch_multiple=batch_multiple, max_tokens=max_tokens,
+        seq_buckets=seq_buckets, batch_buckets=batch_buckets,
+        lone_tail=lone_tail,
+    )
+    record_padding(
+        stats["real_tokens"], stats["padded_tokens"], stats["row_tokens"]
+    )
+    # H2D and dispatch, until the last launch call returns
+    with span(
+        "embed.launch", "encoder", stage="embed.launch",
+        chunks=len(prepared),
+    ):
+        pending = _dispatch_prepared(apply_fn, prepared)
+    return _collect_rows(pending, ids_all.shape[0])
 
 
 def _encoder_params_nbytes(enc: "SentenceEncoder") -> int:
@@ -970,13 +900,10 @@ class SentenceEncoder:
         mesh=None,
         extend_positions: int | None = None,
         max_tokens: int | None = None,
-        packed: bool | None = None,
         params: Any = None,
     ):
         #: token budget per device launch (None = PATHWAY_EMBED_MAX_TOKENS)
         self.max_tokens = max_tokens if max_tokens is not None else embed_max_tokens()
-        #: per-seq-bucket packed dispatch (None = PATHWAY_PACKED_DISPATCH)
-        self.packed = packed
         self.pretrained = False
         # ``params``: a ready parameter tree of ``cfg``'s model, so that a
         # model of gigabytes is never drawn twice
@@ -1192,7 +1119,6 @@ class SentenceEncoder:
             self.max_length,
             vocab_size=self.cfg.vocab_size,
             batch_multiple=self._batch_multiple,
-            packed=self.packed,
             max_tokens=self.max_tokens,
             seq_buckets=self.cfg.seq_buckets,
             batch_buckets=self.cfg.batch_buckets,

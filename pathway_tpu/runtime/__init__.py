@@ -20,7 +20,6 @@ from .executor import (
     estimate_tokens,
     get_runtime,
     reset_runtime,
-    runtime_enabled,
     runtime_settings,
     runtime_stats_if_active,
 )
@@ -37,7 +36,6 @@ __all__ = [
     "estimate_tokens",
     "get_runtime",
     "reset_runtime",
-    "runtime_enabled",
     "runtime_settings",
     "runtime_stats_if_active",
 ]

@@ -46,8 +46,7 @@ inline ``LLM_RERANK`` submit inside an ``INTERACTIVE`` tick is
 accounted to the interactive tick, never enqueued ahead of it
 (class-inversion fix, PR 7).
 
-``PATHWAY_RUNTIME=0`` restores the three legacy per-plane loops for
-A/B; see README "Operations: unified runtime & QoS classes".
+See README "Operations: unified runtime & QoS classes".
 
 Import discipline: this package sits below ``xpacks`` (the planes import
 it, never the reverse) and only pulls the ``internals`` observability
@@ -59,7 +58,6 @@ from __future__ import annotations
 
 import asyncio
 import enum
-import os
 import threading
 import time
 from collections import deque
@@ -76,7 +74,6 @@ __all__ = [
     "estimate_tokens",
     "budget_chunks",
     "get_runtime",
-    "runtime_enabled",
     "runtime_settings",
     "runtime_stats_if_active",
     "configure",
@@ -198,8 +195,7 @@ def budget_chunks(group: Any, items: list["WorkItem"]) -> list[list["WorkItem"]]
     a token-mass cap so a run of long documents dispatches in
     length-adapted batches.  Every chunk carries at least one item.
 
-    THE budget-chunking implementation — the legacy serving scheduler's
-    ``_budget_chunks`` is an alias of this."""
+    THE budget-chunking implementation."""
     max_tokens = getattr(group, "max_tokens", None)
     estimate = getattr(group, "token_estimate", None)
     if max_tokens is None or estimate is None:
@@ -287,13 +283,6 @@ _SHARE_BUCKETS = (0.01, 0.02, 0.05, 0.1, 0.2, 0.35, 0.5, 0.75, 1.0)
 # the serving query-cache knobs)
 from ..internals.config import env_float as _env_float  # noqa: E402
 from ..internals.config import env_int as _env_int  # noqa: E402
-
-
-def _env_flag(name: str, default: bool) -> bool:
-    v = os.environ.get(name)
-    if v is None:
-        return default
-    return v.strip().lower() not in ("0", "false", "off", "no", "")
 
 
 class DeviceTickRuntime:
@@ -945,7 +934,6 @@ class DeviceTickRuntime:
 # ---------------------------------------------------------------------------
 
 _SETTINGS: dict[str, Any] = {
-    "enabled": _env_flag("PATHWAY_RUNTIME", True),
     "tick_tokens": _env_int("PATHWAY_RUNTIME_TICK_TOKENS", 16384),
     "max_batch": _env_int(
         "PATHWAY_RUNTIME_MAX_BATCH",
@@ -981,10 +969,6 @@ _GLOBAL_LOCK = threading.Lock()
 _GLOBAL: DeviceTickRuntime | None = None
 
 
-def runtime_enabled() -> bool:
-    return bool(_SETTINGS["enabled"])
-
-
 def runtime_settings() -> dict[str, Any]:
     out = dict(_SETTINGS)
     out["depth"] = dict(_SETTINGS["depth"])
@@ -993,9 +977,8 @@ def runtime_settings() -> dict[str, Any]:
 
 
 def configure(**kwargs: Any) -> None:
-    """Adjust the global runtime policy (``enabled``, ``tick_tokens``,
-    ``max_batch``, ``max_wait_ms``, ``retry_after_s``, ``depth``,
-    ``min_share``).  ``depth``/``min_share`` take partial ``{QoS: value}``
+    """Adjust the global runtime policy (``tick_tokens``, ``max_batch``,
+    ``max_wait_ms``, ``retry_after_s``, ``depth``, ``min_share``).  ``depth``/``min_share`` take partial ``{QoS: value}``
     dicts and merge.  Live knobs apply to the already-running global
     runtime too."""
     unknown = set(kwargs) - set(_SETTINGS)

@@ -575,9 +575,8 @@ class TieredKnnIndex:
 
     def maybe_schedule_migrations(self) -> bool:
         """Submit one promotion/demotion batch as a ``BULK_INGEST`` work
-        item on the unified runtime (at most one in flight).  With the
-        runtime disabled (``PATHWAY_RUNTIME=0``) the batch applies
-        inline — either way, no new loop exists anywhere."""
+        item on the unified runtime (at most one in flight) — no new
+        loop exists anywhere."""
         if self.migrate_batch <= 0:
             return False
         with self._lock:
@@ -587,15 +586,12 @@ class TieredKnnIndex:
                 return False
             self._migration_pending = True
         try:
-            from ..runtime import QoS, WorkGroup, get_runtime, runtime_enabled
+            from ..runtime import QoS, WorkGroup, get_runtime
 
-            if not runtime_enabled():
-                self.migrate()
-                return True
             if self._migrate_group is None:
                 self._migrate_group = WorkGroup(
                     f"tier-migrate:{self.tier_label}",
-                    lambda payloads: [self.migrate() for _ in payloads],
+                    self._migrate_deferred,
                     max_batch=1,
                 )
             from ..internals.flight_recorder import current_trace_link
@@ -615,12 +611,28 @@ class TieredKnnIndex:
             return True
         except Exception:  # noqa: BLE001 — tier maintenance is
             # best-effort: the triggering query's results are already
-            # computed, and a transient fault in migrate()/the runtime
+            # computed, and a transient fault in the runtime submit
             # must not ride its error path.  The check counter re-arms
             # on the next search window.
             self._migration_pending = False
             self.migrate_errors += 1
             return False
+
+    def _migrate_deferred(self, payloads: list) -> list:
+        """The migration item's batch handler (runtime tick thread).
+        Nobody reads a deferred item's future, so a fault in migrate()
+        is absorbed here: counted, and the pending flag cleared so the
+        next search window re-arms the check."""
+        out = []
+        for _ in payloads:
+            try:
+                out.append(self.migrate())
+            except Exception:  # noqa: BLE001 — best-effort, see above
+                with self._lock:
+                    self._migration_pending = False
+                    self.migrate_errors += 1
+                out.append(None)
+        return out
 
     # -- snapshot / restore ---------------------------------------------
     def snapshot_header(self) -> dict:

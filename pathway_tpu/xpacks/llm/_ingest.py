@@ -4,8 +4,8 @@ The ingest plane (connector → splitter → embedder → index upsert) is
 where the live-RAG loop's freshness budget goes.  This module rebuilds
 its embedding hot path as a producer/consumer pipeline:
 
-* a **host worker** tokenizes and packs (``models/encoder.pad_chunk``
-  via :func:`~pathway_tpu.models.encoder.packed_prepare`) one batch
+* a **host worker** tokenizes and packs (the encoder's
+  ``prepare_chunks``) one batch
   AHEAD of the device — the double-buffered hand-off queue (depth
   ``PATHWAY_INGEST_PIPELINE_DEPTH``, default 2) means tokenize(N+1)
   overlaps encode(N) instead of serializing on the embedder thread (the
@@ -17,26 +17,23 @@ its embedding hot path as a producer/consumer pipeline:
   D2H(embeddings)+H2D(same bytes) round trip disappears, only keys and
   metadata stay host-side.
 
-Every stage records flight-recorder spans (``tokenize`` / ``h2d`` /
-``encode`` / ``upsert``, category ``ingest``) and documents count into
+Every stage records flight-recorder spans (``tokenize`` / ``encode`` /
+``upsert``, category ``ingest``) and documents count into
 ``pathway_ingest_docs_total``; packing efficiency feeds
 ``pathway_embed_padding_efficiency``.  Under ``PATHWAY_FAULTS`` chaos
 the device stage honors the ``embedder`` site: an injected failure
 fails THAT batch's future and the pipeline keeps draining.
 
-PR 7: with the unified device-tick runtime enabled (default,
-``PATHWAY_RUNTIME=1``) the device worker no longer touches the device
-itself — each prepared chunk (one bounded ``bb×seq`` launch) is
-submitted to the shared executor as a ``BULK_INGEST``-class work item
-whose token estimate is the chunk's padded token mass.  Interactive
+The device worker does not touch the device itself — each prepared
+chunk (one bounded ``bb×seq`` launch) is submitted to the unified
+device-tick runtime as a ``BULK_INGEST``-class work item whose token
+estimate is the chunk's padded token mass.  Interactive
 serving ticks preempt the backlog at tick granularity (a query never
 waits behind more than the chunk already on the device) while the
 runtime's starvation bound guarantees ingest forward progress under
 sustained query load.  Upsert staging (host-side bookkeeping; the
 scatter itself runs at the next search) stays on the worker thread so a
 failed chunk still fails its whole batch before anything is staged.
-``PATHWAY_RUNTIME=0`` (or ``use_runtime=False``) restores the in-thread
-device loop for A/B — the two paths are bit-identical by test.
 """
 
 from __future__ import annotations
@@ -95,10 +92,9 @@ class IngestPipeline:
         *,
         depth: int | None = None,
         max_tokens: int | None = None,
-        use_runtime: bool | None = None,
     ):
         from ...models.encoder import embed_max_tokens
-        from ...runtime import WorkGroup, runtime_enabled
+        from ...runtime import WorkGroup
 
         self.encoder = encoder
         self.index = index
@@ -106,16 +102,11 @@ class IngestPipeline:
         self.max_tokens = (
             max_tokens if max_tokens is not None else embed_max_tokens()
         )
-        #: device work rides the unified runtime as BULK_INGEST chunks
-        #: (None = follow the global PATHWAY_RUNTIME setting)
-        self.use_runtime = (
-            runtime_enabled() if use_runtime is None else use_runtime
-        )
         # max_batch=1: every prepared chunk is its own device dispatch
         # AND its own failure domain — one poisoned chunk must not fail
         # another pipeline batch sharing the tick
         self._encode_group = WorkGroup(
-            "ingest-encode", self._encode_chunk_on_runtime, max_batch=1
+            "ingest-encode", self._encode_chunk, max_batch=1
         )
         self._in: queue.Queue = queue.Queue()
         # the hand-off: host worker blocks here once it is `depth`
@@ -195,33 +186,6 @@ class IngestPipeline:
         return self.submit(texts).result()
 
     # -- stage 1: host tokenize + pack ----------------------------------
-    def _prepare(self, ids_all, mask_all):
-        """Host half of the dispatch in the ENCODER's layout: the
-        prepared-chunk protocol (``prepare_chunks``: packed (bb, seq)
-        buckets or the ragged concatenated-token layout, per
-        ``attention_impl``) when the encoder speaks it; the legacy
-        packed_prepare shape for bare duck-typed encoders.  Either way
-        every entry is ``(payload, rows, tokens)``."""
-        enc = self.encoder
-        prepare = getattr(enc, "prepare_chunks", None)
-        if prepare is not None:
-            return prepare(ids_all, mask_all, max_tokens=self.max_tokens)
-        from ...models.encoder import packed_prepare
-
-        prepared, stats = packed_prepare(
-            ids_all, mask_all, enc.max_length,
-            vocab_size=enc.cfg.vocab_size,
-            batch_multiple=getattr(enc, "_batch_multiple", 1),
-            max_tokens=self.max_tokens,
-        )
-        return (
-            [
-                ((ids, mask, tids), rows, int(np.asarray(ids).size))
-                for ids, mask, tids, rows in prepared
-            ],
-            stats,
-        )
-
     def _tokenize_loop(self) -> None:
         from ...internals.flight_recorder import name_thread, span
 
@@ -237,7 +201,13 @@ class IngestPipeline:
                     ids_all, mask_all = enc.tokenizer.encode_batch(
                         item.texts, max_length=enc.max_length
                     )
-                item.prepared, item.stats = self._prepare(ids_all, mask_all)
+                # host half of the dispatch in the ENCODER's layout
+                # (packed (bb, seq) buckets or the ragged
+                # concatenated-token layout, per ``attention_impl``);
+                # every entry is ``(payload, rows, tokens)``
+                item.prepared, item.stats = enc.prepare_chunks(
+                    ids_all, mask_all, max_tokens=self.max_tokens
+                )
             except BaseException as exc:  # noqa: BLE001 — fail THIS batch only
                 if not item.future.done():
                     item.future.set_exception(exc)
@@ -245,9 +215,11 @@ class IngestPipeline:
             self._ready.put(item)  # blocks at `depth` batches ahead
 
     # -- stage 2: device transfer + encode + upsert ---------------------
-    def _encode_chunk_on_runtime(self, payloads: list) -> list:
+    def _encode_chunk(self, payloads: list) -> list:
         """BULK_INGEST batch handler (runtime executor thread): one
-        prepared chunk per call (``max_batch=1``) — H2D + encode, the
+        prepared chunk per call (``max_batch=1``) — the encoder's own
+        device half (packed (bb, seq) launch or ONE ragged
+        concatenated-token launch, H2D + mesh placement included), the
         DEVICE output returned as-is so upsert staging keeps the
         embed→upsert path device-resident.
 
@@ -259,48 +231,19 @@ class IngestPipeline:
         (observed as 300+ ms serving `search` stages behind a 64-chunk
         async backlog).  One tick in flight at a time is the executor's
         whole contract with the device."""
-        assert len(payloads) == 1
-        out = self._encode_chunk(payloads[0])
         import jax
 
-        jax.block_until_ready(out)
-        return [out]
-
-    def _encode_chunk(self, payload) -> Any:
         from ...internals.flight_recorder import span
 
-        enc = self.encoder
-        encode_prepared = getattr(enc, "encode_prepared", None)
-        if encode_prepared is not None:
-            # the encoder's own device half: packed (bb, seq) launch or
-            # ONE ragged concatenated-token launch, H2D + mesh placement
-            # included (attention_impl-aware)
-            tokens = int(
-                np.asarray(payload[0]).size if isinstance(payload, tuple)
-                else np.asarray(payload.ids).size
-            )
-            with span("encode", "ingest", tokens=tokens):
-                return encode_prepared(payload)
-        import jax.numpy as jnp
-
-        ids, mask, tids = payload
-        with span("h2d", "ingest", chunks=1):
-            args = [jnp.asarray(ids), jnp.asarray(mask)]
-            if tids is not None:
-                args.append(jnp.asarray(tids))
-            if getattr(enc, "mesh", None) is not None:
-                import jax
-
-                # the encoder's own data-parallel rule: shard chunks that
-                # divide the data axis, replicate small tails
-                rule = getattr(enc, "_input_sharding", None)
-                sharding = (
-                    rule(args[0].shape[0]) if rule is not None
-                    else enc._data_sharding
-                )
-                args = [jax.device_put(a, sharding) for a in args]
-        with span("encode", "ingest", rows=int(np.asarray(ids).shape[0])):
-            return enc._apply(enc.params, *args)
+        (payload,) = payloads
+        tokens = int(
+            np.asarray(payload[0]).size if isinstance(payload, tuple)
+            else np.asarray(payload.ids).size
+        )
+        with span("encode", "ingest", tokens=tokens):
+            out = self.encoder.encode_prepared(payload)
+        jax.block_until_ready(out)
+        return [out]
 
     def _device_loop(self) -> None:
         from ...internals.flight_recorder import (
@@ -327,38 +270,31 @@ class IngestPipeline:
                     item.stats["padded_tokens"],
                     item.stats.get("row_tokens"),
                 )
-                if self.use_runtime:
-                    # every prepared chunk is one BULK_INGEST work item:
-                    # tokens = its padded token mass (one ragged launch
-                    # == one item too), coalesce 0 (a backlog never
-                    # waits for tick-mates).  Interactive ticks slot in
-                    # between chunks; the min-share bound keeps this
-                    # batch progressing under query floods.
-                    from ...runtime import QoS, get_runtime
+                # every prepared chunk is one BULK_INGEST work item:
+                # tokens = its padded token mass (one ragged launch ==
+                # one item too), coalesce 0 (a backlog never waits for
+                # tick-mates).  Interactive ticks slot in between
+                # chunks; the min-share bound keeps this batch
+                # progressing under query floods.
+                from ...runtime import QoS, get_runtime
 
-                    rt = get_runtime()
-                    futs = [
-                        (
-                            rt.submit(
-                                self._encode_group,
-                                payload,
-                                qos=QoS.BULK_INGEST,
-                                tokens=int(tokens),
-                                coalesce_s=0.0,
-                            ),
-                            rows,
-                        )
-                        for payload, rows, tokens in item.prepared
-                    ]
-                    # all chunks must encode before anything stages:
-                    # a failed chunk fails the WHOLE batch pre-upsert,
-                    # exactly like the legacy single-thread path
-                    outs = [(f.result(), rows) for f, rows in futs]
-                else:
-                    outs = [
-                        (self._encode_chunk(payload), rows)
-                        for payload, rows, _tokens in item.prepared
-                    ]
+                rt = get_runtime()
+                futs = [
+                    (
+                        rt.submit(
+                            self._encode_group,
+                            payload,
+                            qos=QoS.BULK_INGEST,
+                            tokens=int(tokens),
+                            coalesce_s=0.0,
+                        ),
+                        rows,
+                    )
+                    for payload, rows, tokens in item.prepared
+                ]
+                # all chunks must encode before anything stages: a
+                # failed chunk fails the WHOLE batch pre-upsert
+                outs = [(f.result(), rows) for f, rows in futs]
                 if self.index is not None:
                     with span("upsert", "ingest", docs=len(item.texts)):
                         for out, rows in outs:

@@ -545,10 +545,8 @@ class QueryCacheStack:
         return keys, ids_all, mask_all, lens
 
     def _runtime_depth(self) -> int:
-        from ...runtime import QoS, get_runtime, runtime_enabled
+        from ...runtime import QoS, get_runtime
 
-        if not runtime_enabled():
-            return 0
         return get_runtime().queue_depth(QoS.INTERACTIVE)
 
     # -- serve -----------------------------------------------------------
@@ -592,11 +590,7 @@ class QueryCacheStack:
                     if self.stale_s > 0 and epoch == epoch_now
                     else None
                 )
-                if (
-                    age is not None
-                    and age <= self.stale_s
-                    and self._can_refresh()
-                ):
+                if age is not None and age <= self.stale_s:
                     stale += 1
                     results[i] = rows
                     refresh.append((rkey, items[i]))
@@ -838,11 +832,6 @@ class QueryCacheStack:
         return enc is not None and getattr(enc, "encode_padded", None) is not None
 
     # -- stale-while-revalidate ------------------------------------------
-    def _can_refresh(self) -> bool:
-        from ...runtime import runtime_enabled
-
-        return runtime_enabled()
-
     def _schedule_refresh(self, plane, refresh: list[tuple]) -> None:
         """Resubmit stale-served queries as DEFERRED runtime items
         (fire-and-forget, BULK_INGEST class — a cache refresh must not

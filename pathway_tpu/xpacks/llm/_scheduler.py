@@ -2,39 +2,36 @@
 
 The engine's :class:`~pathway_tpu.xpacks.llm._utils.AsyncMicroBatcher`
 coalesces only the calls that land in the *same* engine micro-batch, so
-under concurrent REST load the device sees one small embed/search dispatch
-per request and query p99 balloons (serving_bench: p99 ≈ 2.4× p50 on CPU).
-This module decouples device batching from engine cadence the way WindVE
-(arXiv:2504.14941) decouples a host-side concurrency queue from the
-accelerator:
+under concurrent REST load the device would see one small embed/search
+dispatch per request and query p99 balloons (serving_bench: p99 ≈ 2.4×
+p50 on CPU).  Serving decouples device batching from engine cadence the
+way WindVE (arXiv:2504.14941) decouples a host-side concurrency queue
+from the accelerator — and the ONE admission queue and device-step loop
+that does so is the unified device-tick runtime
+(:mod:`pathway_tpu.runtime`):
 
-* a host-side **admission queue** collects work items (embed texts, rerank
-  pairs, fused retrieve requests) from every in-flight plane — engine
-  micro-batches AND concurrent REST handlers;
-* a single **device-step loop** drains it on a ``max_batch`` /
-  ``max_wait_ms`` policy, so one scheduler tick carries embeds from
-  request A, KNN probes from request B and rerank pairs from request C,
-  each kind as one padded device dispatch (the power-of-two bucketing in
-  ``models/encoder.py`` / ``ops/topk.bucket_k`` keeps XLA compile counts
-  flat across the ragged batch sizes this produces);
+* :class:`ServingScheduler` is a **counter-keeping facade** over it:
+  ``submit`` hands each work item (embed texts, rerank pairs, fused
+  retrieve requests) to ``get_runtime().submit`` as ``INTERACTIVE`` work
+  (so it preempts bulk-ingest chunks at tick granularity) with this
+  scheduler's ``max_wait_ms`` as the item's coalesce window, so one tick
+  carries embeds from request A, KNN probes from request B and rerank
+  pairs from request C, each kind as one padded device dispatch (the
+  power-of-two bucketing in ``models/encoder.py`` / ``ops/topk.bucket_k``
+  keeps XLA compile counts flat across the ragged batch sizes this
+  produces).  The facade holds no thread, queue or condition variable;
 * requests carry an optional **deadline**: items whose deadline passed
-  before dispatch are shed with :class:`DeadlineExceeded` (REST planes
-  map it to 503 + ``Retry-After``) and their device work never runs —
-  backpressure, not collapse.  Admission beyond ``max_queue`` is refused
-  immediately with :class:`SchedulerOverloaded`.
+  before dispatch are shed by the runtime with :class:`DeadlineExceeded`
+  (REST planes map it to 503 + ``Retry-After``) and their device work
+  never runs — backpressure, not collapse.  Admission beyond this
+  scheduler's own ``max_queue`` pending items is refused immediately
+  with :class:`SchedulerOverloaded`.
 
 Observability (queue depth, batch occupancy, wait-time histogram,
-deadline drops) registers with ``internals/monitoring.py`` and renders on
-the OpenMetrics ``/status`` endpoint as ``pathway_scheduler_*`` series.
-
-PR 7: by default (``PATHWAY_RUNTIME=1``) :class:`ServingScheduler` is a
-**thin facade over the unified device-tick runtime**
-(:mod:`pathway_tpu.runtime`): submissions execute on the shared QoS
-executor as ``INTERACTIVE`` work (so they preempt bulk-ingest chunks at
-tick granularity), while this class keeps its legacy per-instance
-counters, admission cap and ``pathway_scheduler_*`` series via observer
-hooks.  ``PATHWAY_RUNTIME=0`` restores the self-contained device-step
-loop below for A/B.
+deadline drops) is kept per scheduler through the runtime's observer
+hooks (``_obs_*``), registers with ``internals/monitoring.py`` and
+renders on the OpenMetrics ``/status`` endpoint as
+``pathway_scheduler_*`` series.
 """
 
 from __future__ import annotations
@@ -42,7 +39,6 @@ from __future__ import annotations
 import asyncio
 import os
 import threading
-import time
 from concurrent.futures import Future
 from typing import Any
 
@@ -53,9 +49,7 @@ from ...runtime import (
     DeadlineExceeded,
     QoS,
     WorkGroup,
-    budget_chunks as _budget_chunks,
     get_runtime,
-    runtime_enabled,
 )
 
 __all__ = [
@@ -81,28 +75,13 @@ class ServingNotReady(DeadlineExceeded):
     """The live index is not lowered yet (engine still starting up)."""
 
 
-class _WorkItem:
-    __slots__ = (
-        "group", "payload", "future", "enqueued_at", "deadline_at", "trace",
-    )
-
-    def __init__(self, group, payload, future, enqueued_at, deadline_at,
-                 trace=None):
-        self.group = group
-        self.payload = payload
-        self.future = future
-        self.enqueued_at = enqueued_at
-        self.deadline_at = deadline_at
-        #: sampled RequestTrace riding this item (internals/flight_recorder)
-        self.trace = trace
-
-
 #: wait-time histogram bucket upper bounds (milliseconds)
 _WAIT_BUCKETS_MS = (1.0, 2.0, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0, 1000.0)
 
 
 class ServingScheduler:
-    """Admission queue + device-step loop (see module docstring)."""
+    """Per-scheduler admission cap and counters over the shared runtime
+    (see module docstring)."""
 
     def __init__(
         self,
@@ -118,14 +97,11 @@ class ServingScheduler:
         self.max_queue = max_queue
         self.retry_after_s = retry_after_s
         self.name = name
-        self._cv = threading.Condition()
-        self._queue: list[_WorkItem] = []
-        self._thread: threading.Thread | None = None
-        #: facade mode: items currently enqueued on the shared runtime
-        #: on this scheduler's behalf (legacy queue-depth/admission view)
+        #: items currently enqueued on the shared runtime on this
+        #: scheduler's behalf (its queue-depth/admission view)
         self._runtime_pending = 0
-        # metrics — guarded by _mx, not _cv: the tick updates them while
-        # submitters hold _cv
+        # metrics — the runtime's tick thread updates them through the
+        # observer hooks while submitters read them
         self._mx = threading.Lock()
         self._counters = {
             "submitted_total": 0,
@@ -177,84 +153,40 @@ class ServingScheduler:
             sheddable = deadline_s is not None
         if trace is not None and not trace.sampled:
             trace = None
-        if runtime_enabled():
-            # facade path: execute on the unified QoS runtime as
-            # INTERACTIVE work.  This scheduler keeps its legacy
-            # admission cap (max_queue over ITS OWN pending items) and
-            # its pathway_scheduler_* counters via the observer hooks
-            # below; re-entrant submits from the runtime thread are
-            # handled by the runtime itself (inline, inheriting the
-            # running tick's class — no class inversion, no deadlock).
-            rt = get_runtime()
-            if (
-                sheddable
-                and not rt.on_runtime_thread()
-                and self._runtime_pending >= self.max_queue
-            ):
-                with self._mx:
-                    self._counters["shed_queue_total"] += 1
-                fut: Future = Future()
-                fut.set_exception(
-                    SchedulerOverloaded(
-                        f"scheduler queue full ({self.max_queue} pending)",
-                        retry_after_s=self.retry_after_s,
-                    )
-                )
-                return fut
+        # This scheduler keeps its own admission cap (max_queue over ITS
+        # OWN pending items) and its pathway_scheduler_* counters via
+        # the observer hooks below; re-entrant submits from the runtime
+        # thread are handled by the runtime itself (inline, inheriting
+        # the running tick's class — no class inversion, no deadlock).
+        rt = get_runtime()
+        if (
+            sheddable
+            and not rt.on_runtime_thread()
+            and self._runtime_pending >= self.max_queue
+        ):
             with self._mx:
-                self._counters["submitted_total"] += 1
-            return rt.submit(
-                group,
-                payload,
-                qos=QoS.INTERACTIVE,
-                deadline_s=deadline_s,
-                sheddable=sheddable,
-                trace=trace,
-                coalesce_s=self.max_wait_ms / 1000.0,
-                observer=self,
-                retry_after_s=self.retry_after_s,
-            )
-        fut: Future = Future()
-        if self._thread is not None and threading.current_thread() is self._thread:
-            # re-entrant submit from inside a batch handler (e.g. a
-            # retrieve handler whose embedder delegates through the
-            # batcher): run inline — a queued item could never drain
-            # while the loop is inside this very tick.  _execute handles
-            # the dispatch lock, result validation and error routing
-            self._execute(
-                group,
-                [_WorkItem(group, payload, fut, time.monotonic(), None, trace)],
+                self._counters["shed_queue_total"] += 1
+            fut: Future = Future()
+            fut.set_exception(
+                SchedulerOverloaded(
+                    f"scheduler queue full ({self.max_queue} pending)",
+                    retry_after_s=self.retry_after_s,
+                )
             )
             return fut
-        now = time.monotonic()
-        item = _WorkItem(
-            group,
-            payload,
-            fut,
-            now,
-            None if deadline_s is None else now + deadline_s,
-            trace,
-        )
-        with self._cv:
-            if sheddable and len(self._queue) >= self.max_queue:
-                with self._mx:
-                    self._counters["shed_queue_total"] += 1
-                fut.set_exception(
-                    SchedulerOverloaded(
-                        f"scheduler queue full ({self.max_queue} pending)",
-                        retry_after_s=self.retry_after_s,
-                    )
-                )
-                return fut
-            self._ensure_thread()
-            self._queue.append(item)
-            depth = len(self._queue)
-            self._cv.notify_all()
         with self._mx:
             self._counters["submitted_total"] += 1
-            if depth > self._queue_depth_max:
-                self._queue_depth_max = depth
-        return fut
+        return rt.submit(
+            group,
+            payload,
+            qos=QoS.INTERACTIVE,
+            deadline_s=deadline_s,
+            sheddable=sheddable,
+            trace=trace,
+            coalesce_s=self.max_wait_ms / 1000.0,
+            observer=self,
+            retry_after_s=self.retry_after_s,
+        )
 
     async def submit_async(
         self,
@@ -273,20 +205,17 @@ class ServingScheduler:
         )
 
     def executor_alive(self) -> bool:
-        """Is the device-step executor serving this scheduler alive?
-        Facade mode: the shared runtime's tick thread; legacy mode: this
-        scheduler's own loop thread.  (The containment tests' "the loop
-        survived the fault" observable, architecture-neutral.)"""
-        if runtime_enabled():
-            rt = get_runtime()
-            return rt._thread is not None and rt._thread.is_alive()
-        return self._thread is not None and self._thread.is_alive()
+        """Is the device-step executor serving this scheduler (the shared
+        runtime's tick thread) alive?  (The containment tests' "the loop
+        survived the fault" observable.)"""
+        rt = get_runtime()
+        return rt._thread is not None and rt._thread.is_alive()
 
-    # -- runtime observer hooks (facade mode) ----------------------------
+    # -- runtime observer hooks ------------------------------------------
     # The shared runtime calls these (never under its condition variable)
-    # so this scheduler's legacy per-instance counters — queue depth,
-    # wait histogram, occupancy, shed/completed/failed — stay truthful
-    # while the actual draining happens on the unified executor.
+    # so this scheduler's per-instance counters — queue depth, wait
+    # histogram, occupancy, shed/completed/failed — stay truthful while
+    # the actual draining happens on the unified executor.
     def _obs_enqueued(self) -> None:
         with self._mx:
             self._runtime_pending += 1
@@ -321,130 +250,6 @@ class ServingScheduler:
         with self._mx:
             self._counters["completed_total" if ok else "failed_total"] += n
 
-    # -- device-step loop (legacy, PATHWAY_RUNTIME=0) --------------------
-    def _ensure_thread(self) -> None:
-        if self._thread is None or not self._thread.is_alive():
-            self._thread = threading.Thread(
-                target=self._loop, daemon=True, name="pw-sched"
-            )
-            self._thread.start()
-
-    def _loop(self) -> None:
-        from ...internals.flight_recorder import name_thread
-
-        name_thread("pw-sched")
-        while True:
-            with self._cv:
-                while not self._queue:
-                    self._cv.wait()
-                # admission window: from the first pending item, wait up
-                # to max_wait_ms for concurrent requests to join the tick,
-                # flushing early once max_batch items are pending
-                flush_at = time.monotonic() + self.max_wait_ms / 1000.0
-                while len(self._queue) < self.max_batch:
-                    remaining = flush_at - time.monotonic()
-                    if remaining <= 0:
-                        break
-                    self._cv.wait(timeout=remaining)
-                items, self._queue = self._queue, []
-            try:
-                self._run_tick(items)
-            except BaseException as exc:  # noqa: BLE001 — the loop must
-                # survive; per-item errors are already routed to futures in
-                # _execute, so anything landing here is a harness bug: fail
-                # the unresolved items with the ACTUAL exception (a generic
-                # wrapper would make the defect undiagnosable)
-                for it in items:
-                    if not it.future.done():
-                        it.future.set_exception(exc)
-
-    def _run_tick(self, items: list[_WorkItem]) -> None:
-        now = time.monotonic()
-        groups: dict[int, tuple[WorkGroup, list[_WorkItem]]] = {}
-        for it in items:  # submission order preserved: results must zip
-            groups.setdefault(id(it.group), (it.group, []))[1].append(it)
-        for group, gitems in groups.values():
-            live: list[_WorkItem] = []
-            for it in gitems:
-                self._observe_wait((now - it.enqueued_at) * 1000.0)
-                if it.trace is not None:
-                    it.trace.add_stage_mono("queue_wait", it.enqueued_at, now)
-                if it.deadline_at is not None and now > it.deadline_at:
-                    with self._mx:
-                        self._counters["shed_deadline_total"] += 1
-                    if not it.future.done():  # client may have cancelled
-                        it.future.set_exception(
-                            DeadlineExceeded(
-                                "deadline exceeded before dispatch "
-                                f"(queued {(now - it.enqueued_at) * 1000:.1f} ms)",
-                                retry_after_s=self.retry_after_s,
-                            )
-                        )
-                else:
-                    live.append(it)
-            for chunk in _budget_chunks(group, live):
-                self._execute(group, chunk)
-
-    def _execute(self, group: WorkGroup, chunk: list[_WorkItem]) -> None:
-        if not chunk:
-            return
-        from ...internals.flight_recorder import batch_traces, span
-
-        with self._mx:
-            self._counters["batches_total"] += 1
-            if len(chunk) > 1:
-                self._counters["multi_item_batches_total"] += 1
-            self._occupancy_sum += len(chunk)
-            if len(chunk) > self._occupancy_max:
-                self._occupancy_max = len(chunk)
-        # honor the batcher's dispatch lock: build-time probes may call the
-        # model off-thread while the loop runs
-        lock = getattr(group, "_dispatch_lock", None)
-        traces = [it.trace for it in chunk if it.trace is not None]
-        timed = span(
-            f"tick:{group.label}", "scheduler",
-            scheduler=self.name, occupancy=len(chunk), ok=True,
-        )
-        try:
-            with timed:  # a raising body reads ok=False
-                from ...testing import faults
-
-                if faults.enabled:
-                    # chaos site "scheduler.step": a failed device step
-                    # fans out to the batch's waiters like any handler
-                    # error
-                    faults.perturb("scheduler.step")
-                # batch-scope the riding traces: the handler's stage
-                # timers (embed, search) stamp onto every request in
-                # the tick
-                with batch_traces(traces):
-                    if lock is not None:
-                        with lock:
-                            results = group.batch_fn(
-                                [it.payload for it in chunk]
-                            )
-                    else:
-                        results = group.batch_fn(
-                            [it.payload for it in chunk]
-                        )
-                if len(results) != len(chunk):
-                    raise RuntimeError(
-                        f"batch handler {group.label!r} returned "
-                        f"{len(results)} results for {len(chunk)} items"
-                    )
-        except BaseException as exc:  # noqa: BLE001 — propagate to every waiter
-            with self._mx:
-                self._counters["failed_total"] += len(chunk)
-            for it in chunk:
-                if not it.future.done():
-                    it.future.set_exception(exc)
-            return
-        with self._mx:
-            self._counters["completed_total"] += len(chunk)
-        for it, res in zip(chunk, results):
-            if not it.future.done():
-                it.future.set_result(res)
-
     def _observe_wait(self, wait_ms: float) -> None:
         with self._mx:
             self._wait_sum_ms += wait_ms
@@ -458,16 +263,13 @@ class ServingScheduler:
 
     # -- observability ---------------------------------------------------
     def stats(self) -> dict[str, Any]:
-        with self._cv:
-            depth = len(self._queue)
         with self._mx:
-            # facade mode: pending items live on the shared runtime's
-            # interactive queue, tracked per scheduler via the hooks
-            depth += self._runtime_pending
             batches = self._counters["batches_total"]
             return {
                 **self._counters,
-                "queue_depth": depth,
+                # pending items live on the shared runtime's interactive
+                # queue, tracked per scheduler via the hooks
+                "queue_depth": self._runtime_pending,
                 "queue_depth_max": self._queue_depth_max,
                 "batch_occupancy_mean": (
                     self._occupancy_sum / batches if batches else 0.0

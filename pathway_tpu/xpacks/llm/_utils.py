@@ -285,15 +285,12 @@ class AsyncMicroBatcher:
     When shared-executor serving is enabled (the default) calls delegate
     to the process-wide executor instead: work coalesces ACROSS engine
     steps and REST planes, not just within one loop round, and every
-    device dispatch serializes on the executor thread.  Under the
-    unified device-tick runtime (``PATHWAY_RUNTIME=1``, default) the
-    batcher submits its items as ``LLM_RERANK``-class work — below
-    interactive serving ticks, above bulk ingest; with
-    ``PATHWAY_RUNTIME=0`` it delegates to the legacy
-    :class:`~pathway_tpu.xpacks.llm._scheduler.ServingScheduler` loop.
-    ``use_scheduler`` pins the behavior per batcher (None = follow the
-    global ``PATHWAY_SERVING_SCHEDULER`` setting; False = per-loop
-    micro-batching only).
+    device dispatch serializes on the executor thread.  The batcher
+    submits its items to the unified device-tick runtime as
+    ``LLM_RERANK``-class work — below interactive serving ticks, above
+    bulk ingest.  ``use_scheduler`` pins the behavior per batcher (None =
+    follow the global ``PATHWAY_SERVING_SCHEDULER`` setting; False =
+    per-loop micro-batching only).
     """
 
     def __init__(
@@ -309,9 +306,9 @@ class AsyncMicroBatcher:
         # token-budget admission: a flush fires once the PENDING batch's
         # estimated token mass reaches ``max_tokens`` — batch size adapts
         # to document length, so a run of long documents flushes small
-        # while a run of tweets still fills ``max_batch``.  The serving
-        # scheduler honors the same attributes when it chunk-drains this
-        # batcher as a WorkGroup.
+        # while a run of tweets still fills ``max_batch``.  The runtime
+        # honors the same attributes when it chunk-drains this batcher
+        # as a WorkGroup.
         self.max_tokens = max_tokens
         self.token_estimate = token_estimate or estimate_tokens
         self.label = getattr(batch_fn, "__name__", "batch")
@@ -322,14 +319,6 @@ class AsyncMicroBatcher:
         self._pending: dict[int, list[tuple[Any, asyncio.Future]]] = {}
         self._pending_tokens: dict[int, int] = {}
 
-    def _scheduler(self):
-        from ._scheduler import get_scheduler, scheduler_enabled
-
-        use = self.use_scheduler
-        if use is None:
-            use = scheduler_enabled()
-        return get_scheduler() if use else None
-
     async def call(self, item: Any) -> Any:
         use = self.use_scheduler
         if use is None:
@@ -337,19 +326,14 @@ class AsyncMicroBatcher:
 
             use = scheduler_enabled()
         if use:
-            from ...runtime import QoS, get_runtime, runtime_enabled
+            from ...runtime import QoS, get_runtime
 
-            if runtime_enabled():
-                # engine-plane embed/rerank/LLM-guard work rides the
-                # unified runtime as LLM_RERANK: below interactive
-                # serving, above bulk ingest, never shed (no deadline)
-                return await get_runtime().submit_async(
-                    self, item, qos=QoS.LLM_RERANK
-                )
-        sched = self._scheduler() if use else None
-        if sched is not None:
-            # engine-plane work carries no deadline: it is never shed
-            return await sched.submit_async(self, item)
+            # engine-plane embed/rerank/LLM-guard work rides the
+            # unified runtime as LLM_RERANK: below interactive
+            # serving, above bulk ingest, never shed (no deadline)
+            return await get_runtime().submit_async(
+                self, item, qos=QoS.LLM_RERANK
+            )
         loop = asyncio.get_running_loop()
         lid = id(loop)
         lst = self._pending.setdefault(lid, [])

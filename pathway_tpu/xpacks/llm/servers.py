@@ -80,8 +80,7 @@ class DocumentStoreServer(BaseRestServer):
     With the serving scheduler enabled (default), ``/v1/retrieve``
     answers off the shared cross-request scheduler (fused embed→search,
     deadline shedding) when the store exposes a plane for it; hybrid or
-    embedder-less stores keep the engine-routed endpoint.  Under the
-    unified device-tick runtime (``PATHWAY_RUNTIME=1``, default) those
+    embedder-less stores keep the engine-routed endpoint.  Those
     ticks run as ``INTERACTIVE``-class work on the shared QoS executor,
     ahead of engine-plane rerank/embed micro-batches (``LLM_RERANK``)
     and bulk ingest (``BULK_INGEST``).

@@ -320,9 +320,8 @@ class VectorStoreServer:
         micro-batch cadence — with ``deadline_ms``-based shedding
         (503 + Retry-After).  Statistics/inputs stay engine-routed.
 
-        Under the unified device-tick runtime (``PATHWAY_RUNTIME=1``,
-        the default) those ticks execute as ``INTERACTIVE``-class work
-        on the process-wide QoS executor: they preempt bulk-ingest
+        Those ticks execute as ``INTERACTIVE``-class work on the
+        process-wide QoS executor (the unified device-tick runtime): they preempt bulk-ingest
         chunks at tick granularity, so serving p99 survives ingest
         bursts (see README "Operations: unified runtime & QoS classes";
         per-class state rides ``/v1/health`` and ``/status``).

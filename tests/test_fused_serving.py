@@ -5,7 +5,9 @@ Pins the fused-serving contract end to end:
 * fused-vs-reference top-k parity BIT-EXACT at f32 — dense (f32/bf16
   storage), int8 codes + rescore ring, the forced Pallas megakernel
   body (interpret mode on CPU), mesh 1/2 sharding, and the tiered hot
-  tier all produce the same keys AND scores as the staged legacy chain;
+  tier all produce the same keys AND scores as the staged legacy chain
+  (the two-shard float32 index against the SINGLE-device one: keys
+  exact, scores within the float32 bound of a reordered sum);
 * exact tie order: equal scores surface lowest-slot-first in every
   formulation (the ``lax.top_k`` stable order the megakernel's online
   merge reproduces);
@@ -144,7 +146,25 @@ def test_sharded_fused_parity(mesh_n, index_dtype, monkeypatch):
     q = _vecs(5, seed=5)
     ref = _search(shard, q, 7, "reference", monkeypatch)
     assert _search(shard, q, 7, "auto", monkeypatch) == ref
-    assert _search(single, q, 7, "auto", monkeypatch) == ref
+    got = _search(single, q, 7, "auto", monkeypatch)
+    if mesh_n == 1 or index_dtype == "int8":
+        # one shard is the single-device program; int8 codes dot in
+        # integers, which no order of summation changes
+        assert got == ref
+    else:
+        # over two shards the float32 matmul is another XLA program than
+        # the single-device one and may sum a row's 16 products in
+        # another order: keys and their order stay exact, a cos score
+        # may move by 2 * dim * eps (the dot of two unit vectors, and
+        # half of that for each of the two norms they were divided by;
+        # observed here: 3.0e-8)
+        assert [[k for k, _ in row] for row in got] == [
+            [k for k, _ in row] for row in ref
+        ]
+        tol = 2 * q.shape[1] * float(np.finfo(np.float32).eps)
+        for row_g, row_r in zip(got, ref):
+            for (_, a), (_, b) in zip(row_g, row_r):
+                assert abs(a - b) <= tol, (a, b, tol)
     qd = jnp.asarray(q)
     assert _search(shard, qd, 7, "auto", monkeypatch) == \
         _search(shard, qd, 7, "reference", monkeypatch)

@@ -41,18 +41,6 @@ def _mixed_texts(n, seed=0, max_words=110):
 # ---------------------------------------------------------------------------
 
 
-def test_packed_parity_with_legacy_whole_batch(enc):
-    """The packed per-bucket path must reproduce the legacy whole-batch
-    path bit-for-bit in f32: masked attention/pooling make each row's
-    result independent of how much padding rides alongside it."""
-    texts = _mixed_texts(37)
-    ids, mask = enc.tokenizer.encode_batch(texts, max_length=128)
-    fwd = lambda i, m: enc._apply(enc.params, i, m)  # noqa: E731
-    out_packed = bucketed_dispatch(fwd, ids, mask, 128, vocab_size=1024, packed=True)
-    out_legacy = bucketed_dispatch(fwd, ids, mask, 128, vocab_size=1024, packed=False)
-    np.testing.assert_array_equal(out_packed, out_legacy)
-
-
 def test_packed_bit_exact_vs_manual_bucket_dispatch(enc):
     """Rows of one seq bucket dispatched by the packed path must be
     BIT-exact with a hand-built pad_chunk dispatch at the same (bb, seq)
@@ -61,7 +49,7 @@ def test_packed_bit_exact_vs_manual_bucket_dispatch(enc):
     texts = _mixed_texts(10, seed=3, max_words=25)  # all land in seq 32
     ids, mask = enc.tokenizer.encode_batch(texts, max_length=128)
     fwd = lambda i, m: enc._apply(enc.params, i, m)  # noqa: E731
-    out_packed = bucketed_dispatch(fwd, ids, mask, 128, vocab_size=1024, packed=True)
+    out_packed = bucketed_dispatch(fwd, ids, mask, 128, vocab_size=1024)
     pids, pmask, _ = pad_chunk(ids[:, :32], mask[:, :32], 32, 32, ids_dtype=np.uint16)
     manual = np.asarray(fwd(jnp.asarray(pids), jnp.asarray(pmask)), np.float32)
     np.testing.assert_array_equal(out_packed, manual[:10])
@@ -120,12 +108,12 @@ def test_compile_set_flat_across_mixed_length_batches(enc):
         batches.append(enc.tokenizer.encode_batch(texts, max_length=128))
     # first pass warms whatever grid shapes these mixes hit...
     for ids, mask in batches:
-        bucketed_dispatch(fwd, ids, mask, 128, vocab_size=1024, packed=True)
+        bucketed_dispatch(fwd, ids, mask, 128, vocab_size=1024)
     before = compile_stats().get("encoder.forward", 0)
     # ...after which ANY reordering/repetition of heterogeneous-length
     # traffic re-uses the compiled set: zero new compilations
     for ids, mask in batches + batches[::-1]:
-        bucketed_dispatch(fwd, ids, mask, 128, vocab_size=1024, packed=True)
+        bucketed_dispatch(fwd, ids, mask, 128, vocab_size=1024)
     assert compile_stats().get("encoder.forward", 0) == before
 
 
@@ -158,7 +146,7 @@ def test_async_micro_batcher_token_budget_flush():
 
 
 def test_scheduler_budget_chunks():
-    from pathway_tpu.xpacks.llm._scheduler import WorkGroup, _budget_chunks
+    from pathway_tpu.runtime import WorkGroup, budget_chunks as _budget_chunks
     from pathway_tpu.xpacks.llm._utils import AsyncMicroBatcher
 
     class Item:

@@ -573,7 +573,7 @@ def test_decode_rides_generate_class_on_runtime():
     before = rt.stats()["classes"]["generate"]["completed_total"]
     s = DecodeSession(
         TINY, _lm().params, pool_tokens=2048, block_size=16,
-        auto=True, use_runtime=True,
+        auto=True,
     )
     h = s.submit([4, 5, 6], max_new_tokens=5)
     assert h.result(timeout=120) == _lm().generate_ids([[4, 5, 6]], 5)[0].tolist()
@@ -591,7 +591,7 @@ def test_interactive_p99_bounded_while_decode_backlog_drains():
     lm = _lm()
     s = DecodeSession(
         TINY, lm.params, pool_tokens=4096, block_size=16,
-        auto=True, use_runtime=True,
+        auto=True,
     )
     # warm every launch shape the backlog will use (row bucket 8)
     warm = [s.submit([i + 1, i + 2, i + 3], max_new_tokens=2) for i in range(6)]

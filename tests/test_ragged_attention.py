@@ -386,9 +386,8 @@ def test_fused_serving_tick_parity_with_ragged_impl(golden, monkeypatch):
     ]
 
 
-@pytest.mark.parametrize("use_runtime", [False, True])
-def test_ingest_pipeline_ragged_parity(golden, use_runtime):
-    """The ingest pipeline (and its runtime BULK_INGEST chunks) must
+def test_ingest_pipeline_ragged_parity(golden):
+    """The ingest pipeline (its runtime BULK_INGEST chunks) must
     dispatch ragged payloads end to end: futures resolve to embeddings
     identical to direct encode, and with an index attached the staged
     device upsert searches identically."""
@@ -397,12 +396,12 @@ def test_ingest_pipeline_ragged_parity(golden, use_runtime):
 
     enc = _ragged(golden)
     texts = _mixed_texts(17, seed=13)
-    with IngestPipeline(enc, use_runtime=use_runtime) as pipe:
+    with IngestPipeline(enc) as pipe:
         emb = pipe.submit(texts).result(timeout=120)
     np.testing.assert_allclose(emb, enc.encode(texts), atol=1e-6)
 
     index = DeviceKnnIndex(dim=enc.dim, capacity=64)
-    with IngestPipeline(enc, index, use_runtime=use_runtime) as pipe:
+    with IngestPipeline(enc, index) as pipe:
         n = pipe.submit(texts, keys=[f"k{i}" for i in range(17)]).result(
             timeout=120
         )

@@ -2,11 +2,9 @@
 preempts a saturating bulk backlog at tick granularity, the starvation
 bound keeps ingest progressing under sustained interactive load),
 per-class admission control with Retry-After, inline re-entrant submits
-without class inversion, tick-budget composition, runtime-vs-legacy
-(``PATHWAY_RUNTIME=0``) result parity for all three planes, bounded
-upsert slicing, and runtime observability on /status and /v1/health."""
+without class inversion, tick-budget composition, bounded upsert
+slicing, and runtime observability on /status and /v1/health."""
 
-import asyncio
 import socket
 import threading
 import time
@@ -16,16 +14,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import pathway_tpu as pw
 from pathway_tpu.runtime import (
     AdmissionRefused,
     DeadlineExceeded,
     DeviceTickRuntime,
     QoS,
     WorkGroup,
-    configure,
     get_runtime,
-    runtime_enabled,
 )
 
 
@@ -35,28 +30,6 @@ def _free_port() -> int:
     port = s.getsockname()[1]
     s.close()
     return port
-
-
-def _wait_http(call, timeout=15.0):
-    deadline = time.monotonic() + timeout
-    last = None
-    while time.monotonic() < deadline:
-        try:
-            return call()
-        except Exception as exc:  # noqa: BLE001 — server still starting
-            last = exc
-            time.sleep(0.2)
-    raise TimeoutError(f"server did not come up: {last}")
-
-
-@pytest.fixture
-def legacy_runtime():
-    """Run the body with PATHWAY_RUNTIME logically =0, restoring after."""
-    configure(enabled=False)
-    try:
-        yield
-    finally:
-        configure(enabled=True)
 
 
 # ---------------------------------------------------------------------------
@@ -262,116 +235,6 @@ def test_tick_budget_composition_strict_priority_with_reservation():
     assert first_bulk == ("bulk", 1), calls
     assert sum(n for l, n in calls if l == "inter") == 5
     assert sum(n for l, n in calls if l == "bulk") == 4
-
-
-# ---------------------------------------------------------------------------
-# runtime-vs-legacy parity: all three planes
-# ---------------------------------------------------------------------------
-
-SMALL = None
-
-
-def _small_encoder():
-    global SMALL
-    if SMALL is None:
-        from pathway_tpu.models.encoder import EncoderConfig, SentenceEncoder
-
-        SMALL = SentenceEncoder(
-            cfg=EncoderConfig(
-                vocab_size=1024, hidden_dim=32, num_layers=2, num_heads=4,
-                mlp_dim=64, max_len=128, dtype=jnp.float32,
-            ),
-            max_length=128,
-        )
-    return SMALL
-
-
-def test_ingest_pipeline_parity_runtime_vs_legacy():
-    """The BULK_INGEST runtime path must be BIT-exact with the legacy
-    in-thread device loop: same chunks, same launches, same numerics."""
-    from pathway_tpu.xpacks.llm._ingest import IngestPipeline
-
-    enc = _small_encoder()
-    rng = np.random.default_rng(3)
-    texts = [
-        " ".join(f"w{rng.integers(0, 50)}" for _ in range(int(k)))
-        for k in rng.integers(1, 110, size=23)
-    ]
-    with IngestPipeline(enc, use_runtime=True) as pipe:
-        out_rt = pipe.submit(texts).result(timeout=120)
-    with IngestPipeline(enc, use_runtime=False) as pipe:
-        out_legacy = pipe.submit(texts).result(timeout=120)
-    np.testing.assert_array_equal(out_rt, out_legacy)
-
-
-def test_micro_batcher_parity_runtime_vs_legacy(legacy_runtime):
-    """AsyncMicroBatcher results are identical whether its flushes ride
-    the unified runtime (LLM_RERANK) or the legacy scheduler loop."""
-    from pathway_tpu.xpacks.llm._utils import AsyncMicroBatcher
-
-    def batch_fn(items):
-        return [i * 3 for i in items]
-
-    async def drive(batcher):
-        return await asyncio.gather(*[batcher.call(i) for i in range(9)])
-
-    legacy = asyncio.run(drive(AsyncMicroBatcher(batch_fn, max_batch=16)))
-    configure(enabled=True)
-    fused = asyncio.run(drive(AsyncMicroBatcher(batch_fn, max_batch=16)))
-    configure(enabled=False)  # the fixture restores True afterwards
-    assert legacy == fused == [i * 3 for i in range(9)]
-
-
-@pytest.fixture
-def corpus_dir(tmp_path):
-    for i in range(6):
-        (tmp_path / f"doc{i}.txt").write_text(
-            f"Document {i} about topic-{i % 3} with unique marker m{i}."
-        )
-    return tmp_path
-
-
-def _start_server(corpus_dir):
-    from pathway_tpu.xpacks.llm import mocks
-    from pathway_tpu.xpacks.llm.vector_store import (
-        VectorStoreClient,
-        VectorStoreServer,
-    )
-
-    docs = pw.io.fs.read(
-        corpus_dir, format="binary", mode="streaming", with_metadata=True,
-        refresh_interval=0.2,
-    )
-    vs = VectorStoreServer(docs, embedder=mocks.FakeEmbedder(dim=8))
-    port = _free_port()
-    vs.run_server(
-        host="127.0.0.1", port=port, threaded=True, with_cache=False,
-        with_scheduler=True,
-    )
-    return VectorStoreClient(host="127.0.0.1", port=port)
-
-
-def test_serving_parity_runtime_vs_legacy(corpus_dir):
-    """/v1/retrieve through the scheduler facade returns exactly the
-    same results whether ticks execute on the unified runtime or the
-    legacy per-plane loop (PATHWAY_RUNTIME=0)."""
-    probe = "Document 2 about topic-2 with unique marker m2."
-    configure(enabled=False)
-    try:
-        legacy_client = _start_server(corpus_dir)
-        legacy_res = _wait_http(lambda: legacy_client.query(probe, k=3))
-        assert legacy_res and legacy_res[0]["text"] == probe
-    finally:
-        configure(enabled=True)
-    pw.global_graph.clear()  # second server: its own graph, same corpus
-    fused_client = _start_server(corpus_dir)
-    fused_res = _wait_http(lambda: fused_client.query(probe, k=3))
-    assert [r["text"] for r in fused_res] == [r["text"] for r in legacy_res]
-    for a, b in zip(fused_res, legacy_res):
-        assert a["dist"] == pytest.approx(b["dist"], abs=1e-6)
-    # the fused pass actually ran on the runtime: interactive work moved
-    stats = get_runtime().stats()
-    assert stats["classes"]["interactive"]["completed_total"] >= 1
 
 
 # ---------------------------------------------------------------------------

@@ -222,7 +222,13 @@ def test_fused_embed_search_parity_with_engine_path(corpus_dir):
     engine_res = _wait_http(lambda: engine_client.query(probe, k=3))
     assert engine_res and engine_res[0]["text"] == probe
 
+    from pathway_tpu.runtime import get_runtime
+
+    def interactive_done():
+        return get_runtime().stats()["classes"]["interactive"]["completed_total"]
+
     pw.global_graph.clear()  # second server: its own graph, same corpus
+    done_before = interactive_done()
     _, sched_client = _start_server(corpus_dir, with_scheduler=True)
     sched_res = _wait_http(lambda: sched_client.query(probe, k=3))
 
@@ -230,6 +236,8 @@ def test_fused_embed_search_parity_with_engine_path(corpus_dir):
     for a, b in zip(sched_res, engine_res):
         assert a["dist"] == pytest.approx(b["dist"], abs=1e-6)
         assert a["metadata"].get("path") == b["metadata"].get("path")
+    # the served query ran on the runtime: interactive work moved
+    assert interactive_done() >= done_before + 1
 
 
 def test_http_deadline_zero_sheds_with_503_retry_after(corpus_dir):

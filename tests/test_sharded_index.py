@@ -3,11 +3,14 @@ device-batch staging with mesh placement, apply-time scatter coalescing,
 and runtime-submitted sharded ticks under INTERACTIVE+BULK contention.
 
 Everything runs on the virtual 8-device CPU mesh (tests/conftest.py
-forces ``--xla_force_host_platform_device_count=8``).  Parity is pinned
-BIT-EXACT: the sharded search computes the same per-row dot products the
-single-device matmul does (each is the same length-D reduction), local
-top-k ties resolve in slot order, and the ICI merge concatenates shards
-in global-slot order — so keys AND scores must match to the last bit.
+forces ``--xla_force_host_platform_device_count=8``).  Parity: keys,
+their order and the counts are pinned EXACT (local top-k ties resolve in
+slot order and the ICI merge concatenates shards in global-slot order).
+Scores are pinned to the last bit where both sides run the same program
+(a 1-device mesh); over more shards the per-shard matmul is another XLA
+program than the single-device one and may sum a row's D products in
+another order, so scores compare within the float32 bound of that
+(:func:`_score_tol`).
 """
 
 from __future__ import annotations
@@ -40,6 +43,43 @@ def _vecs(n: int, dim: int = 16, seed: int = 0) -> np.ndarray:
     )
 
 
+def _score_tol(metric: str, q: np.ndarray, *rows: np.ndarray) -> float:
+    """How far two float32 programs may lie apart on one score when each
+    sums the same D products in its own order.  A float32 sum of D terms
+    is within (D-1)*u*sum|terms| of the exact one in ANY order (u =
+    eps/2), so two orders are within D*eps*sum|terms| of each other.  A
+    score is built from three such sums and their first-order errors
+    add.  cos: the dot of two unit vectors (sum|terms| <= 1) and the two
+    norms the row and the query were divided by (1/2 each: a relative
+    error passes a square root halved), 2 in all.  l2sq, ``2 q.v - |q|^2
+    - |v|^2``: 2|q||v| + |q|^2 + |v|^2 = (|q| + |v|)^2.  With D = 16
+    that is 3.8e-6 for cos (observed: 1.2e-7) and 2.2e-4 for l2sq
+    scores of magnitude 36 (observed: 1.9e-6); a wrong row is off by
+    1e-2 and more, and the keys are compared exactly besides."""
+    dim = q.shape[-1]
+    mass = 2.0
+    if metric == "l2sq":
+        longest = max(
+            float(np.linalg.norm(np.asarray(r), axis=-1).max()) for r in rows
+        )
+        mass = (float(np.linalg.norm(q, axis=-1).max()) + longest) ** 2
+    return dim * float(np.finfo(np.float32).eps) * mass
+
+
+def _assert_same_hits(got, want, tol: float) -> None:
+    """Same keys in the same order for every query (exact); scores equal
+    to the last bit when ``tol`` is 0, else within ``tol``."""
+    assert [[k for k, _ in row] for row in got] == [
+        [k for k, _ in row] for row in want
+    ]
+    if tol == 0:
+        assert got == want
+        return
+    for row_g, row_w in zip(got, want):
+        for (_, a), (_, b) in zip(row_g, row_w):
+            assert abs(a - b) <= tol, (a, b, tol)
+
+
 @pytest.mark.parametrize("mesh_n", [1, 2, 8])
 @pytest.mark.parametrize("metric", ["cos", "l2sq"])
 def test_sharded_parity_search_upsert_delete(mesh_n, metric):
@@ -54,18 +94,20 @@ def test_sharded_parity_search_upsert_delete(mesh_n, metric):
     for idx in (single, shard):
         idx.upsert_batch(keys[20:], dev)
     q = _vecs(5, seed=3)
-    assert single.search(q, 7) == shard.search(q, 7)  # keys AND scores
-    # overwrite a host-staged key from a device batch and vice versa
     v2 = _vecs(2, seed=9)
+    # one shard: the same program as the single-device index, bit-exact
+    tol = 0 if mesh_n == 1 else _score_tol(metric, q, vecs, v2)
+    _assert_same_hits(shard.search(q, 7), single.search(q, 7), tol)
+    # overwrite a host-staged key from a device batch and vice versa
     for idx in (single, shard):
         idx.upsert_batch(keys[:1], jnp.asarray(v2[:1]))
         idx.upsert(keys[25], v2[1])
-    assert single.search(q, 7) == shard.search(q, 7)
+    _assert_same_hits(shard.search(q, 7), single.search(q, 7), tol)
     # deletes
     for idx in (single, shard):
         for k in keys[5:15]:
             idx.remove(k)
-    assert single.search(q, 7) == shard.search(q, 7)
+    _assert_same_hits(shard.search(q, 7), single.search(q, 7), tol)
 
 
 def test_degenerate_single_device_mesh_bit_identical():
@@ -110,7 +152,9 @@ def test_corpus_larger_than_one_shard_capacity_grows_and_serves():
     single.upsert_batch(keys, jnp.asarray(vecs))
     shard.upsert_batch(keys, jnp.asarray(vecs))
     q = _vecs(4, seed=11)
-    assert single.search(q, 12) == shard.search(q, 12)
+    _assert_same_hits(
+        shard.search(q, 12), single.search(q, 12), _score_tol("cos", q)
+    )
     assert shard.capacity == single.capacity >= n
     assert shard.capacity % shard.n_shards == 0
     assert shard.vectors.sharding == shard._vec_sharding
@@ -207,7 +251,9 @@ def test_coalescing_keeps_mesh_placement(monkeypatch):
     for idx in (single, shard):
         idx.upsert_batch(keys, jnp.asarray(vecs))
     q = _vecs(2, dim=8, seed=8)
-    assert single.search(q, 5) == shard.search(q, 5)
+    _assert_same_hits(
+        shard.search(q, 5), single.search(q, 5), _score_tol("cos", q)
+    )
     assert shard.vectors.sharding == shard._vec_sharding
     assert shard.scatter_dispatches <= 3
 
@@ -255,7 +301,7 @@ def test_runtime_sharded_ticks_under_interactive_and_bulk_contention():
     )
 
     results: list = []
-    with IngestPipeline(enc, sharded, use_runtime=True) as pipe:
+    with IngestPipeline(enc, sharded) as pipe:
         futs = [
             pipe.submit(texts[i : i + 16], keys=keys[i : i + 16])
             for i in range(0, 96, 16)
@@ -282,7 +328,7 @@ def test_runtime_sharded_ticks_under_interactive_and_bulk_contention():
 
     # oracle: same encoder outputs into a single-device index
     oracle = DeviceKnnIndex(dim=enc.dim, capacity=sharded.index.capacity)
-    with IngestPipeline(enc, oracle, use_runtime=False) as pipe:
+    with IngestPipeline(enc, oracle) as pipe:
         pipe.submit(texts, keys=keys).result(timeout=120)
     q = enc.encode(["subject 3 documents"])
     r_shard = sharded.index.search(q, 8)
