@@ -31,16 +31,30 @@ Precision: weights are held in ``param_dtype`` (bfloat16) and products take
 stream, norms, rotary tables, softmax, the router and the combine are
 float32.
 
-Attention is XLA over blocks of ``q_block`` queries against the keys a
-block can see (the block itself and what precedes it, on window layers
-only as far back as the window reaches): no [T, T] score matrix is ever
-held, and a window layer's work grows with T, not T^2.
+Attention is XLA over blocks of ``q_block`` queries.  A query block visits
+the key blocks from the first that holds a token some query of it may see
+(the start of the text its first token belongs to; on window layers no
+further back than the window reaches) up to itself, and a block of pure
+padding visits none: no [T, T] score matrix is ever held, a window layer's
+work grows with T, not T^2, and a text never pays for the keys of the texts
+packed before it.  The softmax is the one-shot one taken in two passes over
+those blocks (the row's maximum and sum first, then ``exp(s - max) / sum``
+rounded to the compute dtype and multiplied by the values), so what is
+rounded where does not depend on how many blocks a row saw.
 
 Two layouts over one parameter tree, as the BERT encoder has them: the
 dense forward ([batch, seq] ids and mask, padding behind the text) and the
-packed ragged forward (rows concatenated along one token axis with segment
-ids and positions).  A padding token is routed to no expert, and under a
-causal mask no real token sees one.
+packed forward (rows concatenated along one token axis with segment ids
+and positions).  The packed one serves (``attention_impl="ragged"``, the
+default): the routed layers take a launch's tokens as one axis, so
+documents that share a launch share one read of every expert they touch,
+where a launch a document reads nearly all of them again; and rows of any
+lengths go together, so the programs are one per TOKEN bucket
+(``token_buckets``, four) and not one per (rows, sequence) pair.  A lone
+document rides the same programs.  The dense forward stays for what feeds
+one text's states to one block (:meth:`CausalMoeEmbedder.layer`) and for
+``attention_impl="xla"``.  A padding token is routed to no expert, and
+under a causal mask no real token sees one.
 """
 
 from __future__ import annotations
@@ -105,17 +119,41 @@ class CausalMoeEmbedderConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.bfloat16
     seq_buckets: tuple[int, ...] = (32, 64, 128, 256, 512, 1024, 2048)
-    #: one row a launch: each (rows, sequence) program of a model this size
-    #: takes seconds to compile and about a second to load, and the rows of
-    #: a batch go out back to back before any result is collected
+    #: rows of a DENSE launch (``attention_impl="xla"``, and the row count a
+    #: fused serving tick hands the search): one, because each (rows,
+    #: sequence) program of a model this size takes seconds to compile and
+    #: about a second to load.  The packed dispatch does not read it
     batch_buckets: tuple[int, ...] = (1,)
     #: queries a block of attention takes at once
     q_block: int = 512
-    #: "xla" (the dense [batch, seq] dispatch) or "ragged" (packed launches)
-    attention_impl: str = "xla"
+    #: "ragged": a call's rows go out packed along one token axis, as many
+    #: to a launch as ``token_buckets[-1]`` tokens hold, so an expert is
+    #: read once a launch and not once a document; "xla": the dense
+    #: [batch, seq] dispatch, one row a launch
+    attention_impl: str = "ragged"
+    #: token counts a packed launch is padded to, each one compiled
+    #: program; the last is the most a launch holds, and a call over it
+    #: goes out as several launches.  Even steps and not a doubling
+    #: ladder: on a TPU v5e a launch costs 17 ms for the experts plus
+    #: 12.5 us a token of its BUCKET, padding or not, so the quarter of a
+    #: launch that a doubled bucket pads on average costs what a second
+    #: launch's read of the experts does.  Four and not more: each program
+    #: takes 9-14 s to compile and 1.9 s to load, and six put set-up a
+    #: tenth over what five dense programs took (PERF.md 6, PR 32)
+    token_buckets: tuple[int, ...] = (1536, 3072, 4608, 6144)
 
     program_name: ClassVar[str] = "pw_moe_embedder_forward"
     emb_dim: ClassVar[None] = None  # the vector is the hidden state
+    #: row counts the packed launch's ``starts`` operand is padded to: one,
+    #: so that how many documents share a launch mints no program
+    packed_row_buckets: ClassVar[tuple[int, ...]] = (32,)
+    #: attention never unpacks to a dense [rows, sequence] shape: rows of
+    #: any lengths share a packed launch, which carries no sequence bucket
+    packed_unpacks_rows: ClassVar[bool] = False
+    #: the first packed dispatch launches every token bucket once on
+    #: padding: a program takes seconds to compile and a second to load,
+    #: which a lone short document would otherwise pay under live traffic
+    warm_packed: ClassVar[bool] = True
 
     def __post_init__(self):
         n = len(self.layer_types)
@@ -180,34 +218,67 @@ def _rotate(x, pos, spec: RotarySpec):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
 
 
-def _attention(q, k, v, pos, seg, *, window: int | None, q_block: int):
+def _attention(q, k, v, pos, seg, valid, *, window: int | None, q_block: int):
     """Causal grouped-query attention of one token axis.  ``q`` [T, H, hd],
     ``k``/``v`` [T, KV, hd] in the compute dtype, ``pos`` [T] positions in
-    the row, ``seg`` [T] row of each token (None: one row).  Returns
-    [T, H, hd] float32.  Query block ``i`` is scored against the key blocks
-    it can see only."""
+    the row; ``seg`` [T] the row of each token and ``valid`` [T] whether it
+    is one (both None: one row, every token real).  Returns [T, H, hd]
+    float32.  Query block ``i`` visits key blocks ``first..i``: ``first``
+    holds the start of the row its first token belongs to (a later row of
+    the block starts later), on window layers no further back than the
+    window reaches; a block whose first token is padding visits none."""
     t, h, hd = q.shape
     kv = k.shape[1]
-    q = q.reshape(t, kv, h // kv, hd)
     bq = min(q_block, t)
+    blocks = -(-t // bq)
+    if blocks * bq > t:  # whole blocks: what is added lies behind every token
+        behind = lambda a: a if a is None else jnp.pad(
+            a, ((0, blocks * bq - t),) + ((0, 0),) * (a.ndim - 1))
+        q, k, v, pos, seg, valid = (behind(a) for a in (q, k, v, pos, seg, valid))
+    q = q.reshape(blocks, bq, kv, h // kv, hd)
     back = 0 if window is None else -(-(window - 1) // bq)  # key blocks behind
-    token = jnp.arange(t)
-    outs = []
-    for lo in range(0, t, bq):
-        hi = min(lo + bq, t)
-        k_lo = 0 if window is None else max(0, lo - back * bq)
-        s = jnp.einsum("qkgd,tkd->kgqt", q[lo:hi], k[k_lo:hi],
-                       preferred_element_type=jnp.float32) / math.sqrt(hd)
-        qi, kj = token[lo:hi, None], token[None, k_lo:hi]
-        see = kj <= qi
-        if seg is not None:
-            see &= seg[lo:hi, None] == seg[None, k_lo:hi]
+    token = jnp.arange(blocks * bq)
+
+    def block(i):
+        lo = i * bq
+        at = lambda a, start=lo: jax.lax.dynamic_slice_in_dim(a, start, bq)
+        q_i, token_i, pos_i = q[i], at(token), at(pos)
+        first = 0 if seg is None else (lo - pos[lo]) // bq
         if window is not None:
-            see &= pos[lo:hi, None] - pos[None, k_lo:hi] < window
-        p = jax.nn.softmax(jnp.where(see[None, None], s, -1e30), axis=-1)
-        outs.append(jnp.einsum("kgqt,tkd->qkgd", p.astype(v.dtype), v[k_lo:hi],
-                               preferred_element_type=jnp.float32))
-    return jnp.concatenate(outs, axis=0).reshape(t, h, hd)
+            first = jnp.maximum(first, i - back)
+        last = i + 1 if valid is None else jnp.where(valid[lo], i + 1, first)
+
+        def scores(j):
+            s = jnp.einsum("qkgd,tkd->kgqt", q_i, at(k, j * bq),
+                           preferred_element_type=jnp.float32) / math.sqrt(hd)
+            see = at(token, j * bq)[None, :] <= token_i[:, None]
+            if seg is not None:
+                see &= at(seg)[:, None] == at(seg, j * bq)[None, :]
+            if window is not None:
+                see &= pos_i[:, None] - at(pos, j * bq)[None, :] < window
+            return jnp.where(see[None, None], s, -1e30)
+
+        def max_and_sum(j, carry):
+            m, l = carry
+            s = scores(j)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+            return m_new, (l * jnp.exp(m - m_new)
+                           + jnp.sum(jnp.exp(s - m_new[..., None]), axis=-1))
+
+        rows = (kv, h // kv, bq)
+        m, l = jax.lax.fori_loop(
+            first, last, max_and_sum,
+            (jnp.full(rows, -1e30, jnp.float32), jnp.zeros(rows, jnp.float32)))
+
+        def weigh(j, acc):
+            p = jnp.exp(scores(j) - m[..., None]) / l[..., None]
+            return acc + jnp.einsum("kgqt,tkd->qkgd", p.astype(v.dtype), at(v, j * bq),
+                                    preferred_element_type=jnp.float32)
+
+        return jax.lax.fori_loop(first, last, weigh,
+                                 jnp.zeros((bq, kv, h // kv, hd), jnp.float32))
+
+    return jax.lax.map(block, jnp.arange(blocks)).reshape(blocks * bq, h, hd)[:t]
 
 
 def _gated_mlp(x, w_gate_up, w_down):
@@ -218,7 +289,7 @@ def _gated_mlp(x, w_gate_up, w_down):
     return jnp.dot(act, w_down, preferred_element_type=jnp.float32)
 
 
-def _attend_row(cfg: CausalMoeEmbedderConfig, i: int, p, a, pos, seg):
+def _attend_row(cfg: CausalMoeEmbedderConfig, i: int, p, a, pos, seg, valid):
     """Steps 1-4 of layer ``i`` for one token axis: ``a`` [T, D] the normed
     input (float32) -> the attention's addition to the residual, float32."""
     dt = cfg.dtype
@@ -229,7 +300,7 @@ def _attend_row(cfg: CausalMoeEmbedderConfig, i: int, p, a, pos, seg):
     k = jnp.einsum("td,dhe->the", ad, p["wk"], preferred_element_type=jnp.float32)
     v = jnp.einsum("td,dhe->the", ad, p["wv"], preferred_element_type=jnp.float32)
     q, k = _rotate(q, pos, spec), _rotate(k, pos, spec)
-    o = _attention(q.astype(dt), k.astype(dt), v.astype(dt), pos, seg,
+    o = _attention(q.astype(dt), k.astype(dt), v.astype(dt), pos, seg, valid,
                    window=None if full else cfg.window, q_block=cfg.q_block)
     gate = jax.nn.sigmoid(jnp.dot(ad, p["wg"], preferred_element_type=jnp.float32))
     o = (o * gate[:, :, None]).astype(dt)
@@ -239,13 +310,14 @@ def _attend_row(cfg: CausalMoeEmbedderConfig, i: int, p, a, pos, seg):
 def _layer(cfg: CausalMoeEmbedderConfig, i: int, p, x, pos, seg, valid):
     """One block: ``x`` [B, T, D] float32 (attention is a row's own; the
     routed layer takes all B*T tokens as one axis, so an expert's weights
-    are read once for the launch)."""
+    are read once for the launch).  ``seg`` None: each of the B rows is one
+    text; else B is 1 and the row is texts packed end to end."""
     a = _rms_norm(x, p["attn_norm"], cfg.rms_eps)
     attend = functools.partial(_attend_row, cfg, i, p)
     if seg is None:
-        x = x + jax.vmap(lambda a_, pos_: attend(a_, pos_, None))(a, pos)
+        x = x + jax.vmap(lambda a_, pos_: attend(a_, pos_, None, None))(a, pos)
     else:
-        x = x + jax.vmap(attend)(a, pos, seg)
+        x = x + attend(a[0], pos[0], seg[0], valid[0])[None]
     b = _rms_norm(x, p["mlp_norm"], cfg.rms_eps)
     bd = b.astype(cfg.dtype)
     if cfg.mlp_types[i] == "dense":
@@ -335,10 +407,12 @@ class CausalMoeEmbedder:
                       dense_s: int | None = None):
         """Rows concatenated along one token axis (``ragged_prepare``):
         ``seg`` names each token's row, the pad tail carries
-        ``seg == rows``; ``starts`` [rows] is where each row begins.
-        ``bounds`` and ``dense_s`` serve the BERT encoder's kernel."""
+        ``seg == rows``; ``starts`` [rows] is where each row begins (rows
+        past the launch's own begin at 0 and hold no token).  ``bounds``
+        and ``dense_s`` serve the BERT encoder's kernel: here a query
+        block's key range comes from ``pos`` and ``seg`` on the device."""
         cfg = self.cfg
-        ids, pos, seg = (a.astype(jnp.int32) for a in (ids, pos, seg))
+        ids, pos, seg = (jnp.asarray(a, jnp.int32) for a in (ids, pos, seg))
         rows = starts.shape[0]
         valid = seg < rows
         x, sizes = _tokens_forward(

@@ -169,6 +169,7 @@ class CrossEncoder:
             type_ids_all=type_ids_all,
             vocab_size=self.cfg.vocab_size,
             max_tokens=self.max_tokens,
+            cfg=self.cfg,
         )
         record_padding(
             stats["real_tokens"], stats["padded_tokens"], stats["row_tokens"]

@@ -54,6 +54,17 @@ BATCH_BUCKETS = (1, 2, 4, 8, 32, 128, 256, 512, 1024)
 #: (``_chunk_sizes``); what is left, or a group that never had as many, is
 #: its tail
 TAIL_ROWS = 32
+#: launch sizes for the packed token axis: fine 128-token steps (the
+#: ragged kernel's block) up to 4096, then 512 steps to the VMEM cap —
+#: a FINITE shape set (the compile-flatness pin), with only tail-block
+#: alignment as padding (<=3% at any size, <1% amortized on full
+#: launches).  The 32/64 sub-block buckets keep a 1-row tick from
+#: padding to a full 128-token block.
+TOKEN_BUCKETS: tuple[int, ...] = (
+    (32, 64)
+    + tuple(range(128, 4096 + 1, 128))
+    + tuple(range(4608, 8192 + 1, 512))
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -93,10 +104,30 @@ class EncoderConfig:
     #: bulk load amortises for a BERT and nothing does for a model of
     #: gigabytes (models/causal_moe_embedder.py)
     batch_buckets: tuple[int, ...] = BATCH_BUCKETS
+    #: token counts a packed (``attention_impl="ragged"``) launch is padded
+    #: to; the last is the most one launch holds.  The model's own too: the
+    #: BERT's follow its kernel's 128-token block, a language-model
+    #: embedder's are few because each is a program of seconds
+    token_buckets: tuple[int, ...] = TOKEN_BUCKETS
 
     #: what :class:`SentenceEncoder` asks of any encoder config: the name
-    #: of its jitted programs in a device trace, and its two forwards
+    #: of its jitted programs in a device trace, its two forwards, and how
+    #: its packed launches are laid out
     program_name: ClassVar[str] = "pw_encoder_forward"
+    #: off the TPU the packed attention unpacks a launch's rows to a dense
+    #: [rows, sequence bucket] shape: the launch carries that bucket
+    #: (``dense_s``, part of the program's key), and whether rows of
+    #: different buckets may share it is :func:`ragged_mixes_buckets`'s call
+    packed_unpacks_rows: ClassVar[bool] = True
+    #: whether the first packed dispatch launches every token bucket once
+    #: on padding (42 token buckets by nine row counts are loaded on demand)
+    warm_packed: ClassVar[bool] = False
+
+    @property
+    def packed_row_buckets(self) -> tuple[int, ...]:
+        """Row counts a packed launch's ``starts`` is padded to; the last
+        is the most rows one launch takes."""
+        return self.batch_buckets
 
     def build_models(self):
         """(dense [batch, seq] forward, packed ragged forward) over one
@@ -560,19 +591,6 @@ def packed_prepare(
 # one token axis, ONE launch per tick, near-zero padding
 # ---------------------------------------------------------------------------
 
-#: launch sizes for the packed token axis: fine 128-token steps (the
-#: ragged kernel's block) up to 4096, then 512 steps to the VMEM cap —
-#: a FINITE shape set (the compile-flatness pin), with only tail-block
-#: alignment as padding (<=3% at any size, <1% amortized on full
-#: launches).  The 32/64 sub-block buckets keep a 1-row tick from
-#: padding to a full 128-token block.
-TOKEN_BUCKETS: tuple[int, ...] = (
-    (32, 64)
-    + tuple(range(128, 4096 + 1, 128))
-    + tuple(range(4608, 8192 + 1, 512))
-)
-
-
 class RaggedChunk:
     """One prepared ragged launch: rows concatenated along the token
     axis.  ``ids``/``pos``/``seg`` are per-token (pad tail carries
@@ -580,7 +598,8 @@ class RaggedChunk:
     CLS position — cross-encoder scoring gathers it); ``bounds`` is the
     per-q-block kv block range for the Pallas kernel
     (ops/ragged_attention.ragged_bounds); ``dense_s`` is the seq bucket
-    the XLA reference unpacks to off-TPU."""
+    the XLA reference unpacks to off-TPU (None for a model whose packed
+    attention never unpacks)."""
 
     __slots__ = ("ids", "pos", "seg", "type_ids", "starts", "bounds", "dense_s")
 
@@ -631,21 +650,21 @@ def ragged_plan(
     max_length: int,
     max_tokens: int | None = None,
     mix_buckets: bool | None = None,
-    seq_buckets: Sequence[int] = SEQ_BUCKETS,
-    batch_buckets: Sequence[int] = BATCH_BUCKETS,
+    cfg: Any = None,
 ) -> list[np.ndarray]:
     """Launch plan for the ragged layout: rows greedily packed until the
-    token budget (``max_tokens``, capped by the kernel's VMEM bound) or
-    the row bucket ceiling.  With ``mix_buckets`` (the TPU default, see
-    :func:`ragged_mixes_buckets`) rows pack in submission order into ONE
-    launch per budget window; without it rows group by their own seq
-    bucket first (the XLA reference's attention-cost guard).  Row order
-    inside a group preserves submission order so results re-zip
-    deterministically."""
-    from ..ops.ragged_attention import MAX_PACKED_TOKENS
-
+    token budget (``max_tokens``, capped by the largest token bucket) or
+    the row bucket ceiling.  With ``mix_buckets`` rows pack in submission
+    order into ONE launch per budget window; without it rows group by
+    their own seq bucket first (the XLA reference's attention-cost guard,
+    :func:`ragged_mixes_buckets`).  Row order inside a group preserves
+    submission order so results re-zip deterministically.  The buckets,
+    the row ceiling and (where ``mix_buckets`` is None) whether lengths
+    mix are the encoder config's (``cfg``; None: an ``EncoderConfig``'s)."""
+    cfg = cfg or EncoderConfig()
+    seq_buckets, row_buckets = cfg.seq_buckets, cfg.packed_row_buckets
     if mix_buckets is None:
-        mix_buckets = ragged_mixes_buckets()
+        mix_buckets = not cfg.packed_unpacks_rows or ragged_mixes_buckets()
     # same row cap as the bucketed dispatch: sequences truncate at the
     # largest seq bucket (over-cap documents go sequence-parallel via
     # the ring path, never through a single-device launch)
@@ -653,9 +672,9 @@ def ragged_plan(
         np.maximum(np.asarray(lengths, dtype=np.int64), 1),
         min(max_length, seq_buckets[-1]),
     )
-    cap = MAX_PACKED_TOKENS if max_tokens is None else min(
-        int(max_tokens), MAX_PACKED_TOKENS
-    )
+    cap = cfg.token_buckets[-1]
+    if max_tokens is not None:
+        cap = min(int(max_tokens), cap)
     # a single row must always fit (its length is bounded by the seq cap)
     cap = max(cap, int(lengths.max()) if len(lengths) else 1)
     groups: list[np.ndarray] = []
@@ -667,7 +686,7 @@ def ragged_plan(
         for j, r in enumerate(rows):
             if j > start and (
                 total + int(lengths[r]) > cap
-                or j - start >= batch_buckets[-1]
+                or j - start >= row_buckets[-1]
             ):
                 groups.append(rows[start:j])
                 start, total = j, 0
@@ -676,7 +695,7 @@ def ragged_plan(
             groups.append(rows[start:])
         return groups
     # reference-mode plan: group by seq bucket, then chunk each group on
-    # the BATCH_BUCKETS grid exactly like the packed path (_chunk_sizes)
+    # the row-bucket grid exactly like the packed path (_chunk_sizes)
     # — so the attention unpack's [row_bucket, seq_bucket] shape carries
     # no pad rows (a 64-row group must not round to a 128-row unpack)
     by_bucket: dict[int, list[int]] = {}
@@ -688,13 +707,48 @@ def ragged_plan(
         # bb*seq bounds the chunk's real tokens, so the VMEM/budget cap
         # holds a fortiori on the ragged axis
         start = 0
-        for bb in _chunk_sizes(len(rows), seq, 1, cap, batch_buckets):
+        for bb in _chunk_sizes(len(rows), seq, 1, cap, row_buckets):
             take = min(bb, len(rows) - start)
             groups.append(rows[start : start + take])
             start += take
             if start >= len(rows):
                 break
     return groups
+
+
+def ragged_chunk(rows, lengths, ids_all, type_ids_all, max_length: int,
+                 ids_dtype, cfg: Any, tokens: int = 0) -> "RaggedChunk":
+    """One launch of ``rows`` (indices into ``ids_all``) laid out along the
+    token axis, padded to ``cfg``'s token and row buckets; at least to the
+    bucket of ``tokens`` (no rows at all: a launch of padding alone, which
+    warms that bucket's program)."""
+    from ..ops.ragged_attention import ragged_block, ragged_bounds
+
+    t_bucket = _bucket(max(int(lengths[rows].sum()), tokens), cfg.token_buckets)
+    n_rows = _bucket(len(rows), cfg.packed_row_buckets)
+    dense_s = None
+    if cfg.packed_unpacks_rows:
+        longest = int(lengths[rows].max()) if len(rows) else 1
+        dense_s = min(_bucket(longest, cfg.seq_buckets), max_length)
+    ids = np.zeros(t_bucket, ids_dtype)
+    pos = np.zeros(t_bucket, np.uint16)
+    seg = np.full(t_bucket, n_rows, np.uint16)  # pad tail: OOB segment
+    tids = None if type_ids_all is None else np.zeros(t_bucket, np.uint8)
+    starts = np.zeros(n_rows, np.int32)
+    cu = np.zeros(len(rows) + 1, np.int64)
+    off = 0
+    for j, r in enumerate(rows):
+        ln = int(lengths[r])
+        ids[off : off + ln] = ids_all[r, :ln]
+        pos[off : off + ln] = np.arange(ln, dtype=np.uint16)
+        seg[off : off + ln] = j
+        if tids is not None:
+            tids[off : off + ln] = type_ids_all[r, :ln]
+        starts[j] = off
+        off += ln
+        cu[j + 1] = off
+    bounds = ragged_bounds(cu, t_bucket, ragged_block(t_bucket))
+    return RaggedChunk(ids, pos, seg, tids, starts, bounds, dense_s)
 
 
 def ragged_prepare(
@@ -705,59 +759,26 @@ def ragged_prepare(
     vocab_size: int = 1 << 31,
     max_tokens: int | None = None,
     mix_buckets: bool | None = None,
-    seq_buckets: Sequence[int] = SEQ_BUCKETS,
-    batch_buckets: Sequence[int] = BATCH_BUCKETS,
+    cfg: Any = None,
 ) -> tuple[list[tuple], dict]:
     """Host half of the ragged dispatch: tokenized rows → packed
     ``(RaggedChunk, rows, tokens)`` launches plus padding stats.  Every
     row occupies exactly its own length on the token axis (intra-bucket
     token padding is structurally zero — ``row_tokens == real_tokens``);
     only the tail block's bucket alignment pads."""
-    from ..ops.ragged_attention import ragged_block, ragged_bounds
-
+    cfg = cfg or EncoderConfig()
     lengths = np.minimum(
         np.maximum(np.asarray(mask_all.sum(axis=1), dtype=np.int64), 1),
-        min(max_length, seq_buckets[-1]),
+        min(max_length, cfg.seq_buckets[-1]),
     )
     ids_dtype = dispatch_dtype(vocab_size)
     prepared: list[tuple] = []
     padded_tokens = 0
-    for rows in ragged_plan(
-        lengths, max_length, max_tokens, mix_buckets, seq_buckets,
-        batch_buckets,
-    ):
-        t_real = int(lengths[rows].sum())
-        t_bucket = _bucket(t_real, TOKEN_BUCKETS)
-        n_rows = _bucket(len(rows), batch_buckets)
-        dense_s = min(
-            _bucket(int(lengths[rows].max()), seq_buckets), max_length
-        )
-        ids = np.zeros(t_bucket, ids_dtype)
-        pos = np.zeros(t_bucket, np.uint16)
-        seg = np.full(t_bucket, n_rows, np.uint16)  # pad tail: OOB segment
-        tids = None if type_ids_all is None else np.zeros(t_bucket, np.uint8)
-        starts = np.zeros(n_rows, np.int32)
-        cu = np.zeros(len(rows) + 1, np.int64)
-        off = 0
-        for j, r in enumerate(rows):
-            ln = int(lengths[r])
-            ids[off : off + ln] = ids_all[r, :ln]
-            pos[off : off + ln] = np.arange(ln, dtype=np.uint16)
-            seg[off : off + ln] = j
-            if tids is not None:
-                tids[off : off + ln] = type_ids_all[r, :ln]
-            starts[j] = off
-            off += ln
-            cu[j + 1] = off
-        bounds = ragged_bounds(cu, t_bucket, ragged_block(t_bucket))
-        prepared.append(
-            (
-                RaggedChunk(ids, pos, seg, tids, starts, bounds, dense_s),
-                rows,
-                t_bucket,
-            )
-        )
-        padded_tokens += t_bucket
+    for rows in ragged_plan(lengths, max_length, max_tokens, mix_buckets, cfg):
+        chunk = ragged_chunk(rows, lengths, ids_all, type_ids_all, max_length,
+                             ids_dtype, cfg)
+        prepared.append((chunk, rows, chunk.ids.shape[0]))
+        padded_tokens += chunk.ids.shape[0]
     real = int(lengths.sum())
     stats = {
         "rows": int(len(lengths)),
@@ -976,6 +997,7 @@ class SentenceEncoder:
         self.mesh = mesh
         self._batch_multiple = 1
         self._sp_mesh = None
+        self._packed_warm = False
         if mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec
 
@@ -1149,8 +1171,7 @@ class SentenceEncoder:
             return ragged_prepare(
                 ids_all, mask_all, self.max_length,
                 vocab_size=self.cfg.vocab_size, max_tokens=max_tokens,
-                seq_buckets=self.cfg.seq_buckets,
-                batch_buckets=self.cfg.batch_buckets,
+                cfg=self.cfg,
             )
         prepared, stats = packed_prepare(
             ids_all, mask_all, self.max_length,
@@ -1174,6 +1195,7 @@ class SentenceEncoder:
         payloads are ``(ids, mask, tids)``; ragged payloads are
         :class:`RaggedChunk` (one concatenated-token launch)."""
         if isinstance(payload, RaggedChunk):
+            self._warm_packed()
             args = payload.device_args()
             if self.mesh is not None:
                 # the packed token axis has no batch dim to shard —
@@ -1193,6 +1215,23 @@ class SentenceEncoder:
             args = [jax.device_put(a, sharding) for a in args]
         return self._apply(self.params, *args)
 
+    def _warm_packed(self) -> None:
+        """Before the first packed launch of an encoder whose config asks
+        for it (``warm_packed``): every token bucket launched once on
+        padding, so that each program is compiled or loaded here and not
+        under the first call that happens to need it."""
+        if self._packed_warm or not self.cfg.warm_packed:
+            return
+        self._packed_warm = True
+        none = np.zeros(0, np.int64)
+        jax.block_until_ready([
+            self.encode_prepared(ragged_chunk(
+                none, none, None, None, self.max_length,
+                dispatch_dtype(self.cfg.vocab_size), self.cfg, tokens=tokens,
+            ))
+            for tokens in self.cfg.token_buckets
+        ])
+
     def _encode_ragged(self, ids_all, mask_all) -> np.ndarray:
         """Ragged dispatch: one launch per token-budget group (ONE for a
         whole serving tick), order-preserving collection."""
@@ -1201,8 +1240,7 @@ class SentenceEncoder:
         prepared, stats = ragged_prepare(
             ids_all, mask_all, self.max_length,
             vocab_size=self.cfg.vocab_size, max_tokens=self.max_tokens,
-            seq_buckets=self.cfg.seq_buckets,
-            batch_buckets=self.cfg.batch_buckets,
+            cfg=self.cfg,
         )
         record_padding(
             stats["real_tokens"], stats["padded_tokens"], stats["row_tokens"]
@@ -1292,8 +1330,7 @@ class SentenceEncoder:
             # the fused tick IS the one-launch case — never split it by
             # seq bucket (the whole-tick launch is the contract)
             mix_buckets=True,
-            seq_buckets=self.cfg.seq_buckets,
-            batch_buckets=self.cfg.batch_buckets,
+            cfg=self.cfg,
         )
         if len(prepared) != 1:
             # a tick too big for one launch (token budget / VMEM cap)
@@ -1307,7 +1344,11 @@ class SentenceEncoder:
         record_padding(
             stats["real_tokens"], stats["padded_tokens"], stats["row_tokens"]
         )
-        return self.encode_prepared(payload), n
+        out = self.encode_prepared(payload)
+        # where the launch's rows are padded past the dense dispatch's row
+        # bucket (one fixed row count), hand the search that bucket's rows
+        bb = _bucket(n, self.cfg.batch_buckets)
+        return (out[:bb] if out.shape[0] > bb else out), n
 
     def _encode_ring(self, ids_all, mask_all) -> np.ndarray:
         """Sequence-parallel path for documents beyond the bucket cap."""

@@ -59,7 +59,7 @@ from encoders import laguna as builder  # noqa: E402
 from pathway_tpu.internals import flight_recorder  # noqa: E402
 from pathway_tpu.models import causal_moe_embedder as cme  # noqa: E402
 from pathway_tpu.models.encoder import (  # noqa: E402
-    SEQ_BUCKETS, EncoderConfig, SentenceEncoder)
+    SEQ_BUCKETS, TOKEN_BUCKETS, EncoderConfig, SentenceEncoder, ragged_plan, ragged_prepare)
 from pathway_tpu.ops import routed_experts as rx  # noqa: E402
 
 SEED = 2147483659
@@ -145,14 +145,19 @@ def _encode_reference(tiny, rows, precision="float32"):
         reference.tokenize = old
 
 
-def _program_rows(cfg, params, rows):
-    """The dense forward over token rows padded to one width: [n, D]."""
+def _padded(rows):
+    """Token rows padded behind to one width: ids and mask [n, width]."""
     width = max(len(r) for r in rows)
     ids = np.zeros((len(rows), width), np.int32)
     mask = np.zeros((len(rows), width), np.uint8)
     for i, r in enumerate(rows):
         ids[i, : len(r)], mask[i, : len(r)] = r, 1
-    out, _counters = cme.CausalMoeEmbedder(cfg).apply({"params": params}, ids, mask)
+    return ids, mask
+
+
+def _program_rows(cfg, params, rows):
+    """The dense forward over token rows padded to one width: [n, D]."""
+    out, _counters = cme.CausalMoeEmbedder(cfg).apply({"params": params}, *_padded(rows))
     return np.asarray(out)
 
 
@@ -309,6 +314,149 @@ def test_bucketed_and_ragged_plans_agree(tiny, params):
     np.testing.assert_allclose(out[0], out[1], rtol=0, atol=2e-4)
 
 
+# -- the packed dispatch: a call's documents share launches ---------------------
+
+
+def _one_layer(tiny, params, kind: str, **over):
+    """The model cut to ONE routed layer of ``kind`` (float32 products):
+    the config and its parameter tree."""
+    layer = {"full": 4, "window": 1}[kind]
+    cfg = _cfg(tiny, layer_types=(kind,), mlp_types=("sparse",), dtype=jnp.float32,
+               heads_per_layer=(builder.model_config(tiny).heads_per_layer[layer],), **over)
+    return cfg, {"tok_emb": params["tok_emb"], "final_norm": params["final_norm"],
+                 "layer_0": params[f"layer_{layer}"]}
+
+
+def _packed(cfg, rows):
+    """``rows`` (token id arrays) as ONE prepared launch of ``cfg``."""
+    ids, mask = _padded(rows)
+    prepared, _stats = ragged_prepare(ids, mask, ids.shape[1], vocab_size=cfg.vocab_size, cfg=cfg)
+    assert len(prepared) == 1
+    return prepared[0][0]
+
+
+# q_block is 16 and the window 8: a document shorter than a block, one over
+# several blocks, one that ends exactly on a block's edge (5 + 40 + 19 = 64),
+# one that is a whole block, then (80 tokens in the 128 bucket) three blocks of
+# padding; the second order starts with the long one and ends inside a block
+@pytest.mark.parametrize("lengths", [(5, 40, 19, 16), (40, 16, 5, 19, 3)])
+@pytest.mark.parametrize("kind", ["full", "window"])
+def test_documents_packed_together_get_the_vectors_of_the_dense_forward_alone(
+        tiny, params, kind, lengths):
+    cfg, p = _one_layer(tiny, params, kind, token_buckets=(32, 128))
+    assert cfg.q_block == 16 and cfg.window == 8
+    rows = [_ids(n, tiny["vocab_size"], seed=n) for n in lengths]
+    chunk = _packed(cfg, rows)
+    assert chunk.ids.shape == (128,) and chunk.starts.shape == cfg.packed_row_buckets
+    assert sum(lengths) <= 128 - 2 * cfg.q_block  # a pad tail of more than one block
+    forward = jax.jit(lambda *args: cme.CausalMoeEmbedder(cfg, packed=True).apply(
+        {"params": p}, *args)[0])
+    packed = lambda c: forward(c.ids, c.pos, c.seg, c.starts)
+    together = packed(chunk)
+    alone = np.stack([_program_rows(cfg, p, [r])[0] for r in rows])
+    np.testing.assert_allclose(np.asarray(together)[: len(rows)], alone, rtol=0, atol=2e-5)
+    # the block ranges are not decoration: a neighbour's tokens change nothing
+    other = [r if i != 1 else _ids(len(r), tiny["vocab_size"], seed=99)
+             for i, r in enumerate(rows)]
+    again = packed(_packed(cfg, other))
+    keep = [i for i in range(len(rows)) if i != 1]
+    np.testing.assert_allclose(np.asarray(again)[keep], alone[keep], rtol=0, atol=2e-5)
+
+
+@pytest.mark.parametrize("k", range(1, 9))
+def test_a_flush_under_the_cap_is_one_prepared_launch(published, k):
+    cfg = builder.model_config(published)
+    assert cfg.attention_impl == "ragged" and len(cfg.token_buckets) <= 6
+    cycle = [96, 192, 384, 768, 96, 1536, 192, 384]  # 3,648 tokens in all
+    lengths = np.asarray(cycle[:k])
+    mask = (np.arange(2048)[None, :] < lengths[:, None]).astype(np.uint8)
+    prepared, stats = ragged_prepare(np.ones(mask.shape, np.int32), mask, 2048,
+                                     vocab_size=cfg.vocab_size, cfg=cfg)
+    assert len(prepared) == 1 and stats["real_tokens"] == int(lengths.sum())
+    chunk, rows, tokens = prepared[0]
+    assert rows.tolist() == list(range(k))
+    assert tokens == min(b for b in cfg.token_buckets if b >= lengths.sum())
+    # the row count mints no program: one shape of ``starts`` whatever k is
+    assert chunk.starts.shape == (32,) and chunk.dense_s is None
+    assert chunk.starts[:k].tolist() == np.concatenate([[0], np.cumsum(lengths)[:-1]]).tolist()
+    assert (np.asarray(chunk.seg) == 32).sum() == tokens - lengths.sum()  # the pad tail
+
+
+def test_a_flush_over_the_cap_splits_in_submission_order(published):
+    cfg = builder.model_config(published)
+    cap = cfg.token_buckets[-1]
+    lengths = [96, 192, 384, 768, 1536, 2048] * 2  # the cell's cycle: 10,048 tokens
+    groups = ragged_plan(lengths, 2048, cfg=cfg)
+    assert np.concatenate(groups).tolist() == list(range(12))
+    assert len(groups) == -(-sum(lengths) // cap)  # as few as the cap allows here
+    for g, nxt in zip(groups, groups[1:]):
+        assert sum(lengths[i] for i in g) <= cap < sum(lengths[i] for i in g) + lengths[nxt[0]]
+    # a caller's own budget still binds, and more rows than ``starts`` holds split too
+    assert [g.tolist() for g in ragged_plan([100] * 4, 2048, max_tokens=250, cfg=cfg)] \
+        == [[0, 1], [2, 3]]
+    assert [len(g) for g in ragged_plan([8] * 70, 2048, cfg=cfg)] == [32, 32, 6]
+    # the BERT encoder's plan reads ITS config: the module's constants
+    assert EncoderConfig().token_buckets == TOKEN_BUCKETS and len(TOKEN_BUCKETS) == 42
+    assert [len(g) for g in ragged_plan([8] * 70, 128, mix_buckets=True)] == [70]
+
+
+def test_the_packed_dispatch_mints_one_program_a_token_bucket_and_no_other(tiny, params):
+    buckets = (32, 64, 128, 256)
+    enc = SentenceEncoder(cfg=_cfg(tiny, token_buckets=buckets),
+                          max_length=tiny["max_seq_length"], params=params)
+    before = flight_recorder.compile_stats()
+    launches = flight_recorder.moe_stats()["launches_total"]
+    enc.encode(_texts([6]))  # the first dispatch: every bucket once on padding, then the text
+
+    def minted():
+        now = flight_recorder.compile_stats()
+        return {site: now.get(site, 0) - before.get(site, 0)
+                for site in ("encoder.forward", "encoder.forward_ragged")}
+
+    assert minted() == {"encoder.forward": 0, "encoder.forward_ragged": len(buckets)}
+    assert flight_recorder.moe_stats()["launches_total"] - launches == len(buckets) + 1
+    rng = np.random.default_rng(7)
+    seen = set()
+    for flush in range(50):
+        lengths = rng.choice([3, 6, 7, 14, 30, 62], size=int(rng.integers(1, 8)))
+        prepared, _ = enc.prepare_chunks(*enc._tokenize(_texts(lengths, seed=flush)))
+        seen |= {tokens for _payload, _rows, tokens in prepared}
+        out = enc.encode(_texts(lengths, seed=flush))
+        assert out.shape == (len(lengths), 64) and np.isfinite(out).all()
+    assert seen == set(buckets)  # the flushes drove every bucket, some in several launches
+    assert minted() == {"encoder.forward": 0, "encoder.forward_ragged": len(buckets)}
+
+
+@pytest.mark.parametrize("lengths", [(9, 5, 30), (40, 3, 16, 16, 7)])
+def test_a_packed_launch_counts_the_sum_of_its_documents_and_routes_no_padding(
+        tiny, params, lengths):
+    cfg = _cfg(tiny, dtype=jnp.float32, token_buckets=(32, 128))
+    rows = [_ids(n, tiny["vocab_size"], seed=10 + n) for n in lengths]
+    chunk = _packed(cfg, rows)
+    ids, pos, seg = (jnp.asarray(a, jnp.int32)[None] for a in (chunk.ids, chunk.pos, chunk.seg))
+    group_sizes = jax.jit(lambda *args: cme._tokens_forward(cfg, params, *args)[1])
+    sizes = group_sizes(ids, pos, seg, seg < chunk.starts.shape[0])
+    alone = []
+    for r in rows:
+        one = jnp.asarray(r)[None]
+        alone.append(group_sizes(one, jnp.arange(len(r))[None], None,
+                                 jnp.ones(one.shape, bool)))
+    sparse = sum(1 for m in cfg.mlp_types if m == "sparse")
+    assert len(sizes) == sparse
+    for layer in range(sparse):  # expert by expert, the documents' own pairs and no pad's
+        np.testing.assert_array_equal(
+            np.asarray(sizes[layer]), sum(np.asarray(a[layer]) for a in alone))
+    routed, touched, fullest_sum, fullest = np.asarray(rx.launch_counters(sizes)).tolist()
+    assert routed == sum(lengths) * cfg.top_k * sparse  # 128 tokens went in
+    each = [np.asarray(rx.launch_counters(a)).tolist() for a in alone]
+    assert routed == sum(c[0] for c in each)
+    assert max(c[1] for c in each) <= touched <= sum(c[1] for c in each)
+    assert max(c[3] for c in each) <= fullest <= sum(c[3] for c in each)
+    _vectors, counters = cme.CausalMoeEmbedder(cfg, packed=True).apply(
+        {"params": params}, chunk.ids, chunk.pos, chunk.seg, chunk.starts)
+    assert np.asarray(counters).tolist() == [routed, touched, fullest_sum, fullest]
+
+
 # -- routing ------------------------------------------------------------------
 
 
@@ -399,8 +547,9 @@ def test_route_renormalises_the_top_k_and_scales(tiny):
 
 def test_launch_counters_reach_the_recorder_without_a_sync(tiny, params):
     before = flight_recorder.moe_stats()
-    enc = SentenceEncoder(cfg=_cfg(tiny), max_length=tiny["max_seq_length"], params=params)
-    enc.encode(_texts([6, 40]))  # two sequence buckets (32, 64): two launches
+    enc = SentenceEncoder(cfg=_cfg(tiny, attention_impl="xla"),
+                          max_length=tiny["max_seq_length"], params=params)
+    enc.encode(_texts([6, 40]))  # two sequence buckets (32, 64): two dense launches
     after = flight_recorder.moe_stats()
     sparse = 4
     assert after["launches_total"] - before["launches_total"] == 2

@@ -391,15 +391,17 @@ def small_encoder():
     return SentenceEncoder(cfg=cfg, max_length=64)
 
 
-def count_launches(enc) -> list[tuple]:
+def count_launches(enc, attr: str = "_apply") -> list[tuple]:
+    """The shapes of ``ids`` in every launch of ``enc``'s dense forward
+    (``_apply``) or packed one (``_apply_ragged``) from here on."""
     launches: list[tuple] = []
-    apply = enc._apply
+    apply = getattr(enc, attr)
 
-    def counting(params, ids, mask):
+    def counting(params, ids, *rest, **kw):
         launches.append(tuple(ids.shape))
-        return apply(params, ids, mask)
+        return apply(params, ids, *rest, **kw)
 
-    enc._apply = counting
+    setattr(enc, attr, counting)
     return launches
 
 
@@ -440,7 +442,7 @@ def test_a_bulk_flush_takes_the_row_buckets(fresh_runtime):
     np.testing.assert_allclose(got, alone, atol=1e-5, rtol=0)
 
 
-def tiny_moe_encoder():
+def tiny_moe_encoder(**over):
     import jax
 
     from pathway_tpu.models import causal_moe_embedder as cme
@@ -451,17 +453,39 @@ def tiny_moe_encoder():
         layer_types=("full", "window"), heads_per_layer=(4, 4),
         mlp_types=("dense", "sparse"), window=8, dense_mlp_dim=64,
         num_experts=4, top_k=2, expert_dim=16, shared_expert_dim=16,
-        max_len=64, seq_buckets=(8, 16, 32, 64), q_block=16,
+        max_len=64, seq_buckets=(8, 16, 32, 64), q_block=16, **over,
     )
     params = cme.init_params(cfg, jax.random.PRNGKey(0))
     return SentenceEncoder(cfg=cfg, max_length=64, params=params)
 
 
+def test_language_model_embedder_launches_a_flush_as_one_packed_program(fresh_runtime):
+    """The config's default: the files of one scan share ONE launch of a
+    token-bucket program that the first dispatch's warm-up already minted,
+    and no dense program exists."""
+    from pathway_tpu.internals.flight_recorder import compile_stats
+
+    enc = tiny_moe_encoder(token_buckets=(32, 64, 128))
+    assert enc.cfg.attention_impl == "ragged" and enc.cfg.packed_row_buckets == (32,)
+    alone = np.stack([enc.encode([t])[0] for t in MIXED])  # warms every token bucket
+    dense, packed = count_launches(enc), count_launches(enc, "_apply_ragged")
+    compiled = dict(compile_stats())
+    ticks = fresh_runtime.stats()["ticks_total"]
+    got = encoder_flush(enc, MIXED)
+    assert fresh_runtime.stats()["ticks_total"] - ticks == 1
+    assert dense == [] and len(packed) == 1 and packed[0][0] in enc.cfg.token_buckets
+    assert dict(compile_stats()) == compiled
+    # another program than a lone row's (a larger token bucket): float32 sums
+    # in another order, the same products
+    np.testing.assert_allclose(got, alone, atol=1e-5, rtol=0)
+
+
 def test_language_model_embedder_launches_one_row_programs(fresh_runtime):
+    """The dense dispatch of the same config (``attention_impl="xla"``)."""
     from pathway_tpu.internals.flight_recorder import compile_stats
     from pathway_tpu.models.encoder import BATCH_BUCKETS, EncoderConfig, packed_plan
 
-    enc = tiny_moe_encoder()
+    enc = tiny_moe_encoder(attention_impl="xla")
     assert enc.cfg.batch_buckets == (1,) and EncoderConfig().batch_buckets == BATCH_BUCKETS
     alone = np.stack([enc.encode([t])[0] for t in MIXED])  # compiles (1, seq) only
     launches = count_launches(enc)

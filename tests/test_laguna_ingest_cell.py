@@ -58,6 +58,8 @@ def test_a_traced_rehearsal_reads_the_routing_counters_and_no_device_metric():
     assert 1.0 <= metrics["moe.tokens_per_expert"]["value"] <= 64 * 4 / 4
     assert metrics["moe.load_max_over_mean"]["value"] >= 1.0
     assert metrics["ingest.rows_per_tick"]["value"] > 0
+    # documents that arrive together share a packed launch: never under one a launch
+    assert metrics["embed.docs_per_launch"]["value"] >= 1.0
     for name in ("embed_moe.device_ms_per_launch", "moe.grouped_matmul_roofline",
                  "ingest_moe_step.mfu"):
         assert name not in metrics
