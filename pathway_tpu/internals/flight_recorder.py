@@ -95,6 +95,8 @@ __all__ = [
     "ingest_stats",
     "record_moe_launch",
     "moe_stats",
+    "record_ssm_launch",
+    "ssm_stats",
     "observability_metrics_lines",
 ]
 
@@ -986,38 +988,65 @@ def ingest_stats() -> dict[str, Any]:
 
 
 # ---------------------------------------------------------------------------
-# routed-expert counters (pathway_moe_*): computed on the device by the
-# forward that routes, they come back with its result and are added up here
-# once the launch has finished: recording one never waits for the device
+# launch counters of a language-model embedder (pathway_moe_*, pathway_ssm_*):
+# computed on the device by the forward, they come back with its result and
+# are added up here once the launch has finished: recording one never waits
+# for the device
 # ---------------------------------------------------------------------------
 
-_moe_lock = threading.Lock()
-_moe_pending: deque = deque()
-_moe_counters = {
-    "launches_total": 0,
-    "routed_tokens_total": 0,
-    "experts_touched_total": 0,
-    "max_expert_tokens_sum": 0,
-    "max_expert_tokens": 0,
-}
+
+class _LaunchCounters:
+    """Totals over the launches of one kind of forward.  ``names`` are the
+    totals; ``add(totals, values)`` folds one launch's int32 array into
+    them.  Device arrays wait in line until they are ready."""
+
+    def __init__(self, names: tuple[str, ...], add):
+        self._lock = threading.Lock()
+        self._pending: deque = deque()
+        self._totals = dict.fromkeys(names, 0)
+        self._add = add
+
+    def _drain(self, wait: bool) -> None:
+        import numpy as np
+
+        while self._pending:
+            counters = self._pending[0]
+            if not wait and not counters.is_ready():
+                return
+            self._pending.popleft()
+            self._add(self._totals, [int(v) for v in np.asarray(counters)])
+
+    def record(self, counters: Any) -> None:
+        with self._lock:
+            self._pending.append(counters)
+            self._drain(wait=False)
+
+    def stats(self, wait: bool) -> dict[str, int]:
+        with self._lock:
+            self._drain(wait=wait)
+            return dict(self._totals)
 
 
-def _moe_drain(wait: bool) -> None:
-    import numpy as np
+def _add_moe(totals: dict, values: list) -> None:
+    routed, touched, fullest_sum, fullest = values
+    totals["launches_total"] += 1
+    totals["routed_tokens_total"] += routed
+    totals["experts_touched_total"] += touched
+    totals["max_expert_tokens_sum"] += fullest_sum
+    totals["max_expert_tokens"] = fullest
 
-    while _moe_pending:
-        counters = _moe_pending[0]
-        if not wait and not counters.is_ready():
-            return
-        _moe_pending.popleft()
-        routed, touched, fullest_sum, fullest = (
-            int(v) for v in np.asarray(counters)
-        )
-        _moe_counters["launches_total"] += 1
-        _moe_counters["routed_tokens_total"] += routed
-        _moe_counters["experts_touched_total"] += touched
-        _moe_counters["max_expert_tokens_sum"] += fullest_sum
-        _moe_counters["max_expert_tokens"] = fullest
+
+def _add_ssm(totals: dict, values: list) -> None:
+    for name, value in zip(totals, values):
+        totals[name] += value
+
+
+_moe_launches = _LaunchCounters(
+    ("launches_total", "routed_tokens_total", "experts_touched_total",
+     "max_expert_tokens_sum", "max_expert_tokens"), _add_moe)
+_ssm_launches = _LaunchCounters(
+    ("launches_total", "documents_total", "tokens_total", "bucket_tokens_total"),
+    _add_ssm)
 
 
 def record_moe_launch(counters: Any) -> None:
@@ -1026,18 +1055,28 @@ def record_moe_launch(counters: Any) -> None:
     pairs routed and experts that got a token (summed over the routed
     layers), each layer's fullest expert summed, and the fullest of all.  Launches that have finished are added up; this one waits in
     line until a later call or :func:`moe_stats`."""
-    with _moe_lock:
-        _moe_pending.append(counters)
-        _moe_drain(wait=False)
+    _moe_launches.record(counters)
 
 
 def moe_stats(wait: bool = True) -> dict[str, int]:
     """The ``pathway_moe_*`` counters over every launch so far.  ``wait``
     waits for the launches still in flight; a scrape does not (it holds
     the lock the launching thread takes) and counts them the next time."""
-    with _moe_lock:
-        _moe_drain(wait=wait)
-        return dict(_moe_counters)
+    return _moe_launches.stats(wait)
+
+
+def record_ssm_launch(counters: Any) -> None:
+    """One launch of a forward with state-space layers.  ``counters`` is the
+    int32 device array the forward returned beside its result: launches
+    (1), documents, real tokens, the tokens of its bucket.  It waits in line
+    as :func:`record_moe_launch`'s does."""
+    _ssm_launches.record(counters)
+
+
+def ssm_stats(wait: bool = True) -> dict[str, int]:
+    """The ``pathway_ssm_*`` counters over every launch so far; ``wait`` as
+    :func:`moe_stats`."""
+    return _ssm_launches.stats(wait)
 
 
 # ---------------------------------------------------------------------------
@@ -1143,15 +1182,12 @@ def observability_metrics_lines() -> list[str]:
         "pathway_embed_intra_bucket_efficiency "
         f"{ing['intra_bucket_efficiency']:.4f}"
     )
-    moe = moe_stats(wait=False)
-    if moe["launches_total"]:
-        for name, kind in (
-            ("launches_total", "counter"), ("routed_tokens_total", "counter"),
-            ("experts_touched_total", "counter"),
-            ("max_expert_tokens_sum", "counter"), ("max_expert_tokens", "gauge"),
-        ):
-            lines.append(f"# TYPE pathway_moe_{name} {kind}")
-            lines.append(f"pathway_moe_{name} {moe[name]}")
+    for family, totals in (("moe", moe_stats(wait=False)), ("ssm", ssm_stats(wait=False))):
+        if totals["launches_total"]:
+            for name, value in totals.items():
+                kind = "counter" if name.endswith(("_total", "_sum")) else "gauge"
+                lines.append(f"# TYPE pathway_{family}_{name} {kind}")
+                lines.append(f"pathway_{family}_{name} {value}")
     impls = attention_impl_stats()
     if impls:
         lines.append("# TYPE pathway_attention_impl gauge")

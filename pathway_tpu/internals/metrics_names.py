@@ -234,6 +234,21 @@ METRICS: dict[str, tuple[str, str]] = {
     "pathway_moe_max_expert_tokens": (
         "gauge", "the fullest expert of the last launch",
     ),
+    # launch counters of a forward with state-space layers
+    # (models/causal_hybrid_embedder.py, added up by
+    # flight_recorder.record_ssm_launch)
+    "pathway_ssm_launches_total": (
+        "counter", "launches of a forward with state-space layers",
+    ),
+    "pathway_ssm_documents_total": (
+        "counter", "documents (rows that hold a token) those launches carried",
+    ),
+    "pathway_ssm_tokens_total": (
+        "counter", "real tokens those launches carried",
+    ),
+    "pathway_ssm_bucket_tokens_total": (
+        "counter", "tokens of those launches' buckets, padding included",
+    ),
     "pathway_attention_impl": (
         "gauge",
         "encoders built per attention implementation (flax/fused/pallas/ragged)",

@@ -281,11 +281,13 @@ def _attention(q, k, v, pos, seg, valid, *, window: int | None, q_block: int):
     return jax.lax.map(block, jnp.arange(blocks)).reshape(blocks * bq, h, hd)[:t]
 
 
-def _gated_mlp(x, w_gate_up, w_down):
-    """``(silu(x Wg) * (x Wu)) Wd`` -> float32; gate columns first."""
+def _gated_mlp(x, w_gate_up, w_down, gate_scale: float = 1.0):
+    """``(silu(gate_scale * (x Wg)) * (x Wu)) Wd`` -> float32; gate columns
+    first."""
     h = jnp.dot(x, w_gate_up, preferred_element_type=jnp.float32)
     f = h.shape[-1] // 2
-    act = (jax.nn.silu(h[..., :f]) * h[..., f:]).astype(x.dtype)
+    gate = h[..., :f] if gate_scale == 1.0 else h[..., :f] * gate_scale
+    act = (jax.nn.silu(gate) * h[..., f:]).astype(x.dtype)
     return jnp.dot(act, w_down, preferred_element_type=jnp.float32)
 
 

@@ -1,0 +1,19 @@
+"""The whole ingest step's share of the chip's peak FLOP/s over the window:
+forward FLOPs of the real tokens of every file made queryable
+(``costs_falcon_h1.forward_flops``: every matrix a token is multiplied by,
+attention under its causal mask, the scan; a document of n words is n + 2
+tokens) / (window seconds x peak bf16 FLOP/s).  Nothing off the chip, or
+where the deployment states no such encoder."""
+
+import costs_falcon_h1
+
+
+def read(ctx):
+    sizes, words = ctx["facts"].get("encoder"), ctx["facts"].get("document_words")
+    if ctx["peaks"] is None or not sizes or "ssm_heads" not in sizes or not words:
+        return None
+    flops = sum(costs_falcon_h1.forward_flops(words[r["answer"]["passage"] % len(words)] + 2, sizes)
+                for r in ctx["records"] if not r["failed"])
+    if not flops:
+        return None
+    return 100.0 * flops / (ctx["seconds"] * ctx["peaks"]["bf16_flops_per_s"])

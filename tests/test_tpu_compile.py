@@ -8,8 +8,11 @@ which knows nothing of the chip's fast memory or of what fits its HBM.  The
 cell's first chip run ended in ``RESOURCE_EXHAUSTED`` in ``vmem`` in the
 search megakernel at rows of 2,048 values, after every interpret-mode test
 had passed; a later PR that touches that kernel's blocks, or the embedder's
-shapes, would find out the same way, on the chip's budget.  Three compiles,
-one file (the on-chip-measurement guide, section 2): the topology is described
+shapes, would find out the same way, on the chip's budget.  The hybrid
+embedder's cell (``ingest-docs-falcon-h1``) added its own: the scan kernel at
+the published shapes, the packed forward at each token bucket with that
+kernel in it, and the index's search and apply at rows of 5,120 values.  A
+dozen compiles, one file (the on-chip-measurement guide, section 2): the topology is described
 inside a fixture of THIS file only, because one process at a time may load the
 TPU's library, and the persistent compile cache is kept out of it, because a
 compile for a described chip cannot be read back without the chip."""
@@ -73,21 +76,26 @@ def test_the_search_megakernel_compiles_for_rows_of_2048_values(one_chip, no_per
         assert "tpu_custom_call" in compiled.as_text()
 
 
-def _published_model(one_chip):
-    """The cell's config object and its parameter tree as shapes on the
-    described chip."""
+def _cell_model(one_chip, builder_name: str, config_name: str, model_module):
+    """A cell's configuration file, its config object and its parameter tree
+    as shapes on the described chip."""
+    import importlib
+
     if BENCH not in sys.path:
         sys.path.insert(0, BENCH)
-    from encoders import laguna as builder
-
-    from pathway_tpu.models import causal_moe_embedder as cme
-
-    with open(os.path.join(BENCH, "configs", "vs-laguna-xs2-bf16-marcodoc.json")) as f:
+    builder = importlib.import_module("encoders." + builder_name)
+    with open(os.path.join(BENCH, "configs", config_name + ".json")) as f:
         config = json.load(f)
     cfg = builder.model_config(config)
-    shapes = jax.eval_shape(lambda: cme.init_params(cfg, jax.random.PRNGKey(0)))
-    return cfg, jax.tree_util.tree_map(
+    shapes = jax.eval_shape(lambda: model_module.init_params(cfg, jax.random.PRNGKey(0)))
+    return config, cfg, jax.tree_util.tree_map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), shapes)
+
+
+def _published_model(one_chip):
+    from pathway_tpu.models import causal_moe_embedder as cme
+
+    return _cell_model(one_chip, "laguna", "vs-laguna-xs2-bf16-marcodoc", cme)[1:]
 
 
 def test_the_forward_compiles_at_the_published_widths_and_fits_the_chip(
@@ -133,3 +141,107 @@ def test_the_packed_forward_compiles_at_its_largest_token_bucket_beside_the_inde
     assert memory.argument_size_in_bytes == pytest.approx(7.33e9, rel=0.01)
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes + 3 * 2.15e9 < 15.75e9
     assert "ragged-dot" in compiled.as_text()
+
+
+# -- the hybrid (attention + state-space) embedder's cell -----------------------
+
+def _hybrid_model(one_chip):
+    from pathway_tpu.models import causal_hybrid_embedder as che
+
+    return _cell_model(one_chip, "falcon_h1", "vs-falcon-h1-34b-bf16-marcodoc", che)
+
+
+@pytest.mark.parametrize("tokens", [768, 3072, 6144])
+def test_the_scan_kernel_compiles_at_the_published_shapes(one_chip, no_persistent_cache, tokens):
+    """32 heads of 128 channels, a state of 256, 2 groups, chunks of 128:
+    interpret mode knows nothing of Mosaic's tiles or of VMEM."""
+    from pathway_tpu.ops import ssd_scan as S
+
+    h, p, g, n, chunk = 32, 128, 2, 256, 128
+    shape = lambda s, d=jnp.float32: jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+    args = (shape((tokens, h, p)), shape((tokens, h)), shape((h,)), shape((tokens, g, n)),
+            shape((tokens, g, n)), shape((h,)), shape((tokens,), jnp.int32),
+            shape((tokens,), jnp.int32), shape((tokens,), jnp.bool_))
+    compiled = jax.jit(lambda *a: S.ssd_scan_pallas(*a, chunk=chunk)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and S.KERNEL_NAME in text
+
+
+def test_the_hybrid_packed_forward_compiles_at_each_token_bucket_beside_the_index(
+        one_chip, no_persistent_cache, monkeypatch):
+    """The launches that serve, the arrays as ``ragged_chunk`` lays them out,
+    with the scan's kernel in them (the model asks the platform, which is the
+    CPU here: the test hands it the kernel).  The temporaries (the MLP's
+    [tokens, 43,008] float32 product first) have to fit beside 6.11 GB of
+    weights and the three copies of the index's 1.34 GB that an apply holds."""
+    import numpy as np
+
+    from pathway_tpu.models import causal_hybrid_embedder as che
+    from pathway_tpu.models.encoder import dispatch_dtype, ragged_chunk
+    from pathway_tpu.ops import ssd_scan as S
+
+    monkeypatch.setattr(che, "ssd_scan", S.ssd_scan_pallas)
+    _config, cfg, params = _hybrid_model(one_chip)
+    model = che.CausalHybridEmbedder(cfg, packed=True)
+    none = np.zeros(0, np.int64)
+    for tokens in cfg.token_buckets:
+        chunk = ragged_chunk(none, none, None, None, cfg.max_len,
+                             dispatch_dtype(cfg.vocab_size), cfg, tokens=tokens)
+        assert chunk.ids.shape == (tokens,) and chunk.dense_s is None
+        args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+                for a in (chunk.ids, chunk.pos, chunk.seg, chunk.starts)]
+        compiled = jax.jit(lambda p, *a: model.apply({"params": p}, *a)).lower(
+            params, *args).compile()
+        memory = compiled.memory_analysis()
+        assert memory.argument_size_in_bytes == pytest.approx(6.115e9, rel=0.01)  # bfloat16
+        assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+                + 3 * 1.342e9 < 15.75e9), tokens
+        assert S.KERNEL_NAME in compiled.as_text()
+
+
+def test_the_check_s_packed_layer_compiles_at_the_published_widths(
+        one_chip, no_persistent_cache, monkeypatch):
+    """What ``layer_gap`` calls in the cell: one block over the longest
+    document behind a neighbour on a packed axis (2,283 tokens: eighteen
+    chunks, five query blocks), with the scan's kernel in it."""
+    from pathway_tpu.models import causal_hybrid_embedder as che
+    from pathway_tpu.ops import ssd_scan as S
+
+    monkeypatch.setattr(che, "ssd_scan", S.ssd_scan_pallas)
+    config, cfg, params = _hybrid_model(one_chip)  # puts perfbench/ on the path
+    from encoders import falcon_h1 as builder
+
+    x = jax.ShapeDtypeStruct((cfg.max_len, cfg.hidden_dim), jnp.float32, sharding=one_chip)
+    program = builder._layer_program(json.dumps(config, sort_keys=True), 0)
+    compiled = program.lower(params["layer_0"], x).compile()
+    assert S.KERNEL_NAME in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
+def test_the_index_searches_and_applies_rows_of_5120_values(one_chip, no_persistent_cache):
+    """65,536 slots of 5,120 float32 values: a block of 1,024 rows is 20 MiB,
+    which the megakernel has to ask VMEM for; the apply is the scatter of a
+    flush's rows (32 at most a launch) and of their validity."""
+    from pathway_tpu.ops import fused_serving as fs
+    from pathway_tpu.ops import knn
+
+    config, _cfg, _params = _hybrid_model(one_chip)
+    n, d = config["index"]["capacity"], config["index"]["dim"]
+    assert (n, d) == (65_536, 5120)
+    shape = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one_chip)
+    block = fs.validate_serving_geometry(n, "cos")
+    fn = getattr(fs._pallas_fused_dense, "__wrapped__", fs._pallas_fused_dense)
+    for q_b, q_dtype in ((8, jnp.bfloat16), (32, jnp.float32)):
+        compiled = fn.lower(shape((q_b, d), q_dtype), shape((n, d), jnp.float32),
+                            shape((n,), jnp.bool_), k=16, q_b=q_b, metric="cos",
+                            normalize=True, qdt="f32", block_n=block, interpret=False).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+    scatter = getattr(knn._scatter_rows_dropping, "__wrapped__", knn._scatter_rows_dropping)
+    compiled = scatter.lower(shape((n, d), jnp.float32), shape((32,), jnp.int32),
+                             shape((32, d), jnp.float32), normalize=True).compile()
+    memory = compiled.memory_analysis()
+    # undonated: the matrix in, the matrix out (PERF.md 7 #6), beside the weights
+    assert memory.argument_size_in_bytes + memory.output_size_in_bytes < 2 * 1.35e9
+    mask = getattr(knn._scatter_mask, "__wrapped__", knn._scatter_mask)
+    mask.lower(shape((n,), jnp.bool_), shape((32,), jnp.int32),
+               shape((32,), jnp.bool_)).compile()
