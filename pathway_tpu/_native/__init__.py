@@ -21,15 +21,15 @@ from typing import Iterator
 import numpy as np
 
 __all__ = [
-    "hash_bytes", "tokenize_batch", "walk_dir", "read_files", "lib",
-    "ABI_VERSION",
+    "hash_bytes", "tokenize_batch", "list_dir", "stat_files", "read_files",
+    "lib", "ABI_VERSION",
 ]
 
-ABI_VERSION = 2
+ABI_VERSION = 3
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "native.cpp")
-# no fused multiply-add: pw_fs_walk's st_mtime has to be the very float
+# no fused multiply-add: pw_fs_list's st_mtime has to be the very float
 # os.stat computes (sec + 1e-9 * nsec, rounded twice)
 _CXXFLAGS = ("-O3", "-shared", "-fPIC", "-std=c++17", "-ffp-contract=off")
 
@@ -91,11 +91,20 @@ lib.pw_tokenize_batch.argtypes = [
 ]
 
 
-class _FsWalk(ctypes.Structure):
+class _FsList(ctypes.Structure):
     _fields_ = [
         ("n", ctypes.c_int64), ("entries", ctypes.c_int64),
+        ("stats", ctypes.c_int64),
         ("paths_len", ctypes.c_int64), ("paths", ctypes.c_void_p),
         ("mtimes", ctypes.c_void_p), ("sizes", ctypes.c_void_p),
+        ("n_missing", ctypes.c_int64), ("missing", ctypes.c_void_p),
+    ]
+
+
+class _FsStat(ctypes.Structure):
+    _fields_ = [
+        ("n", ctypes.c_int64), ("mtimes", ctypes.c_void_p),
+        ("sizes", ctypes.c_void_p), ("kinds", ctypes.c_void_p),
     ]
 
 
@@ -107,10 +116,16 @@ class _FsRead(ctypes.Structure):
     ]
 
 
-lib.pw_fs_walk.argtypes = [ctypes.c_char_p, ctypes.c_char_p]
-lib.pw_fs_walk.restype = ctypes.POINTER(_FsWalk)
-lib.pw_fs_walk_free.argtypes = [ctypes.POINTER(_FsWalk)]
-lib.pw_fs_walk_free.restype = None
+lib.pw_fs_list.argtypes = [
+    ctypes.c_char_p, ctypes.c_char_p, ctypes.c_char_p, ctypes.c_int64
+]
+lib.pw_fs_list.restype = ctypes.POINTER(_FsList)
+lib.pw_fs_list_free.argtypes = [ctypes.POINTER(_FsList)]
+lib.pw_fs_list_free.restype = None
+lib.pw_fs_stat.argtypes = [ctypes.c_char_p, ctypes.c_int64]
+lib.pw_fs_stat.restype = ctypes.POINTER(_FsStat)
+lib.pw_fs_stat_free.argtypes = [ctypes.POINTER(_FsStat)]
+lib.pw_fs_stat_free.restype = None
 lib.pw_fs_read.argtypes = [ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64]
 lib.pw_fs_read.restype = ctypes.POINTER(_FsRead)
 lib.pw_fs_read_free.argtypes = [ctypes.POINTER(_FsRead)]
@@ -167,27 +182,67 @@ def tokenize_batch(
     return ids, mask
 
 
-def walk_dir(root: bytes, pattern: bytes) -> tuple[bytes, list[float], list[int], int]:
-    """The regular files under the directory ``root`` (ending with ``/``)
-    whose base name matches ``pattern`` (``*`` and ``?`` only), as
-    ``glob.glob(root + b"**/" + pattern, recursive=True)`` finds them:
-    ``(paths joined by NUL in sorted order, st_mtime of each, st_size of
-    each, directory entries read)``; the last is -1 when ``root`` cannot
-    be listed (it is a single file, or not there).  One call without the
-    interpreter lock; see native.cpp for what exactly it mirrors."""
-    res = lib.pw_fs_walk(root, pattern)
+def _counted(paths: bytes, n: int) -> None:
+    """Native code walks ``n`` NUL-ended paths in ``paths``: they are there."""
+    found = paths.count(b"\0")
+    if found != n:
+        raise ValueError(f"{n} paths announced, {found} NUL-ended")
+
+
+def list_dir(
+    root: bytes, pattern: bytes, known: bytes, n_known: int
+) -> tuple[bytes, list[float], list[int], list[int], int, int]:
+    """Pass 1 of a poll.  The regular files under the directory ``root``
+    (ending with ``/``) whose base name matches ``pattern`` (``*`` and ``?``
+    only), as ``glob.glob(root + b"**/" + pattern, recursive=True)`` finds
+    them, less the ``n_known`` paths in ``known`` (each ended by NUL), which
+    cost no system call: ``(the NEW paths joined by NUL in sorted order,
+    st_mtime of each, st_size of each, indices of the known paths that were
+    not listed, directory entries read, fstatat calls made)``.  The entries
+    are -1 when ``root`` cannot be listed (it is a single file, or not
+    there).  One call without the interpreter lock; see native.cpp for what
+    exactly it mirrors."""
+    _counted(known, n_known)
+    res = lib.pw_fs_list(root, pattern, known, n_known)
     if not res:
-        raise MemoryError("pw_fs_walk")
+        raise MemoryError("pw_fs_list")
     try:
         w = res.contents
         return (
             ctypes.string_at(w.paths, w.paths_len) if w.paths_len else b"",
             _list_at("f8", w.mtimes, w.n),
             _list_at("i8", w.sizes, w.n),
+            _list_at("i8", w.missing, w.n_missing),
             w.entries,
+            w.stats,
         )
     finally:
-        lib.pw_fs_walk_free(res)
+        lib.pw_fs_list_free(res)
+
+
+#: what ``stat_files`` found at a path; ``OTHER`` is also nothing at all
+REGULAR, DIRECTORY, OTHER = 0, 1, 2
+
+
+def stat_files(paths: bytes, n: int) -> tuple[list[float], list[int], list[int]]:
+    """Pass 2 of a poll.  One ``fstatat`` (links followed) of each of the
+    ``n`` paths in ``paths`` (each ended by NUL), relative to its directory,
+    which is opened once: ``(st_mtime of each, st_size of each, kind of
+    each)``; time and size are 0 but for a ``REGULAR`` file.  One call
+    without the interpreter lock."""
+    _counted(paths, n)
+    res = lib.pw_fs_stat(paths, n)
+    if not res:
+        raise MemoryError("pw_fs_stat")
+    try:
+        w = res.contents
+        return (
+            _list_at("f8", w.mtimes, w.n),
+            _list_at("i8", w.sizes, w.n),
+            _list_at("u1", w.kinds, w.n),
+        )
+    finally:
+        lib.pw_fs_stat_free(res)
 
 
 #: bytes one ``pw_fs_read`` call may hold before it hands back

@@ -6,13 +6,15 @@
 // the hashing tokenizer's batch encode (models/tokenizer.py), which
 // dominates host time in the embedding ingest path.
 //
-// And the watched-directory pass of io/fs (pw_fs_walk, pw_fs_read): one poll
-// walks the tree under a directory, matches base names against a `*`/`?`
-// pattern the way glob.glob("<dir>/**/<pattern>", recursive=True) does,
-// stats every regular file and reads the files the caller names, all with
-// the interpreter lock released.  The Python lister of io/fs is its
-// fallback, and is also what io/fs takes when the path is a single file or
-// a glob, or the pattern holds a path separator or a bracket expression.
+// And the watched-directory poll of io/fs (pw_fs_list, pw_fs_stat,
+// pw_fs_read): pass 1 lists the tree under a directory, matches base names
+// against a `*`/`?` pattern the way glob.glob("<dir>/**/<pattern>",
+// recursive=True) does and stats only the files the caller does not know
+// yet; pass 2 stats every file the caller knew; pw_fs_read reads the files
+// the caller names; all with the interpreter lock released.  The Python
+// lister of io/fs is the fallback, and is also what io/fs takes when the
+// path is a single file or a glob, or the pattern holds a path separator or
+// a bracket expression.
 //
 // Built by pathway_tpu/_native/__init__.py with g++ -O3 -shared -fPIC;
 // every exported function has a pure-Python fallback with identical
@@ -28,6 +30,8 @@
 #include <cstdint>
 #include <cstring>
 #include <string>
+#include <string_view>
+#include <unordered_map>
 #include <vector>
 
 // ---------------------------------------------------------------------------
@@ -226,16 +230,28 @@ extern "C" void pw_tokenize_batch(
 }
 
 // ---------------------------------------------------------------------------
-// Watched-directory pass (io/fs).  Mirrors, for a directory `root` and a
-// base-name pattern without `/` or `[`:
+// Watched-directory poll (io/fs), in two passes with the caller's commit
+// between them.
+//
+// Pass 1, pw_fs_list: mirrors, for a directory `root` and a base-name pattern
+// without `/` or `[`,
 //   sorted(f for f in glob.glob(root + "/**/" + pattern, recursive=True)
-//          if os.path.isfile(f))  +  os.stat(f) of each
-// `**` enters every directory whose name does not start with `.`, symlinked
-// ones too; a pattern with `*` or `?` skips names that start with `.` unless
-// it starts with `.` itself, and a pattern without either is compared as it
-// is; a name is kept when stat (links followed) says regular file.  A
-// directory is opened by its whole path, as os.scandir opens it, so a loop of
-// symlinks ends where the kernel ends it for Python (ELOOP, ENAMETOOLONG).
+//          if os.path.isfile(f))
+// and tells the files the caller does not know yet (with os.stat's st_mtime
+// and st_size of each) from the known ones, which it only marks as listed:
+// readdir gives the names, and a name the caller holds costs no system call
+// here, so the pass costs the entries that are there plus one fstatat a NEW
+// file.  `**` enters every directory whose name does not start with `.`,
+// symlinked ones too; a pattern with `*` or `?` skips names that start with
+// `.` unless it starts with `.` itself, and a pattern without either is
+// compared as it is; a new name is kept when stat (links followed) says
+// regular file.  A directory is opened by its whole path, as os.scandir opens
+// it, so a loop of symlinks ends where the kernel ends it for Python (ELOOP,
+// ENAMETOOLONG).
+//
+// Pass 2, pw_fs_stat: one fstatat of every file the caller names, relative to
+// its directory opened once (a stat by path walks the path again for every
+// file), for the caller to compare with the (mtime, size) it holds.
 // ---------------------------------------------------------------------------
 
 namespace {
@@ -306,6 +322,11 @@ struct FsFile {
   int64_t size;
 };
 
+// the float os.stat gives: st_mtime = sec + 1e-9 * nsec
+inline double mtime_of(const struct stat& st) {
+  return (double)st.st_mtim.tv_sec + 1e-9 * (double)st.st_mtim.tv_nsec;
+}
+
 struct FsPattern {
   const uint8_t* bytes;
   size_t len;
@@ -313,18 +334,28 @@ struct FsPattern {
   bool hidden;  // starts with `.`: hidden names are not skipped
 };
 
+struct FsListing {
+  FsPattern pat;
+  std::unordered_map<std::string_view, int64_t> known;  // path -> its index
+  std::vector<uint8_t> listed;                          // per known path
+  std::vector<FsFile> files;                            // the new ones
+  int64_t entries = 0;
+  int64_t stats = 0;
+};
+
 // false when `dir` (which ends with '/') cannot be listed
-bool walk_dir(const std::string& dir, const FsPattern& pat,
-              std::vector<FsFile>* files, int64_t* entries) {
+bool list_dir(const std::string& dir, FsListing* out) {
   DIR* d = opendir(dir.c_str());
   if (d == nullptr) return false;
   const int fd = dirfd(d);
+  const FsPattern& pat = out->pat;
   std::vector<std::string> subdirs;
+  std::string path;
   while (struct dirent* e = readdir(d)) {
     const char* name = e->d_name;
     if (name[0] == '.' && (name[1] == 0 || (name[1] == '.' && name[2] == 0)))
       continue;
-    ++*entries;
+    ++out->entries;
     const size_t len = std::strlen(name);
     const bool hidden = name[0] == '.';
     const bool wanted =
@@ -332,63 +363,85 @@ bool walk_dir(const std::string& dir, const FsPattern& pat,
                      name_matches(pat.bytes, pat.len, (const uint8_t*)name, len))
                   : (len == pat.len && std::memcmp(name, pat.bytes, len) == 0);
     const unsigned char type = e->d_type;
-    // a wanted name is stat'ed for its time and size; any other only where
-    // the directory entry does not say whether `**` enters it
+    if (wanted && type != DT_DIR) {
+      path.assign(dir).append(name, len);
+      const auto hit = out->known.find(path);
+      if (hit != out->known.end()) {
+        // a known file: pass 2 asks for its time and size, and says so if it
+        // is a directory now (the caller then lists again)
+        out->listed[hit->second] = 1;
+        continue;
+      }
+    }
+    // a wanted new name is stat'ed for its time and size; any other only
+    // where the directory entry does not say whether `**` enters it
     const bool ask = wanted ? type != DT_DIR
                             : (!hidden && (type == DT_LNK || type == DT_UNKNOWN));
     struct stat st;
-    const bool known = ask && fstatat(fd, name, &st, 0) == 0;
-    if (type == DT_DIR || (known && S_ISDIR(st.st_mode))) {
+    if (ask) ++out->stats;
+    const bool answered = ask && fstatat(fd, name, &st, 0) == 0;
+    if (type == DT_DIR || (answered && S_ISDIR(st.st_mode))) {
       if (!hidden) subdirs.emplace_back(name, len);  // `**` enters no hidden one
-    } else if (wanted && known && S_ISREG(st.st_mode)) {
-      // the float os.stat gives: st_mtime = sec + 1e-9 * nsec
-      files->push_back({dir + name,
-                        (double)st.st_mtim.tv_sec + 1e-9 * (double)st.st_mtim.tv_nsec,
-                        (int64_t)st.st_size});
+    } else if (wanted && answered && S_ISREG(st.st_mode)) {
+      out->files.push_back({dir + name, mtime_of(st), (int64_t)st.st_size});
     }
   }
   closedir(d);  // before the children: one descriptor however deep the tree
   for (const std::string& sub : subdirs)
-    walk_dir(dir + sub + "/", pat, files, entries);  // as glob: unlistable is empty
+    list_dir(dir + sub + "/", out);  // as glob: unlistable is empty
   return true;
 }
 
 }  // namespace
 
-// What the caller sees of a walk: `n` regular files sorted by path (byte
+// What the caller sees of pass 1: `n` NEW regular files sorted by path (byte
 // order, which is str order for UTF-8), their paths joined by NUL, and per
-// file st_mtime and st_size; `entries` counts the directory entries read and
-// is -1 when `root` itself cannot be listed (a single file, or nothing).
-struct PwFsWalk {
+// file st_mtime and st_size; `missing` holds, ascending, the indices of the
+// `n_missing` known paths that the listing does not hold; `entries` counts
+// the directory entries read and is -1 when `root` itself cannot be listed (a
+// single file, or nothing); `stats` counts the fstatat calls made.
+struct PwFsList {
   int64_t n;
   int64_t entries;
+  int64_t stats;
   int64_t paths_len;
   const char* paths;
   const double* mtimes;
   const int64_t* sizes;
+  int64_t n_missing;
+  const int64_t* missing;
 };
 
 namespace {
-struct FsWalkOwner {
-  PwFsWalk view;  // first: the pointer handed out is the owner's
+struct FsListOwner {
+  PwFsList view;  // first: the pointer handed out is the owner's
   std::string paths;
   std::vector<double> mtimes;
   std::vector<int64_t> sizes;
+  std::vector<int64_t> missing;
 };
 }  // namespace
 
-// `root` ends with '/'.  Returns nullptr only when memory ran out.
-extern "C" PwFsWalk* pw_fs_walk(const char* root, const char* pattern) {
+// `root` ends with '/'; `known` holds `n_known` paths, each ended by NUL, and
+// outlives the call.  Returns nullptr only when memory ran out.
+extern "C" PwFsList* pw_fs_list(const char* root, const char* pattern,
+                                const char* known, int64_t n_known) {
   try {
-    FsPattern pat{(const uint8_t*)pattern, std::strlen(pattern), false,
-                  pattern[0] == '.'};
-    pat.magic = std::strpbrk(pattern, "*?") != nullptr;
-    std::vector<FsFile> files;
-    int64_t entries = 0;
-    if (!walk_dir(root, pat, &files, &entries)) entries = -1;
+    FsListing listing;
+    listing.pat = {(const uint8_t*)pattern, std::strlen(pattern),
+                   std::strpbrk(pattern, "*?") != nullptr, pattern[0] == '.'};
+    listing.known.reserve((size_t)n_known);
+    listing.listed.assign((size_t)n_known, 0);
+    for (int64_t i = 0; i < n_known; i++) {
+      const std::string_view path(known);
+      listing.known.emplace(path, i);
+      known += path.size() + 1;
+    }
+    if (!list_dir(root, &listing)) listing.entries = -1;
+    std::vector<FsFile>& files = listing.files;
     std::sort(files.begin(), files.end(),
               [](const FsFile& a, const FsFile& b) { return a.path < b.path; });
-    auto* out = new FsWalkOwner();
+    auto* out = new FsListOwner();
     out->mtimes.reserve(files.size());
     out->sizes.reserve(files.size());
     for (const FsFile& f : files) {
@@ -397,16 +450,99 @@ extern "C" PwFsWalk* pw_fs_walk(const char* root, const char* pattern) {
       out->mtimes.push_back(f.mtime);
       out->sizes.push_back(f.size);
     }
-    out->view = {(int64_t)files.size(), entries, (int64_t)out->paths.size(),
-                 out->paths.data(), out->mtimes.data(), out->sizes.data()};
+    for (int64_t i = 0; i < n_known; i++)
+      if (!listing.listed[(size_t)i]) out->missing.push_back(i);
+    out->view = {(int64_t)files.size(), listing.entries, listing.stats,
+                 (int64_t)out->paths.size(), out->paths.data(),
+                 out->mtimes.data(), out->sizes.data(),
+                 (int64_t)out->missing.size(), out->missing.data()};
     return &out->view;
   } catch (...) {
     return nullptr;
   }
 }
 
-extern "C" void pw_fs_walk_free(PwFsWalk* walk) {
-  delete reinterpret_cast<FsWalkOwner*>(walk);
+extern "C" void pw_fs_list_free(PwFsList* listing) {
+  delete reinterpret_cast<FsListOwner*>(listing);
+}
+
+// What the caller sees of pass 2, per path it named: `kinds` (0 a regular
+// file, whose st_mtime and st_size follow; 1 a directory; 2 anything else,
+// or nothing there).
+struct PwFsStat {
+  int64_t n;
+  const double* mtimes;
+  const int64_t* sizes;
+  const uint8_t* kinds;
+};
+
+namespace {
+struct FsStatOwner {
+  PwFsStat view;
+  std::vector<double> mtimes;
+  std::vector<int64_t> sizes;
+  std::vector<uint8_t> kinds;
+};
+
+struct Fd {
+  int fd;
+  ~Fd() {
+    if (fd >= 0) close(fd);
+  }
+};
+}  // namespace
+
+// `paths` holds `n` paths, each ended by NUL.  One fstatat a path (links
+// followed), files of one directory one after the other so that the
+// directory is opened once.  Returns nullptr only when memory ran out.
+extern "C" PwFsStat* pw_fs_stat(const char* paths, int64_t n) {
+  try {
+    struct Named {
+      std::string_view dir;  // up to and with the last '/'
+      const char* name;
+      int64_t at;
+    };
+    std::vector<Named> order;
+    order.reserve((size_t)n);
+    for (int64_t i = 0; i < n; i++) {
+      const std::string_view path(paths);
+      const size_t cut = path.rfind('/') + 1;  // 0 where there is none
+      order.push_back({path.substr(0, cut), paths + cut, i});
+      paths += path.size() + 1;
+    }
+    std::stable_sort(order.begin(), order.end(),
+                     [](const Named& a, const Named& b) { return a.dir < b.dir; });
+    auto* out = new FsStatOwner();
+    out->mtimes.assign((size_t)n, 0.0);
+    out->sizes.assign((size_t)n, 0);
+    out->kinds.assign((size_t)n, 2);
+    std::string dir;
+    for (size_t i = 0; i < order.size();) {
+      dir.assign(order[i].dir.empty() ? std::string_view("./") : order[i].dir);
+      const Fd d{open(dir.c_str(), O_RDONLY | O_DIRECTORY | O_CLOEXEC)};
+      for (const std::string_view of = order[i].dir;
+           i < order.size() && order[i].dir == of; i++) {
+        struct stat st;
+        if (d.fd < 0 || fstatat(d.fd, order[i].name, &st, 0) != 0) continue;
+        const size_t at = (size_t)order[i].at;
+        if (S_ISREG(st.st_mode)) {
+          out->kinds[at] = 0;
+          out->mtimes[at] = mtime_of(st);
+          out->sizes[at] = (int64_t)st.st_size;
+        } else if (S_ISDIR(st.st_mode)) {
+          out->kinds[at] = 1;
+        }
+      }
+    }
+    out->view = {n, out->mtimes.data(), out->sizes.data(), out->kinds.data()};
+    return &out->view;
+  } catch (...) {
+    return nullptr;
+  }
+}
+
+extern "C" void pw_fs_stat_free(PwFsStat* stats) {
+  delete reinterpret_cast<FsStatOwner*>(stats);
 }
 
 // The bytes of the first `n_read` of `n` files (paths joined by NUL), one
@@ -428,13 +564,6 @@ struct FsReadOwner {
   std::vector<uint8_t> data;
   std::vector<int64_t> ends;
   std::vector<int32_t> errs;
-};
-
-struct Fd {
-  int fd;
-  ~Fd() {
-    if (fd >= 0) close(fd);
-  }
 };
 
 // Appends the file's bytes to `data`; an errno (and no bytes) if it failed.
@@ -490,4 +619,4 @@ extern "C" void pw_fs_read_free(PwFsRead* r) {
 // version stamp so the loader can invalidate stale cached builds
 // ---------------------------------------------------------------------------
 
-extern "C" int pw_native_abi_version() { return 2; }
+extern "C" int pw_native_abi_version() { return 3; }

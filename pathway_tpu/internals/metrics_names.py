@@ -38,6 +38,11 @@ METRICS: dict[str, tuple[str, str]] = {
         "counter",
         "polls of a watched path per connector and lister (native|python)",
     ),
+    "pathway_connector_files_total": (
+        "counter",
+        "files emitted per connector and the pass of a poll that found them "
+        "(listing: a new name; verify: a known file that changed)",
+    ),
     # serving scheduler (xpacks/llm/_scheduler.py)
     "pathway_scheduler_submitted_total": ("counter", "work items admitted"),
     "pathway_scheduler_completed_total": ("counter", "work items completed"),
@@ -111,7 +116,7 @@ METRICS: dict[str, tuple[str, str]] = {
         "histogram",
         "stage latency: request stages (queue_wait / embed / search / serialize / "
         "total) and every flight_recorder.span(stage=...) of the ingest path "
-        "(connector.scan, engine.flush, index.*, tick.*, embed.*, "
+        "(connector.scan, connector.verify, engine.flush, index.*, tick.*, embed.*, "
         "ingest.read_to_indexed)",
     ),
     "pathway_flight_recorder_spans_total": (

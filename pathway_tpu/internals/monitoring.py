@@ -38,6 +38,8 @@ __all__ = [
     "get_freshness",
     "record_connector_scan",
     "connector_scans",
+    "record_connector_files",
+    "connector_files",
 ]
 
 
@@ -128,6 +130,23 @@ def record_connector_scan(connector: str, lister: str) -> None:
 def connector_scans() -> dict[tuple[str, str], int]:
     with _connector_scans_lock:
         return dict(_connector_scans)
+
+
+#: files emitted per (connector label, the pass of a poll that found them)
+_connector_files: dict[tuple[str, str], int] = defaultdict(int)
+
+
+def record_connector_files(connector: str, found: str, n: int) -> None:
+    """``n`` files read and emitted by one pass of a ``pw.io.fs`` poll;
+    ``found`` is ``"listing"`` (pass 1: a name the connector did not know)
+    or ``"verify"`` (pass 2: a known file whose mtime or size changed)."""
+    with _connector_scans_lock:
+        _connector_files[(connector, found)] += n
+
+
+def connector_files() -> dict[tuple[str, str], int]:
+    with _connector_scans_lock:
+        return dict(_connector_files)
 
 
 #: flush-latency histogram bucket upper bounds (milliseconds)
@@ -292,13 +311,16 @@ class StatsMonitor:
                 f'pathway_connector_finished{{connector="{safe}"}} '
                 f'{1 if st["finished"] else 0}'
             )
-        scans = sorted(connector_scans().items())
-        if scans:
-            lines.append("# TYPE pathway_connector_scans_total counter")
-            for (name, lister), n in scans:
+        for family, second, counts in (
+            ("pathway_connector_scans_total", "lister", connector_scans()),
+            ("pathway_connector_files_total", "found", connector_files()),
+        ):
+            if counts:
+                lines.append(f"# TYPE {family} counter")
+            for (name, value), n in sorted(counts.items()):
                 lines.append(
-                    "pathway_connector_scans_total"
-                    f'{{connector="{escape_label_value(name)}",lister="{lister}"}} {n}'
+                    f'{family}{{connector="{escape_label_value(name)}",'
+                    f'{second}="{value}"}} {n}'
                 )
         for _name, provider in list(_metrics_providers.items()):
             try:

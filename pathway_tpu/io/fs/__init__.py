@@ -10,13 +10,19 @@ path, diffing the (path → mtime,size) snapshot; a changed file retracts
 every row it previously produced and re-emits — the upsert/delete diff
 mechanism the HBM index consumes downstream (SURVEY §3.4).
 
-One poll is snapshot, diff, emit, commit.  The snapshot of a directory and
-the bytes of its new and changed files come from the native core
-(``_native`` ``walk_dir`` / ``read_files``: two calls a poll, the
-interpreter lock released), so the Python work of a poll follows the files
-that changed; every known file is still compared by (mtime, size) on every
-poll.  The Python lister (``glob`` + ``os.stat`` + ``open``) gives the same
-snapshot and takes over where :meth:`_FsSubject._native_walk_args` says it
+One poll is two passes with a commit between them.  Pass 1 lists the path
+and hands over what the listing alone shows: the names ``_seen`` does not
+hold are new files (one ``fstatat`` each, read, emitted), the names of
+``_seen`` that are not listed are deletions, and both are committed at once,
+so the engine works on a dropped file while pass 2 runs.  Pass 2 compares
+every file that was known before the poll by (mtime, size), on every poll,
+and retracts, re-reads and commits the changed ones.  Listing, stats and
+bytes come from the native core (``_native`` ``list_dir`` / ``stat_files`` /
+``read_files``, the interpreter lock released), so pass 1 costs the entries
+that are there and the files that are new, and only pass 2 one system call a
+known file.  The Python lister (``glob`` + ``os.stat`` + ``open``) finds the
+same files in one pass, emits them in the same order (removed, new,
+changed) and takes over where :meth:`_FsSubject._native_walk_args` says it
 must.
 """
 
@@ -68,6 +74,13 @@ def _load_native() -> Any:
                 core = None
             _native_core = core
         return _native_core
+
+
+def _nul_ended(paths: list[str]) -> bytes:
+    """``paths`` as ``_native`` takes a list of them: each ended by NUL."""
+    if not paths:
+        return b""
+    return ("\0".join(paths) + "\0").encode("utf-8", "surrogateescape")
 
 
 def _read_files_python(paths: list[str]) -> Iterator[bytes | OSError]:
@@ -132,13 +145,13 @@ class _FsSubject(ConnectorSubject):
             self._seen = dict(offsets)
 
     def _native_walk_args(self) -> tuple[bytes, bytes] | None:
-        """``(root, pattern)`` for ``_native.walk_dir``, or ``None`` where
+        """``(root, pattern)`` for ``_native.list_dir``, or ``None`` where
         only the Python lister gives ``glob``'s answer: ``path`` is itself
         a glob, or ``object_pattern`` is more than ``*``, ``?`` and plain
         characters of one base name (a separator, a bracket expression,
         ``**``, nothing), or file names do not decode as UTF-8 here.  A
-        ``path`` that is a single file, or is not there, is the walk's own
-        to report (``entries`` < 0)."""
+        ``path`` that is a single file, or is not there, is the listing's
+        own to report (``entries`` < 0)."""
         pattern = self.object_pattern
         if (
             _glob.has_magic(self.path)
@@ -170,30 +183,73 @@ class _FsSubject(ConnectorSubject):
             )
         return sorted(f for f in _glob.glob(p) if os.path.isfile(f))
 
-    def _snapshot(self) -> tuple[list[str], list[float], list[int], int, Any]:
-        """The files of the path now: sorted paths, ``st_mtime`` and
-        ``st_size`` of each, the directory entries it took to find them,
-        and the native core if it did the listing (else ``None``)."""
+    def _list(self, known: list[str]) -> tuple[list, list, list[str], Any, int, int]:
+        """Pass 1: what listing the path shows against ``known``, the paths
+        of ``_seen``.  Returns ``(removed, todo, listed, core, entries,
+        stats)``: the known paths that are gone, ``(path, mtime, size, old
+        entry of _seen)`` of each file to read in the order it is emitted,
+        the known paths left for pass 2 to compare, the native core if it
+        did the listing (else ``None``), the directory entries it took and
+        the stat calls made.  The native listing finds the new files and
+        leaves every known one that is still listed to pass 2; the Python
+        lister stats every file, so its ``todo`` holds the new files and
+        then the changed ones, and nothing is left."""
         core = _load_native() if self._native_args is not None else None
         if core is not None:
-            blob, mtimes, sizes, entries = core.walk_dir(*self._native_args)
+            blob, mtimes, sizes, missing, entries, stats = core.list_dir(
+                *self._native_args, _nul_ended(known), len(known)
+            )
             if entries >= 0:  # the path is a directory
                 try:
                     paths = blob.decode("utf-8").split("\0") if blob else []
-                    return paths, mtimes, sizes, entries, core
                 except UnicodeDecodeError:
                     # a name os.fsdecode escapes sorts elsewhere as a str
                     pass
-        paths, mtimes, sizes = [], [], []
+                else:
+                    gone = set(missing)
+                    return (
+                        [known[i] for i in missing],
+                        [(p, m, s, None) for p, m, s in zip(paths, mtimes, sizes)],
+                        [p for i, p in enumerate(known) if i not in gone]
+                        if gone else known,
+                        core, entries, stats,
+                    )
+        seen = self._seen
+        current, new, changed = set(), [], []
         for path in self._list_files():
             try:
                 st = os.stat(path)
             except OSError:
                 continue
-            paths.append(path)
-            mtimes.append(st.st_mtime)
-            sizes.append(st.st_size)
-        return paths, mtimes, sizes, len(paths), None
+            current.add(path)
+            old = seen.get(path)
+            if old is None:
+                new.append((path, st.st_mtime, st.st_size, None))
+            elif old[0] != st.st_mtime or old[1] != st.st_size:
+                changed.append((path, st.st_mtime, st.st_size, old))
+        removed = [p for p in known if p not in current]
+        return removed, new + changed, [], None, len(current), len(current)
+
+    def _verify(self, core: Any, listed: list[str]) -> tuple[list, list, bool]:
+        """Pass 2: one ``fstatat`` of every path in ``listed``, compared
+        with the (mtime, size) ``_seen`` holds.  Returns ``(removed, todo,
+        entered)`` as :meth:`_list` does: what is no regular file any more
+        (a link whose target went, a file unlinked since the listing), the
+        changed files in path order, and whether one of the removed is a
+        directory now, which ``**`` enters."""
+        mtimes, sizes, kinds = core.stat_files(_nul_ended(listed), len(listed))
+        seen = self._seen
+        removed, todo, entered = [], [], False
+        for path, mtime, size, kind in zip(listed, mtimes, sizes, kinds):
+            if kind != core.REGULAR:
+                removed.append(path)
+                entered = entered or kind == core.DIRECTORY
+                continue
+            old = seen[path]
+            if old[0] != mtime or old[1] != size:
+                todo.append((path, mtime, size, old))
+        todo.sort()  # paths differ, so nothing else is compared
+        return removed, todo, entered
 
     def _metadata_of(self, path: str, mtime: float, size: int) -> dict | None:
         if not self.with_metadata:
@@ -264,54 +320,77 @@ class _FsSubject(ConnectorSubject):
         return keys
 
     def _scan_once(self) -> bool:
-        from ...internals.flight_recorder import span
+        return self._scan_and_emit()[0]
 
-        with span("connector.scan", "connector", record=False) as timed:
-            changed, attrs = self._scan_and_emit()
-            timed.set(**attrs)
+    def _scan_and_emit(self) -> tuple[bool, dict]:
+        """One poll of the path: list it, emit and commit the new and the
+        removed files (the span ``connector.scan``); then, where the native
+        core listed and a known file is still there, compare every known
+        file by (mtime, size) and emit and commit the changed ones (the span
+        ``connector.verify``).  Returns ``(anything changed, the scan
+        span's attrs)``."""
+        from ...internals.flight_recorder import span
+        from ...internals.monitoring import (
+            record_connector_files,
+            record_connector_scan,
+        )
+
+        label = self._metrics_label or f"{self._datasource_name}-0"
+        clock = _time.perf_counter
+        known = list(self._seen)  # what pass 2 checks: the files known BEFORE this poll
+        with span("connector.scan", "connector", record=False) as scan:
+            t_start = clock()
+            removed, todo, listed, core, entries, stats = self._list(known)
+            t_listed = clock()
+            files = self._emit(removed, todo, core)
+            changed = bool(removed or files)
+            scan.set(
+                files=files, removed=len(removed), native=core is not None,
+                entries=entries, stats=stats,
+                walk_ms=round((t_listed - t_start) * 1e3, 3),
+                emit_ms=round((clock() - t_listed) * 1e3, 3),
+            )
             if changed:
                 # an empty poll is no part of a document's way: it shows
                 # in a profiler session only, not in the ring or the stage
-                timed.record = True
-                timed.stage = "connector.scan"
-        return changed
+                scan.record = True
+                scan.stage = "connector.scan"
+        record_connector_scan(label, "native" if core is not None else "python")
+        record_connector_files(label, "listing", files)
+        if listed:
+            # staged on every poll, so that its mean is known where nothing is
+            # ever modified; in the ring when it found something
+            with span("connector.verify", "connector", stage="connector.verify",
+                      record=False) as verify:
+                t_start = clock()
+                removed, todo, entered = self._verify(core, listed)
+                walk_ms = round((clock() - t_start) * 1e3, 3)
+                files = self._emit(removed, todo, core)
+                verify.set(known=len(listed), changed=files,
+                           removed=len(removed), walk_ms=walk_ms)
+                verify.record = bool(removed or files)
+            record_connector_files(label, "verify", files)
+            changed = changed or verify.record
+            if entered:
+                # a known name is a directory now: pass 1 took it for the file
+                # it was, so what `**` finds in it is listed by a poll without it
+                self._scan_and_emit()
+        return changed, scan.attrs
 
-    def _scan_and_emit(self) -> tuple[bool, dict]:
-        """One poll of the path: snapshot, diff against ``_seen``, emit,
-        commit.  Returns ``(anything changed, the scan span's attrs)``."""
-        from ...internals.monitoring import record_connector_scan
-
-        t_start = _time.perf_counter()
-        paths, mtimes, sizes, entries, core = self._snapshot()
-        t_walked = _time.perf_counter()
-        changed = False
-        emitted = 0
-        # the diff: no system call in this loop
+    def _emit(self, removed: list[str], todo: list[tuple], core: Any) -> int:
+        """Retract the rows of ``removed``, read and emit ``todo`` (a changed
+        file's old rows retracted first), and commit if that was anything.
+        Returns the files emitted."""
         seen = self._seen
-        known = 0
-        todo = []
-        for path, mtime, size in zip(paths, mtimes, sizes):
-            old = seen.get(path)
-            if old is not None:
-                known += 1
-                if old[0] == mtime and old[1] == size:
-                    continue
-            todo.append((path, mtime, size, old))
-        # deletions
-        if known < len(seen):
-            current = set(paths)
-            for path in [p for p in seen if p not in current]:
-                _, _, keys = seen.pop(path)
-                self._append_state_clear(path)
-                for key, values in keys:
-                    self._remove(key, values)
-                changed = True
-        # additions / modifications
+        for path in removed:
+            _, _, keys = seen.pop(path)
+            self._append_state_clear(path)
+            for key, values in keys:
+                self._remove(key, values)
+        emitted = 0
         if self.append_only and self.fmt in ("plaintext", "json", "jsonlines"):
             for path, mtime, size, old in todo:
-                if self._scan_append_mode(path, old, mtime, size):
-                    changed = True
-                    emitted += 1
+                emitted += self._scan_append_mode(path, old, mtime, size)
         elif todo:
             names = [t[0] for t in todo]
             if core is not None:
@@ -320,7 +399,7 @@ class _FsSubject(ConnectorSubject):
                 contents = _read_files_python(names)
             for (path, mtime, size, old), data in zip(todo, contents):
                 if isinstance(data, OSError):
-                    continue  # gone or unreadable since the walk: next poll
+                    continue  # gone or unreadable since the listing: next poll
                 if old is not None:
                     for key, values in old[2]:
                         self._remove(key, values)
@@ -328,22 +407,10 @@ class _FsSubject(ConnectorSubject):
                     path, data, self._metadata_of(path, mtime, size)
                 )
                 seen[path] = (mtime, size, keys)
-                changed = True
                 emitted += 1
-        if changed:
+        if removed or emitted:
             self.commit()
-        t_end = _time.perf_counter()
-        record_connector_scan(
-            self._metrics_label or f"{self._datasource_name}-0",
-            "native" if core is not None else "python",
-        )
-        return changed, {
-            "files": emitted,
-            "native": core is not None,
-            "entries": entries,
-            "walk_ms": round((t_walked - t_start) * 1e3, 3),
-            "emit_ms": round((t_end - t_walked) * 1e3, 3),
-        }
+        return emitted
 
     # ---- append-only tailing (opt-in log mode) --------------------------
 
@@ -484,13 +551,17 @@ def read(
     "plaintext_by_file" | "binary".  mode: "streaming" polls for
     new/changed/deleted files; "static" reads once at build time.
 
-    A poll of a directory lists it, stats every file and reads the new and
-    changed ones in native code with the interpreter lock released; every
-    known file is compared by (mtime, size) on every poll.  The Python
-    lister (``glob``) does the poll when the native core did not load, when
-    ``path`` is a single file or a glob, or when ``object_pattern`` holds a
-    path separator or a bracket expression; rows, keys and offsets are the
-    same, and ``pathway_connector_scans_total{lister=}`` says which it was.
+    A poll of a directory is two passes in native code with the interpreter
+    lock released.  The first lists the directory, reads the files it did
+    not know and commits them with the deletions, so a new file waits for no
+    check of another file; the second compares every known file by
+    (mtime, size), on every poll, and commits the changed ones: a poll that
+    finds both commits twice.  The Python lister (``glob``) does the poll in
+    one pass and one commit when the native core did not load, when ``path``
+    is a single file or a glob, or when ``object_pattern`` holds a path
+    separator or a bracket expression; rows, keys and offsets are the same,
+    ``pathway_connector_scans_total{lister=}`` says which it was and
+    ``pathway_connector_files_total{found=}`` which pass found a file.
 
     ``append_only=True`` (plaintext/jsonlines): grown files emit only
     their new complete lines instead of retract + full re-read — linear

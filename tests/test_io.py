@@ -308,6 +308,7 @@ import warnings  # noqa: E402
 
 from pathway_tpu.internals import flight_recorder  # noqa: E402
 from pathway_tpu.internals.monitoring import (  # noqa: E402
+    connector_files,
     connector_scans,
     exposition,
 )
@@ -436,6 +437,29 @@ def _case_symlinks(d, fmt, outside):
     _put(f"{outside}/tree/more.txt", _body(fmt, "m1"))
 
 
+def _case_link_becomes_dir(d, fmt, outside):
+    _put(f"{outside}/target.txt", _body(fmt, "t1"))
+    _put(f"{outside}/tree/in.txt", _body(fmt, "i1"))
+    _put(f"{d}/a.txt", _body(fmt, "a1"))
+    os.symlink(f"{outside}/target.txt", f"{d}/link")
+    yield
+    # the known name is listed as a link, as before: only the second pass's
+    # stat says that `**` enters it now, and the poll lists again
+    os.unlink(f"{d}/link")
+    os.symlink(f"{outside}/tree", f"{d}/link")
+
+
+def _case_new_changed_removed(d, fmt, outside):
+    for name in ("a", "b", "c", "d"):
+        _put(f"{d}/{name}.txt", _body(fmt, f"{name}1"))
+    yield
+    _put(f"{d}/d.txt", _body(fmt, "d2 longer"))
+    _put(f"{d}/b.txt", _body(fmt, "b2 longer"))
+    os.unlink(f"{d}/c.txt")
+    _put(f"{d}/sub/e.txt", _body(fmt, "e1"))
+    _put(f"{d}/0.txt", _body(fmt, "01"))
+
+
 def _case_pattern_txt(d, fmt, outside):
     _put(f"{d}/a.txt", _body(fmt, "a1"))
     _put(f"{d}/b.dat", _body(fmt, "b1"))
@@ -457,6 +481,8 @@ FS_CASES = {
     "hidden": (_case_hidden, "*"),
     "symlinks": (_case_symlinks, "*"),
     "pattern_txt": (_case_pattern_txt, "*.txt"),
+    "link_becomes_dir": (_case_link_becomes_dir, "*"),
+    "new_changed_removed": (_case_new_changed_removed, "*"),
 }
 
 
@@ -560,16 +586,78 @@ def test_fs_snapshot_of_python_lister_restores_under_native(tmp_path, monkeypatc
     assert len([e for e in events if e[0] == "insert"]) == 4
 
 
+class _RecordingCore:
+    """The native core, with every call of a poll written down: its name,
+    how many paths it was given, and the sizes of the batches the subject
+    had committed when it was made."""
+
+    def __init__(self, core, subject):
+        self._core, self._subject, self.calls = core, subject, []
+
+    def __getattr__(self, name):  # the kinds stat_files tells apart
+        return getattr(self._core, name)
+
+    def _note(self, name, n):
+        with self._subject._lock:
+            self.calls.append((name, n, [len(b) for b in self._subject._committed]))
+
+    def list_dir(self, root, pattern, known, n_known):
+        self._note("list_dir", n_known)
+        return self._core.list_dir(root, pattern, known, n_known)
+
+    def stat_files(self, paths, n):
+        self._note("stat_files", n)
+        return self._core.stat_files(paths, n)
+
+    def read_files(self, paths):
+        self._note("read_files", len(paths))
+        return self._core.read_files(paths)
+
+
+def _spans(name):
+    return [s for s in flight_recorder.get_recorder().spans(category="connector")
+            if s.name == name]
+
+
+def _staged(stage):
+    """Observations of ``pathway_request_stage_ms{stage=}`` so far."""
+    head = f'pathway_request_stage_ms_count{{stage="{stage}"}} '
+    return sum(int(float(line[len(head):].split()[0]))
+               for line in flight_recorder.observability_metrics_lines()
+               if line.startswith(head))
+
+
 @needs_native
 def test_fs_native_poll_costs_what_changed(tmp_path, monkeypatch):
     """The contract, without a clock: a poll by the native lister makes no
-    Python-level file-system call per file that is there, and the span of a
-    scan that emits says so."""
+    Python-level file-system call per file that is there and one ``fstatat``
+    a file (a first poll in its listing, a later one in its second pass);
+    the new files are committed before the known ones are checked; and the
+    spans say so."""
     d = tmp_path / "watched"
     for i in range(2000):
         _put(f"{d}/passage_{i:07d}.txt", b"w%d " % i * 8)
     subject = _fs_subject(d, "binary")
-    assert len(_poll(subject, True, monkeypatch)) == 2000
+    label = f"{subject._datasource_name}-0"
+    core = _RecordingCore(_built_core, subject)
+    monkeypatch.setattr(fs_mod, "_native_core", core)
+    files_before = connector_files()
+
+    # a first poll: every file is new, so the listing stats each once and
+    # no second pass runs
+    flight_recorder.reset_recorder()
+    verified = _staged("connector.verify")
+    assert subject._scan_once() is True
+    with subject._lock:
+        (batch,), subject._committed = subject._committed, []
+    assert len(batch) == 2000
+    assert [(name, n) for name, n, _ in core.calls] == [
+        ("list_dir", 0), ("read_files", 2000)]
+    (scan,) = _spans("connector.scan")
+    assert (scan.attrs["entries"], scan.attrs["stats"], scan.attrs["files"]) == (
+        2000, 2000, 2000)
+    assert _spans("connector.verify") == []
+    assert _staged("connector.verify") == verified
 
     calls = {"os.stat": 0, "os.path.isfile": 0, "glob.glob": 0, "open": 0}
 
@@ -584,28 +672,138 @@ def test_fs_native_poll_costs_what_changed(tmp_path, monkeypatch):
     monkeypatch.setattr(glob_mod, "glob", counting("glob.glob", glob_mod.glob))
     monkeypatch.setattr(builtins, "open", counting("open", builtins.open))
 
-    assert _poll(subject, True, monkeypatch) == []
+    # nothing new: one listing without a stat, one second pass over all
+    # 2,000; the second pass is staged and in no ring, the first neither
+    flight_recorder.reset_recorder()
+    del core.calls[:]
+    scanned = _staged("connector.scan")
+    changed, attrs = subject._scan_and_emit()
+    assert changed is False and subject._committed == []
     assert calls == {"os.stat": 0, "os.path.isfile": 0, "glob.glob": 0, "open": 0}
+    assert core.calls == [("list_dir", 2000, []), ("stat_files", 2000, [])]
+    assert (attrs["entries"], attrs["stats"], attrs["files"]) == (2000, 0, 0)
+    assert _spans("connector.scan") == [] and _spans("connector.verify") == []
+    assert _staged("connector.scan") == scanned
+    assert _staged("connector.verify") == verified + 1
 
     for i in range(2000, 2007):
         _put(f"{d}/passage_{i:07d}.txt", b"w%d " % i * 8)
     calls.update(dict.fromkeys(calls, 0))  # _put opened seven files itself
     flight_recorder.reset_recorder()
+    del core.calls[:]
     assert subject._scan_once() is True
     assert calls == {"os.stat": 0, "os.path.isfile": 0, "glob.glob": 0, "open": 0}
+    # the seven are committed when the second pass starts on the 2,000 files
+    # that were known before the poll
+    assert core.calls == [("list_dir", 2000, []), ("read_files", 7, []),
+                          ("stat_files", 2000, [7])]
     with subject._lock:
         (batch,), subject._committed = subject._committed, []
     assert len(batch) == 7
-    (scan,) = [s for s in flight_recorder.get_recorder().spans(category="connector")
-               if s.name == "connector.scan"]
+    (scan,) = _spans("connector.scan")
     assert scan.attrs["native"] is True
-    assert scan.attrs["files"] == 7
+    assert scan.attrs["files"] == 7 and scan.attrs["removed"] == 0
     assert scan.attrs["entries"] == 2007
+    assert scan.attrs["stats"] == 7  # one fstatat a NEW file
     assert scan.attrs["walk_ms"] >= 0 and scan.attrs["emit_ms"] >= 0
+    assert _spans("connector.verify") == []  # it found nothing: staged only
+    assert _staged("connector.scan") == scanned + 1
+    assert _staged("connector.verify") == verified + 2
+
+    # every file so far was a new name
+    counted = {k: n - files_before.get(k, 0) for k, n in connector_files().items()
+               if k[0] == label}
+    assert counted == {(label, "listing"): 2007, (label, "verify"): 0}
 
     # the Python lister on the same directory pays per file that is there
     assert _poll(subject, False, monkeypatch) == []
     assert calls["os.stat"] >= 2007 and calls["os.path.isfile"] >= 2007
+
+
+def _step_new_changed_removed(d):
+    _put(f"{d}/b.txt", b"beta, edited")
+    os.unlink(f"{d}/c.txt")
+    _put(f"{d}/sub/e.txt", b"epsilon")
+
+
+def _step_rename(d):
+    os.replace(f"{d}/a.txt", f"{d}/z.txt")
+
+
+def _step_changed_only(d):
+    _put(f"{d}/b.txt", b"beta, edited")
+    _put(f"{d}/a.txt", b"alpha, edited")
+
+
+def _step_new_and_removed(d):
+    os.unlink(f"{d}/a.txt")
+    _put(f"{d}/e.txt", b"epsilon")
+
+
+#: step -> what the one poll after it finds: removed, new, changed (in the
+#: order they are emitted)
+FS_COMMITS = {
+    "new_changed_removed": (_step_new_changed_removed, ["c.txt"], ["sub/e.txt"], ["b.txt"]),
+    "rename": (_step_rename, ["a.txt"], ["z.txt"], []),
+    "changed_only": (_step_changed_only, [], [], ["a.txt", "b.txt"]),
+    "new_and_removed": (_step_new_and_removed, ["a.txt"], ["e.txt"], []),
+}
+
+
+@pytest.mark.parametrize("native", [
+    pytest.param(True, marks=needs_native, id="native"),
+    pytest.param(False, id="python"),
+])
+@pytest.mark.parametrize("step", FS_COMMITS)
+def test_fs_poll_commits_new_and_removed_files_before_changed_ones(
+        tmp_path, monkeypatch, step, native):
+    """One poll sees every new, changed and removed file.  Under the native
+    lister the removed and the new ones are one commit (so a rename is one
+    commit) and the changed ones the next; the Python lister commits once,
+    in the same order."""
+    d = tmp_path / "watched"
+    for name, data in (("a", b"alpha"), ("b", b"beta"), ("c", b"gamma")):
+        _put(f"{d}/{name}.txt", data)
+    if not native:
+        monkeypatch.setattr(fs_mod, "_native_core", None)
+    subject = _fs_subject(d, "binary")
+    label = f"{subject._datasource_name}-0"
+    subject._scan_once()
+    subject._committed.clear()
+    files_before = connector_files()
+    flight_recorder.reset_recorder()
+
+    change, removed, new, changed = FS_COMMITS[step]
+    change(str(d))
+    assert subject._scan_once() is True
+    with subject._lock:
+        batches, subject._committed = subject._committed, []
+    got = [[(op, os.path.relpath(values[1].value["path"], d))
+            for op, _key, values in batch] for batch in batches]
+    first = [("delete", p) for p in removed] + [("insert", p) for p in new]
+    second = [(op, p) for p in changed for op in ("delete", "insert")]
+    assert got == ([b for b in (first, second) if b] if native else [first + second])
+    # the one poll left nothing for the next
+    assert sorted(subject._seen) == sorted(
+        os.path.join(r, f) for r, _d, fs in os.walk(d) for f in fs)
+    assert subject._scan_once() is False
+
+    counted = {k[1]: n - files_before.get(k, 0) for k, n in connector_files().items()
+               if k[0] == label}
+    scans, verifies = _spans("connector.scan"), _spans("connector.verify")
+    if native:
+        assert counted == {"listing": len(new), "verify": len(changed)}
+        # each pass is in the ring where it emitted
+        assert [(s.attrs["removed"], s.attrs["files"]) for s in scans] == (
+            [(len(removed), len(new))] if first else [])
+        assert [(v.attrs["known"], v.attrs["changed"]) for v in verifies] == (
+            [(3 - len(removed), len(changed))] if second else [])
+        for found, n in counted.items():
+            assert (f'pathway_connector_files_total{{connector="{label}",found="{found}"}} '
+                    f"{files_before.get((label, found), 0) + n}") in exposition()
+    else:
+        assert counted == {"listing": len(new) + len(changed)}
+        assert len(scans) == 1 and verifies == []
 
 
 def test_fs_python_lister_warns_once_and_is_counted(tmp_path, monkeypatch):

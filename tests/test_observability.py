@@ -1292,7 +1292,10 @@ def test_span_writes_ring_stage_and_profiler_event(tmp_path):
     assert (work.name, work.attrs) == ("unit.work", {"rows": 3, "done": True})
     assert work.duration_ms >= 2.0 and abs(time.time() - work.start_s) < 60.0
     assert (fails.name, fails.attrs) == ("unit.fails", {"ok": False})
-    assert _stage_counts() == {"unit.stage": 1.0}
+    # an earlier test's server may still poll its directory on a daemon
+    # thread, and every poll stages its second pass (connector.verify)
+    assert {stage: n for stage, n in _stage_counts().items()
+            if stage.startswith("unit.")} == {"unit.stage": 1.0}
     names = [name for name, _s, _d in planes["host"]["pw-span-test"]]
     assert names == ["pw.unit.unit.work", "pw.unit.unit.fails"]
 
