@@ -94,6 +94,7 @@ __all__ = [
     "record_tokenizer_cache",
     "ingest_stats",
     "record_moe_launch",
+    "mla_stats",
     "moe_stats",
     "record_ssm_launch",
     "ssm_stats",
@@ -988,7 +989,8 @@ def ingest_stats() -> dict[str, Any]:
 
 
 # ---------------------------------------------------------------------------
-# launch counters of a language-model embedder (pathway_moe_*, pathway_ssm_*):
+# launch counters of a language-model embedder (pathway_moe_*, pathway_mla_*,
+# pathway_ssm_*):
 # computed on the device by the forward, they come back with its result and
 # are added up here once the launch has finished: recording one never waits
 # for the device
@@ -1027,13 +1029,22 @@ class _LaunchCounters:
             return dict(self._totals)
 
 
+_MOE_NAMES = ("launches_total", "routed_tokens_total", "experts_touched_total",
+              "max_expert_tokens_sum", "max_expert_tokens")
+_MLA_NAMES = ("launches_total", "documents_total", "tokens_total", "bucket_tokens_total",
+              "attention_pairs_total")
+
+
 def _add_moe(totals: dict, values: list) -> None:
-    routed, touched, fullest_sum, fullest = values
+    routed, touched, fullest_sum, fullest = values[:4]
     totals["launches_total"] += 1
     totals["routed_tokens_total"] += routed
     totals["experts_touched_total"] += touched
     totals["max_expert_tokens_sum"] += fullest_sum
     totals["max_expert_tokens"] = fullest
+    if len(values) > 4:  # a forward with latent attention carries four more: pathway_mla_*
+        for name, value in zip(_MLA_NAMES, [1] + values[4:]):
+            totals["mla_" + name] += value
 
 
 def _add_ssm(totals: dict, values: list) -> None:
@@ -1042,8 +1053,7 @@ def _add_ssm(totals: dict, values: list) -> None:
 
 
 _moe_launches = _LaunchCounters(
-    ("launches_total", "routed_tokens_total", "experts_touched_total",
-     "max_expert_tokens_sum", "max_expert_tokens"), _add_moe)
+    _MOE_NAMES + tuple("mla_" + name for name in _MLA_NAMES), _add_moe)
 _ssm_launches = _LaunchCounters(
     ("launches_total", "documents_total", "tokens_total", "bucket_tokens_total"),
     _add_ssm)
@@ -1053,8 +1063,11 @@ def record_moe_launch(counters: Any) -> None:
     """One launch of a forward with routed experts.  ``counters`` is the
     int32 device array the forward returned beside its result: token-expert
     pairs routed and experts that got a token (summed over the routed
-    layers), each layer's fullest expert summed, and the fullest of all.  Launches that have finished are added up; this one waits in
-    line until a later call or :func:`moe_stats`."""
+    layers), each layer's fullest expert summed, and the fullest of all;
+    from a forward with latent attention four more behind them (documents,
+    real tokens, the tokens of its bucket, the (query, key) pairs its causal
+    mask let through: :func:`mla_stats`).  Launches that have finished are
+    added up; this one waits in line until a later call or :func:`moe_stats`."""
     _moe_launches.record(counters)
 
 
@@ -1062,7 +1075,16 @@ def moe_stats(wait: bool = True) -> dict[str, int]:
     """The ``pathway_moe_*`` counters over every launch so far.  ``wait``
     waits for the launches still in flight; a scrape does not (it holds
     the lock the launching thread takes) and counts them the next time."""
-    return _moe_launches.stats(wait)
+    totals = _moe_launches.stats(wait)
+    return {name: totals[name] for name in _MOE_NAMES}
+
+
+def mla_stats(wait: bool = True) -> dict[str, int]:
+    """The ``pathway_mla_*`` counters over every launch of a forward with
+    latent attention so far (they ride the array :func:`record_moe_launch`
+    takes); ``wait`` as :func:`moe_stats`."""
+    totals = _moe_launches.stats(wait)
+    return {name: totals["mla_" + name] for name in _MLA_NAMES}
 
 
 def record_ssm_launch(counters: Any) -> None:
@@ -1182,7 +1204,8 @@ def observability_metrics_lines() -> list[str]:
         "pathway_embed_intra_bucket_efficiency "
         f"{ing['intra_bucket_efficiency']:.4f}"
     )
-    for family, totals in (("moe", moe_stats(wait=False)), ("ssm", ssm_stats(wait=False))):
+    for family, totals in (("moe", moe_stats(wait=False)), ("mla", mla_stats(wait=False)),
+                           ("ssm", ssm_stats(wait=False))):
         if totals["launches_total"]:
             for name, value in totals.items():
                 kind = "counter" if name.endswith(("_total", "_sum")) else "gauge"

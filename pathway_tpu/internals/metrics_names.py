@@ -239,6 +239,25 @@ METRICS: dict[str, tuple[str, str]] = {
     "pathway_moe_max_expert_tokens": (
         "gauge", "the fullest expert of the last launch",
     ),
+    # launch counters of a forward with latent attention
+    # (models/causal_moe_embedder.py _counters; they ride the array
+    # flight_recorder.record_moe_launch adds up)
+    "pathway_mla_launches_total": (
+        "counter", "launches of a forward with latent attention",
+    ),
+    "pathway_mla_documents_total": (
+        "counter", "documents (rows that hold a token) those launches carried",
+    ),
+    "pathway_mla_tokens_total": (
+        "counter", "real tokens those launches carried",
+    ),
+    "pathway_mla_bucket_tokens_total": (
+        "counter", "tokens of those launches' buckets, padding included",
+    ),
+    "pathway_mla_attention_pairs_total": (
+        "counter", "(query, key) pairs the causal mask let through, L(L+1)/2 a document, "
+                   "counted once a launch",
+    ),
     # launch counters of a forward with state-space layers
     # (models/causal_hybrid_embedder.py, added up by
     # flight_recorder.record_ssm_launch)

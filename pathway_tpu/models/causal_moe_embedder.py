@@ -1,5 +1,5 @@
-"""A causal language model as a sentence embedder: window and full attention
-mixed, grouped queries, rotary positions, routed and shared experts, the
+"""A causal language model as a sentence embedder: window, full and latent
+attention, grouped queries, rotary positions, routed and shared experts, the
 final-norm state of the last token as the vector.
 
 The forward of a decoder-only model with nothing generated (no output head,
@@ -9,8 +9,8 @@ arXiv:2401.00368).  :class:`SentenceEncoder` builds it from a
 ``EncoderConfig``; tokenizing, bucketing, dispatch and the spans around them
 are the ones every encoder takes.
 
-Layer ``l`` (``x`` [T, D]; ``H_l`` query heads, ``KV`` key/value heads of size
-``hd``; no bias; RMS norms):
+Layer ``l`` of kind ``"full"`` or ``"window"`` (``x`` [T, D]; ``H_l`` query
+heads, ``KV`` key/value heads of size ``hd``; no bias; RMS norms):
 
 1. ``a = rmsnorm(x)``; ``q = a Wq`` [T, H_l, hd], ``k = a Wk``, ``v = a Wv``
    [T, KV, hd].
@@ -22,9 +22,24 @@ Layer ``l`` (``x`` [T, D]; ``H_l`` query heads, ``KV`` key/value heads of size
 4. ``g = sigmoid(a Wg)`` [T, H_l]; ``x += concat_h(g_h o_h) Wo``.
 5. ``b = rmsnorm(x)``; dense layers: ``x += (silu(b Wg) * (b Wu)) Wd``; sparse
    layers: ``x += routed_experts(b) + shared_expert(b)``
-   (:mod:`pathway_tpu.ops.routed_experts`).
+   (:mod:`pathway_tpu.ops.routed_experts`; ``router_scoring`` picks the
+   softmax router or the sigmoid one with its selection bias).
 6. after the last layer ``rmsnorm``; a row's vector is the state of its last
    real token (the index normalises it).
+
+A layer of kind ``"latent"`` (multi-head latent attention, arXiv:2405.04434,
+as the DeepSeek-V3 lineage configures it) replaces steps 1-4
+(:func:`_attend_latent`): queries and keys/values come through two low-rank
+projections with an RMS norm inside each, a head's query and key are a
+rotary-free part and a rotary part side by side, the rotary key is ONE head
+shared by all, the value has a size of its own, there is no gate.  It runs
+here in the prefill ("expanded") form: keys and values are expanded from the
+latent for every token and a (query, key, head) triple costs ``qk_nope_dim +
+qk_rope_dim + v_head_dim`` multiply-adds (192 + 128), where the absorbed form
+(the up-projections folded into query and output, scores taken against the
+latent itself) costs ``kv_lora_rank + qk_rope_dim + kv_lora_rank`` (576 +
+512).  The absorbed form pays where a cache of latents is read back; an
+embedder keeps no cache, so there is nothing it would save.
 
 Precision: weights are held in ``param_dtype`` (bfloat16) and products take
 ``dtype`` (bfloat16) operands with float32 accumulation; the residual
@@ -86,18 +101,25 @@ class RotarySpec:
     beta_slow: float = 1.0
     #: multiplies cosine and sine; None: ``0.1 ln(yarn_factor) + 1``
     attention_factor: float | None = None
+    #: pairs are ``(2i, 2i+1)`` (``rope_interleave``) and not ``(i, i +
+    #: rot/2)``: the rotated part comes out as ``[evens | odds]``
+    interleaved: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
 class CausalMoeEmbedderConfig:
     """Laguna-XS.2's widths by default, at the five leading layers (the
-    dense one and one period of three window layers and a full one)."""
+    dense one and one period of three window layers and a full one).  A
+    field that one kind of layer alone reads (``head_dim``, ``num_kv_heads``
+    and ``window`` the grouped-query kinds; the ``latent_*`` group the latent
+    kind) is checked only where a layer of that kind is present."""
 
     vocab_size: int = 100_352
     hidden_dim: int = 2048
     head_dim: int = 128
     num_kv_heads: int = 8
-    #: per layer: "full" or "window"; its query heads; "dense" or "sparse"
+    #: per layer: "full", "window" or "latent"; its query heads; "dense" or
+    #: "sparse"
     layer_types: tuple[str, ...] = ("full", "window", "window", "window", "full")
     heads_per_layer: tuple[int, ...] = (48, 64, 64, 64, 48)
     mlp_types: tuple[str, ...] = ("dense", "sparse", "sparse", "sparse", "sparse")
@@ -107,12 +129,24 @@ class CausalMoeEmbedderConfig:
         original_max_len=4096, beta_fast=64.0, beta_slow=1.0,
         attention_factor=1.4158883083359672)
     window_rotary: RotarySpec = RotarySpec(theta=10_000.0)
+    #: latent layers: the ranks of the query's and the key/value's low-rank
+    #: paths, a head's rotary-free and rotary parts (the rotary key is one
+    #: head under all), the value's size, rotary over the whole rotary part
+    latent_q_rank: int = 1536
+    latent_kv_rank: int = 512
+    latent_nope_dim: int = 128
+    latent_rope_dim: int = 64
+    latent_v_dim: int = 128
+    latent_rotary: RotarySpec = RotarySpec(theta=32_000_000.0, interleaved=True)
     dense_mlp_dim: int = 8192
     num_experts: int = 256
     top_k: int = 8
     expert_dim: int = 512
     shared_expert_dim: int = 512
     routed_scaling: float = 2.5
+    #: "softmax", or "sigmoid": the sparse layers then hold a per-expert
+    #: ``bias`` that enters the choice of experts and not their weights
+    router_scoring: str = "softmax"
     rms_eps: float = 1e-6
     #: longest row the dispatch takes; rotary positions need no table
     max_len: int = 2048
@@ -160,9 +194,19 @@ class CausalMoeEmbedderConfig:
         if not (len(self.heads_per_layer) == len(self.mlp_types) == n):
             raise ValueError("layer_types, heads_per_layer and mlp_types "
                              "must name the same layers")
-        if any(h % self.num_kv_heads for h in self.heads_per_layer):
-            raise ValueError("each layer's query heads must be a multiple "
+        if not set(self.layer_types) <= {"full", "window", "latent"}:
+            raise ValueError(f"layer_types {self.layer_types}")
+        if any(h % self.num_kv_heads for kind, h in zip(self.layer_types, self.heads_per_layer)
+               if kind != "latent"):
+            raise ValueError("a grouped-query layer's query heads must be a multiple "
                              f"of num_kv_heads={self.num_kv_heads}")
+        if "latent" in self.layer_types and (self.latent_rope_dim % 2 or min(
+                self.latent_q_rank, self.latent_kv_rank, self.latent_nope_dim,
+                self.latent_rope_dim, self.latent_v_dim) < 1):
+            raise ValueError("a latent layer needs positive ranks and head parts, "
+                             "the rotary part even")
+        if self.router_scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_scoring {self.router_scoring!r}")
 
     @property
     def num_layers(self) -> int:
@@ -207,28 +251,36 @@ def _rms_norm(x, scale, eps: float):
 
 
 def _rotate(x, pos, spec: RotarySpec):
-    """``x`` [T, H, hd], ``pos`` [T] -> rotated, float32."""
+    """``x`` [T, H, hd], ``pos`` [T] -> rotated, float32.  Interleaved
+    pairs are first gathered to ``[evens | odds]``, as the published model
+    does, and then rotated as halves; the result stays in that order, which
+    leaves a score unchanged where query and key are permuted alike."""
     inv_freq, factor = rotary_inv_freq(spec, x.shape[-1])
     rot = 2 * inv_freq.shape[0]
     angles = pos.astype(jnp.float32)[:, None] * jnp.asarray(inv_freq, jnp.float32)[None, :]
     cos = (jnp.cos(angles) * factor)[:, None, :]
     sin = (jnp.sin(angles) * factor)[:, None, :]
     x = x.astype(jnp.float32)
-    x1, x2, rest = x[..., : rot // 2], x[..., rot // 2: rot], x[..., rot:]
+    if spec.interleaved:
+        x1, x2, rest = x[..., 0:rot:2], x[..., 1:rot:2], x[..., rot:]
+    else:
+        x1, x2, rest = x[..., : rot // 2], x[..., rot // 2: rot], x[..., rot:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin, rest], axis=-1)
 
 
 def _attention(q, k, v, pos, seg, valid, *, window: int | None, q_block: int):
     """Causal grouped-query attention of one token axis.  ``q`` [T, H, hd],
-    ``k``/``v`` [T, KV, hd] in the compute dtype, ``pos`` [T] positions in
-    the row; ``seg`` [T] the row of each token and ``valid`` [T] whether it
-    is one (both None: one row, every token real).  Returns [T, H, hd]
-    float32.  Query block ``i`` visits key blocks ``first..i``: ``first``
-    holds the start of the row its first token belongs to (a later row of
-    the block starts later), on window layers no further back than the
-    window reaches; a block whose first token is padding visits none."""
+    ``k`` [T, KV, hd], ``v`` [T, KV, vd] in the compute dtype: scores and
+    their scale go by ``hd``, the result by the value's own ``vd``, and ``KV
+    == H`` is a group of one.  ``pos`` [T] positions in the row; ``seg`` [T]
+    the row of each token and ``valid`` [T] whether it is one (both None:
+    one row, every token real).  Returns [T, H, vd] float32.  Query block
+    ``i`` visits key blocks ``first..i``: ``first`` holds the start of the
+    row its first token belongs to (a later row of the block starts later),
+    on window layers no further back than the window reaches; a block whose
+    first token is padding visits none."""
     t, h, hd = q.shape
-    kv = k.shape[1]
+    kv, vd = k.shape[1], v.shape[-1]
     bq = min(q_block, t)
     blocks = -(-t // bq)
     if blocks * bq > t:  # whole blocks: what is added lies behind every token
@@ -276,9 +328,9 @@ def _attention(q, k, v, pos, seg, valid, *, window: int | None, q_block: int):
                                     preferred_element_type=jnp.float32)
 
         return jax.lax.fori_loop(first, last, weigh,
-                                 jnp.zeros((bq, kv, h // kv, hd), jnp.float32))
+                                 jnp.zeros((bq, kv, h // kv, vd), jnp.float32))
 
-    return jax.lax.map(block, jnp.arange(blocks)).reshape(blocks * bq, h, hd)[:t]
+    return jax.lax.map(block, jnp.arange(blocks)).reshape(blocks * bq, h, vd)[:t]
 
 
 def _gated_mlp(x, w_gate_up, w_down, gate_scale: float = 1.0):
@@ -294,6 +346,8 @@ def _gated_mlp(x, w_gate_up, w_down, gate_scale: float = 1.0):
 def _attend_row(cfg: CausalMoeEmbedderConfig, i: int, p, a, pos, seg, valid):
     """Steps 1-4 of layer ``i`` for one token axis: ``a`` [T, D] the normed
     input (float32) -> the attention's addition to the residual, float32."""
+    if cfg.layer_types[i] == "latent":
+        return _attend_latent(cfg, p, a, pos, seg, valid)
     dt = cfg.dtype
     full = cfg.layer_types[i] == "full"
     spec = cfg.full_rotary if full else cfg.window_rotary
@@ -307,6 +361,32 @@ def _attend_row(cfg: CausalMoeEmbedderConfig, i: int, p, a, pos, seg, valid):
     gate = jax.nn.sigmoid(jnp.dot(ad, p["wg"], preferred_element_type=jnp.float32))
     o = (o * gate[:, :, None]).astype(dt)
     return jnp.einsum("the,hed->td", o, p["wo"], preferred_element_type=jnp.float32)
+
+
+def _attend_latent(cfg: CausalMoeEmbedderConfig, p, a, pos, seg, valid):
+    """A latent layer's attention for one token axis, in the prefill form:
+    ``c_q = rmsnorm(a W_dq)``, ``q = c_q W_uq`` [T, H, nope + rope]; ``[c_kv |
+    k_pe] = a W_dkv`` with ``k_pe`` ONE head [T, rope], ``[k_nope | v] =
+    rmsnorm(c_kv) W_ukv`` [T, H, nope + vd]; rotary on ``q``'s rotary part
+    and on ``k_pe``; head ``h`` scores ``[q_nope | q_pe]_h . [k_nope_h |
+    k_pe] / sqrt(nope + rope)`` under the causal mask and sums ``v_h``; no
+    gate.  The two latent norms are float32 like every norm."""
+    dt, nope = cfg.dtype, cfg.latent_nope_dim
+    f32 = dict(preferred_element_type=jnp.float32)
+    ad = a.astype(dt)
+    c_q = _rms_norm(jnp.dot(ad, p["wq_a"], **f32), p["q_norm"], cfg.rms_eps)
+    q = jnp.einsum("tr,rhe->the", c_q.astype(dt), p["wq_b"], **f32)
+    ckv = jnp.dot(ad, p["wkv_a"], **f32)
+    c_kv = _rms_norm(ckv[:, : cfg.latent_kv_rank], p["kv_norm"], cfg.rms_eps)
+    kv = jnp.einsum("tr,rhe->the", c_kv.astype(dt), p["wkv_b"], **f32)
+    q_pe = _rotate(q[..., nope:], pos, cfg.latent_rotary)
+    k_pe = _rotate(ckv[:, None, cfg.latent_kv_rank:], pos, cfg.latent_rotary)
+    q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+    k = jnp.concatenate(
+        [kv[..., :nope], jnp.broadcast_to(k_pe, q_pe.shape)], axis=-1)  # the one rotary key
+    o = _attention(q.astype(dt), k.astype(dt), kv[..., nope:].astype(dt), pos, seg, valid,
+                   window=None, q_block=cfg.q_block)
+    return jnp.einsum("the,hed->td", o.astype(dt), p["wo"], **f32)
 
 
 def _layer(cfg: CausalMoeEmbedderConfig, i: int, p, x, pos, seg, valid):
@@ -331,7 +411,8 @@ def _layer(cfg: CausalMoeEmbedderConfig, i: int, p, x, pos, seg, valid):
     routed, group_sizes = routed_experts(
         bd.reshape(flat + bd.shape[2:]), valid.reshape(flat), m["router"],
         m["w_gate_up"], m["w_down"], top_k=cfg.top_k, scaling=cfg.routed_scaling,
-        router_input=b.reshape(flat + b.shape[2:]))
+        router_input=b.reshape(flat + b.shape[2:]), scoring=cfg.router_scoring,
+        bias=m.get("bias"))
     shared = _gated_mlp(bd, m["shared"]["w_gate_up"], m["shared"]["w_down"])
     return x + routed.reshape(x.shape) + shared, group_sizes
 
@@ -349,17 +430,26 @@ def _tokens_forward(cfg, params, ids, pos, seg, valid):
     return _rms_norm(x, params["final_norm"], cfg.rms_eps), sizes
 
 
-def _counters(sizes: list):
-    if not sizes:
-        return jnp.zeros((4,), jnp.int32)
-    return launch_counters(sizes)
+def _counters(cfg, sizes: list, lengths, valid):
+    """int32 of one launch: the routed experts' four (``launch_counters``),
+    and behind them, from a model with latent layers, its documents, real
+    tokens, the tokens of its bucket and the (query, key) pairs the causal
+    mask lets through (``L (L + 1) / 2`` a document of ``L`` tokens; once a
+    launch, not once a layer).  ``flight_recorder.record_moe_launch`` adds
+    either up."""
+    moe = launch_counters(sizes) if sizes else jnp.zeros((4,), jnp.int32)
+    if "latent" not in cfg.layer_types:
+        return moe
+    return jnp.concatenate([moe, jnp.stack([
+        jnp.sum(lengths > 0), jnp.sum(lengths), jnp.int32(valid.size),
+        jnp.sum(lengths * (lengths + 1) // 2)]).astype(jnp.int32)])
 
 
 class CausalMoeEmbedder:
     """The model as :class:`SentenceEncoder` takes one: ``init`` and
     ``apply`` over ``{"params": tree}``.  ``apply`` returns (vectors
-    float32, the launch's routed-expert counters); ``record_launch`` is
-    where the encoder sends the second."""
+    float32, the launch's counters: :func:`_counters`); ``record_launch``
+    is where the encoder sends the second."""
 
     def __init__(self, cfg: CausalMoeEmbedderConfig, packed: bool = False):
         self.cfg = cfg
@@ -402,8 +492,9 @@ class CausalMoeEmbedder:
         b, s = ids.shape
         pos = jnp.broadcast_to(jnp.arange(s), (b, s))
         x, sizes = _tokens_forward(cfg, params, ids, pos, None, valid)
-        last = jnp.maximum(jnp.sum(mask, axis=1) - 1, 0)
-        return x[jnp.arange(b), last], _counters(sizes)
+        lengths = jnp.sum(mask, axis=1)
+        last = jnp.maximum(lengths - 1, 0)
+        return x[jnp.arange(b), last], _counters(cfg, sizes, lengths, valid)
 
     def _apply_packed(self, params, ids, pos, seg, starts, bounds=None, *,
                       dense_s: int | None = None):
@@ -421,13 +512,14 @@ class CausalMoeEmbedder:
             cfg, params, ids[None], pos[None], seg[None], valid[None])
         lengths = jnp.zeros((rows + 1,), jnp.int32).at[seg].add(1)[:rows]
         last = starts.astype(jnp.int32) + jnp.maximum(lengths - 1, 0)
-        return x[0][last], _counters(sizes)
+        return x[0][last], _counters(cfg, sizes, lengths, valid)
 
 
 def init_params(cfg: CausalMoeEmbedderConfig, key):
     """A parameter tree drawn layer by layer (a tensor of experts is a
     gigabyte): token embeddings at unit scale, matrices at
-    1/sqrt(fan-in), norms at one."""
+    1/sqrt(fan-in), norms at one, a sigmoid router's selection bias at
+    zero."""
     pd, d, hd, kv = cfg.param_dtype, cfg.hidden_dim, cfg.head_dim, cfg.num_kv_heads
 
     def normal(key, shape, fan_in):
@@ -444,12 +536,23 @@ def init_params(cfg: CausalMoeEmbedderConfig, key):
     for i in range(cfg.num_layers):
         h = cfg.heads_per_layer[i]
         k = jax.random.split(keys[i + 1], 8)
-        layer = {
-            "attn_norm": jnp.ones((d,), pd), "mlp_norm": jnp.ones((d,), pd),
-            "wq": normal(k[0], (d, h, hd), d), "wk": normal(k[1], (d, kv, hd), d),
-            "wv": normal(k[2], (d, kv, hd), d), "wg": normal(k[3], (d, h), d),
-            "wo": normal(k[4], (h, hd, d), h * hd),
-        }
+        layer = {"attn_norm": jnp.ones((d,), pd), "mlp_norm": jnp.ones((d,), pd)}
+        if cfg.layer_types[i] == "latent":
+            qr, kvr, nope = cfg.latent_q_rank, cfg.latent_kv_rank, cfg.latent_nope_dim
+            rope, vd = cfg.latent_rope_dim, cfg.latent_v_dim
+            layer.update({
+                "wq_a": normal(k[0], (d, qr), d), "q_norm": jnp.ones((qr,), pd),
+                "wq_b": normal(k[1], (qr, h, nope + rope), qr),
+                "wkv_a": normal(k[2], (d, kvr + rope), d), "kv_norm": jnp.ones((kvr,), pd),
+                "wkv_b": normal(k[3], (kvr, h, nope + vd), kvr),
+                "wo": normal(k[4], (h, vd, d), h * vd),
+            })
+        else:
+            layer.update({
+                "wq": normal(k[0], (d, h, hd), d), "wk": normal(k[1], (d, kv, hd), d),
+                "wv": normal(k[2], (d, kv, hd), d), "wg": normal(k[3], (d, h), d),
+                "wo": normal(k[4], (h, hd, d), h * hd),
+            })
         if cfg.mlp_types[i] == "dense":
             layer["mlp"] = mlp(k[5], (), cfg.dense_mlp_dim)
         else:
@@ -458,6 +561,8 @@ def init_params(cfg: CausalMoeEmbedderConfig, key):
                 **mlp(k[6], (cfg.num_experts,), cfg.expert_dim),
                 "shared": mlp(k[7], (), cfg.shared_expert_dim),
             }
+            if cfg.router_scoring == "sigmoid":  # float32, as the router reads it
+                layer["moe"]["bias"] = jnp.zeros((cfg.num_experts,), jnp.float32)
         params[f"layer_{i}"] = layer
     return params
 

@@ -2,9 +2,12 @@
 dropping any, one grouped matrix product over the experts that got tokens,
 the weighted combine.
 
-The layer of a sparse mixture-of-experts block as the Qwen-MoE lineage
-defines it (softmax scores over all experts, the ``top_k`` largest kept,
-their scores renormalised and scaled), for a flat axis of tokens:
+The layer of a sparse mixture-of-experts block with either of two scorings
+(:func:`route`): the Qwen-MoE lineage's (softmax scores over all experts, the
+``top_k`` largest kept, their scores renormalised and scaled) and the
+DeepSeek-V3 lineage's (``noaux_tc``: sigmoid scores, the ``top_k`` largest of
+score + a per-expert bias chosen, the weights the UNBIASED scores of the
+chosen, renormalised and scaled), for a flat axis of tokens:
 
 * every token is routed and computed: there is no capacity and no drop, so
   a launch of several documents gives each the result it gets alone;
@@ -30,18 +33,37 @@ __all__ = ["route", "group_tokens", "grouped_matmul", "routed_experts",
            "launch_counters"]
 
 
-def route(x, router, *, top_k: int, scaling: float):
+def route(x, router, *, top_k: int, scaling: float, scoring: str = "softmax",
+          bias=None):
     """``x`` [T, D] (any float dtype), ``router`` [D, E] -> the chosen
     experts [T, top_k] (int32, by falling score) and their weights
-    [T, top_k] (float32): softmax over all E experts in float32, the
-    ``top_k`` largest, divided by their sum, times ``scaling``."""
+    [T, top_k] (float32), all in float32.
+
+    ``scoring="softmax"``: softmax over all E experts, the ``top_k``
+    largest, divided by their sum, times ``scaling``.
+    ``scoring="sigmoid"``: a sigmoid an expert; divided by the chosen's sum
+    + 1e-20 (as published), times ``scaling``.
+    ``bias`` [E] (``e_score_correction_bias``) enters the CHOICE and not the
+    weight: the ``top_k`` largest of score + bias are chosen (listed by
+    falling biased score), and weighted by their scores without it.  The
+    lineage's group limit (the best ``topk_group`` of ``n_group`` groups of
+    experts may be chosen from) is not coded: with one group it keeps every
+    expert, and no configuration here has more."""
     logits = jnp.dot(
         x.astype(jnp.float32), router.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST,
     )
-    scores = jax.nn.softmax(logits, axis=-1)
-    top, experts = jax.lax.top_k(scores, top_k)
-    return experts.astype(jnp.int32), top / jnp.sum(top, axis=-1, keepdims=True) * scaling
+    if scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"scoring {scoring!r}")
+    soft = scoring == "softmax"
+    scores = jax.nn.softmax(logits, axis=-1) if soft else jax.nn.sigmoid(logits)
+    if bias is None:
+        top, experts = jax.lax.top_k(scores, top_k)
+    else:
+        _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+        top = jnp.take_along_axis(scores, experts, axis=-1)
+    total = jnp.sum(top, axis=-1, keepdims=True)
+    return experts.astype(jnp.int32), top / (total if soft else total + 1e-20) * scaling
 
 
 def group_tokens(experts, valid, num_experts: int):
@@ -68,17 +90,19 @@ def grouped_matmul(lhs, rhs, group_sizes):
 
 
 def routed_experts(x, valid, router, w_gate_up, w_down, *, top_k: int,
-                   scaling: float, router_input=None):
+                   scaling: float, router_input=None, scoring: str = "softmax",
+                   bias=None):
     """Sum over each token's experts of ``weight * E(x)`` with ``E`` a gated
     MLP (``silu(x Wg) * (x Wu)) Wd``.
 
     ``x`` [T, D] in the compute dtype, ``valid`` [T] bool, ``router``
     [D, E], ``w_gate_up`` [E, D, 2F] (gate columns first), ``w_down``
     [E, F, D]; ``router_input`` is what the router reads where ``x`` is a
-    rounded copy of it.  Returns ([T, D] float32, group sizes [E])."""
+    rounded copy of it; ``scoring`` and ``bias`` [E] are :func:`route`'s.
+    Returns ([T, D] float32, group sizes [E])."""
     num_experts, _, two_f = w_gate_up.shape
     experts, weights = route(x if router_input is None else router_input, router,
-                             top_k=top_k, scaling=scaling)
+                             top_k=top_k, scaling=scaling, scoring=scoring, bias=bias)
     order, group_sizes, inverse = group_tokens(experts, valid, num_experts)
     rows = x[order // top_k]
     h = grouped_matmul(rows, w_gate_up, group_sizes)

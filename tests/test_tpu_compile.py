@@ -11,8 +11,10 @@ had passed; a later PR that touches that kernel's blocks, or the embedder's
 shapes, would find out the same way, on the chip's budget.  The hybrid
 embedder's cell (``ingest-docs-falcon-h1``) added its own: the scan kernel at
 the published shapes, the packed forward at each token bucket with that
-kernel in it, and the index's search and apply at rows of 5,120 values.  A
-dozen compiles, one file (the on-chip-measurement guide, section 2): the topology is described
+kernel in it, and the index's search and apply at rows of 5,120 values.  The
+latent-attention embedder's cell (``ingest-docs-joyai``), the fullest, its
+packed forward at each token bucket beside 10.59 GB of weights and the index
+at its shapes.  Some twenty compiles, one file (the on-chip-measurement guide, section 2): the topology is described
 inside a fixture of THIS file only, because one process at a time may load the
 TPU's library, and the persistent compile cache is kept out of it, because a
 compile for a described chip cannot be read back without the chip."""
@@ -242,6 +244,73 @@ def test_the_index_searches_and_applies_rows_of_5120_values(one_chip, no_persist
     memory = compiled.memory_analysis()
     # undonated: the matrix in, the matrix out (PERF.md 7 #6), beside the weights
     assert memory.argument_size_in_bytes + memory.output_size_in_bytes < 2 * 1.35e9
+    mask = getattr(knn._scatter_mask, "__wrapped__", knn._scatter_mask)
+    mask.lower(shape((n,), jnp.bool_), shape((32,), jnp.int32),
+               shape((32,), jnp.bool_)).compile()
+
+
+# -- the latent-attention embedder's cell ---------------------------------------
+
+def _latent_model(one_chip):
+    from pathway_tpu.models import causal_moe_embedder as cme
+
+    return _cell_model(one_chip, "joyai", "vs-joyai-flash-bf16-marcodoc", cme)
+
+
+def test_the_latent_packed_forward_compiles_at_each_token_bucket_beside_the_index(
+        one_chip, no_persistent_cache):
+    """The fullest cell: 10.59 GB of weights (every one of 256 experts of 768
+    in four layers, the whole vocabulary).  Each launch that serves, the
+    arrays as ``ragged_chunk`` lays them out: its temporaries (eight routed
+    rows a token, 32 heads of 192 + 128 a token) have to fit beside the
+    weights and the three copies of the index's 0.54 GB that an apply holds."""
+    import numpy as np
+
+    from pathway_tpu.models import causal_moe_embedder as cme
+    from pathway_tpu.models.encoder import dispatch_dtype, ragged_chunk
+
+    _config, cfg, params = _latent_model(one_chip)
+    assert set(cfg.layer_types) == {"latent"} and len(cfg.token_buckets) == 4
+    model = cme.CausalMoeEmbedder(cfg, packed=True)
+    none = np.zeros(0, np.int64)
+    for tokens in cfg.token_buckets:
+        chunk = ragged_chunk(none, none, None, None, cfg.max_len,
+                             dispatch_dtype(cfg.vocab_size), cfg, tokens=tokens)
+        assert chunk.ids.shape == (tokens,) and chunk.dense_s is None
+        args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+                for a in (chunk.ids, chunk.pos, chunk.seg, chunk.starts)]
+        compiled = jax.jit(lambda p, *a: model.apply({"params": p}, *a)).lower(
+            params, *args).compile()
+        memory = compiled.memory_analysis()
+        assert memory.argument_size_in_bytes == pytest.approx(10.587e9, rel=0.01)  # bfloat16
+        assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+                + 3 * 0.537e9 < 15.75e9), tokens
+        assert "ragged-dot" in compiled.as_text()
+
+
+def test_the_index_searches_and_applies_this_cell_s_rows(one_chip, no_persistent_cache):
+    """65,536 slots of 2,048 float32 values beside 10.59 GB of weights: the
+    megakernel at the query batches the cell sends, and the undonated
+    scatter of a flush's rows (the matrix in, the matrix out)."""
+    from pathway_tpu.ops import fused_serving as fs
+    from pathway_tpu.ops import knn
+
+    config, _cfg, _params = _latent_model(one_chip)
+    n, d = config["index"]["capacity"], config["index"]["dim"]
+    assert (n, d) == (65_536, 2048)
+    shape = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one_chip)
+    block = fs.validate_serving_geometry(n, "cos")
+    fn = getattr(fs._pallas_fused_dense, "__wrapped__", fs._pallas_fused_dense)
+    for q_b, q_dtype in ((8, jnp.bfloat16), (32, jnp.float32)):
+        compiled = fn.lower(shape((q_b, d), q_dtype), shape((n, d), jnp.float32),
+                            shape((n,), jnp.bool_), k=16, q_b=q_b, metric="cos",
+                            normalize=True, qdt="f32", block_n=block, interpret=False).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+    scatter = getattr(knn._scatter_rows_dropping, "__wrapped__", knn._scatter_rows_dropping)
+    compiled = scatter.lower(shape((n, d), jnp.float32), shape((32,), jnp.int32),
+                             shape((32, d), jnp.float32), normalize=True).compile()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.output_size_in_bytes < 2 * 0.54e9
     mask = getattr(knn._scatter_mask, "__wrapped__", knn._scatter_mask)
     mask.lower(shape((n,), jnp.bool_), shape((32,), jnp.int32),
                shape((32,), jnp.bool_)).compile()
