@@ -24,6 +24,14 @@ known file.  The Python lister (``glob`` + ``os.stat`` + ``open``) finds the
 same files in one pass, emits them in the same order (removed, new,
 changed) and takes over where :meth:`_FsSubject._native_walk_args` says it
 must.
+
+In streaming mode the two passes keep their own cadences
+(:meth:`_FsSubject.run`): the listing loop on the connector's thread, the
+verify rounds on a thread beside it, each with a whole ``refresh_interval``
+of sleep after every pass of its own, so a new file waits for a listing and
+for no check of a known file.  ``_FsSubject._emit_lock`` makes a listing
+pass, and a round's compare-and-emit, one step each; a round's system calls
+run outside it.
 """
 
 from __future__ import annotations
@@ -131,6 +139,11 @@ class _FsSubject(ConnectorSubject):
         self._line_counts: dict[str, int] = {}
         # path -> (mtime, size, [row keys])
         self._seen: dict[str, tuple[float, int, list]] = {}
+        #: held over every step that reads or writes ``_seen`` and pushes
+        #: rows: a listing pass, a verify round's compare and emit.  So
+        #: ``_pending`` never holds half of one pass's batch when the other
+        #: commits, and ``_offsets_at_commit`` covers the committed batches
+        self._emit_lock = threading.Lock()
         self._native_args = self._native_walk_args()
 
     # offsets = the whole scan state: restoring it suppresses re-emission of
@@ -230,27 +243,6 @@ class _FsSubject(ConnectorSubject):
         removed = [p for p in known if p not in current]
         return removed, new + changed, [], None, len(current), len(current)
 
-    def _verify(self, core: Any, listed: list[str]) -> tuple[list, list, bool]:
-        """Pass 2: one ``fstatat`` of every path in ``listed``, compared
-        with the (mtime, size) ``_seen`` holds.  Returns ``(removed, todo,
-        entered)`` as :meth:`_list` does: what is no regular file any more
-        (a link whose target went, a file unlinked since the listing), the
-        changed files in path order, and whether one of the removed is a
-        directory now, which ``**`` enters."""
-        mtimes, sizes, kinds = core.stat_files(_nul_ended(listed), len(listed))
-        seen = self._seen
-        removed, todo, entered = [], [], False
-        for path, mtime, size, kind in zip(listed, mtimes, sizes, kinds):
-            if kind != core.REGULAR:
-                removed.append(path)
-                entered = entered or kind == core.DIRECTORY
-                continue
-            old = seen[path]
-            if old[0] != mtime or old[1] != size:
-                todo.append((path, mtime, size, old))
-        todo.sort()  # paths differ, so nothing else is compared
-        return removed, todo, entered
-
     def _metadata_of(self, path: str, mtime: float, size: int) -> dict | None:
         if not self.with_metadata:
             return None
@@ -323,12 +315,26 @@ class _FsSubject(ConnectorSubject):
         return self._scan_and_emit()[0]
 
     def _scan_and_emit(self) -> tuple[bool, dict]:
-        """One poll of the path: list it, emit and commit the new and the
-        removed files (the span ``connector.scan``); then, where the native
-        core listed and a known file is still there, compare every known
-        file by (mtime, size) and emit and commit the changed ones (the span
-        ``connector.verify``).  Returns ``(anything changed, the scan
+        """One poll of the path, both passes in turn on the calling thread:
+        :meth:`_listing_pass`, then, where the native core listed and a
+        known file is still there, :meth:`_verify_pass` over the files that
+        were known before the poll.  Returns ``(anything changed, the scan
         span's attrs)``."""
+        changed, attrs, core, listed = self._listing_pass()
+        if listed:
+            found, entered = self._verify_pass(core, listed)
+            changed = changed or found
+            if entered:
+                # a known name is a directory now: pass 1 took it for the file
+                # it was, so what `**` finds in it is listed by a poll without it
+                self._scan_and_emit()
+        return changed, attrs
+
+    def _listing_pass(self) -> tuple[bool, dict, Any, list[str]]:
+        """Pass 1 (the span ``connector.scan``): list the path, emit and
+        commit the new and the removed files, all under the emit lock.
+        Returns ``(anything changed, the span's attrs, the native core if
+        it listed, the known paths left for pass 2 to compare)``."""
         from ...internals.flight_recorder import span
         from ...internals.monitoring import (
             record_connector_files,
@@ -337,10 +343,10 @@ class _FsSubject(ConnectorSubject):
 
         label = self._metrics_label or f"{self._datasource_name}-0"
         clock = _time.perf_counter
-        known = list(self._seen)  # what pass 2 checks: the files known BEFORE this poll
-        with span("connector.scan", "connector", record=False) as scan:
+        with span("connector.scan", "connector", record=False) as scan, \
+                self._emit_lock:
             t_start = clock()
-            removed, todo, listed, core, entries, stats = self._list(known)
+            removed, todo, listed, core, entries, stats = self._list(list(self._seen))
             t_listed = clock()
             files = self._emit(removed, todo, core)
             changed = bool(removed or files)
@@ -357,25 +363,57 @@ class _FsSubject(ConnectorSubject):
                 scan.stage = "connector.scan"
         record_connector_scan(label, "native" if core is not None else "python")
         record_connector_files(label, "listing", files)
-        if listed:
-            # staged on every poll, so that its mean is known where nothing is
-            # ever modified; in the ring when it found something
-            with span("connector.verify", "connector", stage="connector.verify",
-                      record=False) as verify:
-                t_start = clock()
-                removed, todo, entered = self._verify(core, listed)
-                walk_ms = round((clock() - t_start) * 1e3, 3)
+        return changed, scan.attrs, core, listed
+
+    def _verify_pass(
+        self, core: Any, listed: list[str] | None = None
+    ) -> tuple[bool, bool]:
+        """Pass 2 (the span ``connector.verify``): one ``fstatat`` of every
+        path in ``listed`` (of every known file where it is ``None``),
+        outside the emit lock; then, under it, the comparison with the
+        (mtime, size) ``_seen`` holds, and the retraction, re-read and commit
+        of what changed, in path order, and of what is no regular file any
+        more (a link whose target went, a file unlinked since the listing).
+        An entry of ``_seen`` that a listing pass has taken or replaced
+        since the stats were asked for is left alone: that pass knows
+        better.  Returns ``(anything changed, one of the removed is a
+        directory now, which `**` enters)``."""
+        from ...internals.flight_recorder import span
+        from ...internals.monitoring import record_connector_files
+
+        label = self._metrics_label or f"{self._datasource_name}-0"
+        clock = _time.perf_counter
+        seen = self._seen
+        # staged on every pass, so that its mean is known where nothing is
+        # ever modified; in the ring when it found something
+        with span("connector.verify", "connector", stage="connector.verify",
+                  record=False) as verify:
+            t_start = clock()
+            with self._emit_lock:
+                snapshot = (list(seen.items()) if listed is None
+                            else [(path, seen[path]) for path in listed])
+            mtimes, sizes, kinds = core.stat_files(
+                _nul_ended([path for path, _ in snapshot]), len(snapshot)
+            )
+            walk_ms = round((clock() - t_start) * 1e3, 3)
+            removed, todo, entered = [], [], False
+            with self._emit_lock:
+                for (path, old), mtime, size, kind in zip(
+                        snapshot, mtimes, sizes, kinds):
+                    if seen.get(path) is not old:
+                        continue
+                    if kind != core.REGULAR:
+                        removed.append(path)
+                        entered = entered or kind == core.DIRECTORY
+                    elif old[0] != mtime or old[1] != size:
+                        todo.append((path, mtime, size, old))
+                todo.sort()  # paths differ, so nothing else is compared
                 files = self._emit(removed, todo, core)
-                verify.set(known=len(listed), changed=files,
-                           removed=len(removed), walk_ms=walk_ms)
-                verify.record = bool(removed or files)
-            record_connector_files(label, "verify", files)
-            changed = changed or verify.record
-            if entered:
-                # a known name is a directory now: pass 1 took it for the file
-                # it was, so what `**` finds in it is listed by a poll without it
-                self._scan_and_emit()
-        return changed, scan.attrs
+            verify.set(known=len(snapshot), changed=files,
+                       removed=len(removed), walk_ms=walk_ms)
+            verify.record = bool(removed or files)
+        record_connector_files(label, "verify", files)
+        return verify.record, entered
 
     def _emit(self, removed: list[str], todo: list[tuple], core: Any) -> int:
         """Retract the rows of ``removed``, read and emit ``todo`` (a changed
@@ -517,17 +555,75 @@ class _FsSubject(ConnectorSubject):
         return True
 
     def run(self) -> None:
+        """A synchronous poll, then (streaming) the listing loop: a whole
+        ``refresh_s`` of sleep, a listing pass, again.  Once a listing pass
+        leaves known files to compare, the verify rounds run on a thread
+        beside it (``<this thread's name>-verify``): a round, a whole
+        ``refresh_s`` of sleep, again.  That thread is this call's: it ends
+        before the call returns or raises, and a fault of a round is raised
+        here, where the supervisor sees it and starts both again."""
+        from ...internals.flight_recorder import observe_stage
+
+        began = _time.monotonic()
         self._scan_once()
         if self._mode == "static":
             return
+        # of this call alone: `relist` wakes the listing loop before its sleep
+        # is over (a round asks for a pass, or failed), `stop` ends the rounds
+        relist, stop, faults, verifier = threading.Event(), threading.Event(), [], None
+        try:
+            while not self._closed.is_set():
+                self._pause(relist)
+                relist.clear()
+                if faults:
+                    raise faults[0]
+                # the period a new file waits in: one listing pass's start
+                # to the next one's
+                now = _time.monotonic()
+                observe_stage("connector.period", (now - began) * 1e3)
+                began = now
+                _changed, _attrs, core, listed = self._listing_pass()
+                if listed and verifier is None:
+                    verifier = threading.Thread(
+                        target=self._verify_rounds,
+                        args=(core, relist, stop, faults),
+                        name=f"{threading.current_thread().name}-verify",
+                        daemon=True,
+                    )
+                    verifier.start()
+        finally:
+            stop.set()
+            if verifier is not None:
+                verifier.join()
+
+    def _verify_rounds(
+        self, core: Any, relist: threading.Event, stop: threading.Event,
+        faults: list,
+    ) -> None:
+        """The verify thread's body, until :meth:`run` sets ``stop``."""
+        from ...internals.flight_recorder import name_thread
+
+        name_thread(threading.current_thread().name)
+        try:
+            while not stop.is_set():
+                _, entered = self._verify_pass(core)
+                if entered:
+                    # a known name is a directory now: what `**` finds in it
+                    # is listed by a pass without it, which need not wait
+                    relist.set()
+                self._pause(stop)
+        except BaseException as exc:  # noqa: BLE001 - raised again by run()
+            faults.append(exc)
+            relist.set()
+
+    def _pause(self, wake: threading.Event) -> None:
+        """A pass's sleep: ``refresh_s``, whole, unless ``wake`` is set."""
         from ...internals.flight_recorder import span
 
-        while not self._closed.is_set():
-            # profiler only: "between two polls" is an answer an idle gap
-            # can get, and no news for the ring
-            with span("connector.sleep", "connector", record=False):
-                _time.sleep(self.refresh_s)
-            self._scan_once()
+        # profiler only: "between two passes" is an answer an idle gap
+        # can get, and no news for the ring
+        with span("connector.sleep", "connector", record=False):
+            wake.wait(self.refresh_s)
 
 
 def read(
@@ -552,16 +648,25 @@ def read(
     new/changed/deleted files; "static" reads once at build time.
 
     A poll of a directory is two passes in native code with the interpreter
-    lock released.  The first lists the directory, reads the files it did
-    not know and commits them with the deletions, so a new file waits for no
-    check of another file; the second compares every known file by
-    (mtime, size), on every poll, and commits the changed ones: a poll that
-    finds both commits twice.  The Python lister (``glob``) does the poll in
-    one pass and one commit when the native core did not load, when ``path``
-    is a single file or a glob, or when ``object_pattern`` holds a path
-    separator or a bracket expression; rows, keys and offsets are the same,
-    ``pathway_connector_scans_total{lister=}`` says which it was and
-    ``pathway_connector_files_total{found=}`` which pass found a file.
+    lock released, and in streaming mode each keeps its own cadence.  The
+    listing pass lists the directory, reads the files it did not know and
+    commits them with the deletions: new and removed files are found by the
+    listing pass, which begins ``refresh_interval`` after the last one
+    ended, so a new file waits for no check of another file.  The verify
+    round compares every known file by (mtime, size) and commits the changed
+    ones, a changed file's retraction and its new rows in one commit:
+    changed files are found by the verify round, which begins
+    ``refresh_interval`` after the last one ended, on a thread beside the
+    listing loop, never later than when the two took turns.  Each pass is
+    its own commit.  The first poll of a run, and a static read, make both
+    passes in turn.  The Python lister (``glob``) does the poll in one pass,
+    one commit and one thread when the native core did not load, when
+    ``path`` is a single file or a glob, or when ``object_pattern`` holds a
+    path separator or a bracket expression; rows, keys and offsets are the
+    same, ``pathway_connector_scans_total{lister=}`` says which it was,
+    ``pathway_connector_files_total{found=}`` which pass found a file, and
+    ``pathway_request_stage_ms{stage="connector.period"}`` how far apart two
+    listing passes began.
 
     ``append_only=True`` (plaintext/jsonlines): grown files emit only
     their new complete lines instead of retract + full re-read — linear

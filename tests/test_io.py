@@ -853,3 +853,440 @@ def test_fs_python_lister_is_taken_where_it_must(tmp_path, monkeypatch, path_of,
     twin._scan_and_emit()
     assert subject._seen == twin._seen
     assert changed is bool(subject._seen)
+
+
+# ---------------------------------------------------------------------------
+# run() in streaming mode: the two passes of a poll on their own cadences,
+# the listing loop on the connector's thread and the verify rounds on
+# ``<thread>-verify``.  The contract without a clock: the verify thread's
+# ``stat_files`` waits for a permit of the test's, so the test decides which
+# round runs when; every wait has a timeout of its own and none is a sleep.
+# ---------------------------------------------------------------------------
+
+from pathway_tpu.io.streaming import ConnectorSupervisor  # noqa: E402
+
+#: seconds a wait may last before its test fails; none lasts when the code is right
+WAIT = 30.0
+REFRESH_S = 0.01
+
+
+class _LiveCore:
+    """The native core under ``run()``: every call written down as ``(who,
+    name, n)``, ``who`` the pass whose thread made it.  While ``held``, a
+    ``stat_files`` on the verify thread waits for a permit: after it took
+    the stats (``stat_first``: what it hands back is old by then) or before
+    (it hands back what is there when it is let go)."""
+
+    def __init__(self, core, stat_first=True):
+        self._core, self.stat_first = core, stat_first
+        self.calls, self.cond = [], threading.Condition()
+        self.held, self.permits = True, threading.Semaphore(0)
+        self.fault = None
+
+    def __getattr__(self, name):
+        return getattr(self._core, name)
+
+    def note(self, name, n):
+        who = ("verify" if threading.current_thread().name.endswith("-verify")
+               else "listing")
+        with self.cond:
+            self.calls.append((who, name, n))
+            self.cond.notify_all()
+        return who
+
+    def count(self, *call):
+        """Calls so far that begin with ``call``."""
+        return sum(1 for c in self.calls if c[:len(call)] == call)
+
+    def list_dir(self, root, pattern, known, n_known):
+        self.note("list_dir", n_known)
+        return self._core.list_dir(root, pattern, known, n_known)
+
+    def read_files(self, paths):
+        self.note("read_files", len(paths))
+        return self._core.read_files(paths)
+
+    def stat_files(self, paths, n):
+        held = self.note("stat_files", n) == "verify" and self.held
+        if held and self.stat_first:
+            stats = self._core.stat_files(paths, n)
+        if held:
+            self.note("waiting", n)
+            assert self.permits.acquire(timeout=WAIT), "no permit for the round"
+        if self.fault is not None:
+            fault, self.fault = self.fault, None
+            raise fault
+        if not (held and self.stat_first):
+            stats = self._core.stat_files(paths, n)
+        return stats
+
+    def free(self):
+        """No round waits any more."""
+        self.held = False
+        self.permits.release(1000)
+
+
+class _Live:
+    """``run()`` of a streaming subject over ``d`` on a thread of its own."""
+
+    def __init__(self, d, monkeypatch, core, supervised=False, fmt="binary"):
+        self.d, self.core = str(d), core
+        self.subject = subject = _fs_subject(d, fmt, refresh_interval=REFRESH_S)
+        monkeypatch.setattr(fs_mod, "_native_core", core if core._core is not None else None)
+        pause = subject._pause
+
+        def pausing(wake):
+            pause(wake)
+            core.note("pause", "cut" if wake.is_set() else "whole")
+
+        subject._pause = pausing
+        self.commits = []  # (offsets, batches committed) after every commit
+        commit = subject.commit
+
+        def committing():
+            commit()
+            with subject._lock:
+                self.commits.append((subject._offsets_at_commit, len(subject._committed)))
+
+        subject.commit = committing
+        self.error = None
+        self.supervisor = ConnectorSupervisor(subject, "fs-live") if supervised else None
+        self.thread = threading.Thread(target=self._run, name="pw-conn-7", daemon=True)
+
+    def _run(self):
+        try:
+            (self.supervisor or self.subject).run()
+        except BaseException as exc:  # noqa: BLE001 - handed to the test
+            self.error = exc
+
+    def __enter__(self):
+        self.thread.start()
+        return self
+
+    def __exit__(self, *exc):
+        self.stop()
+
+    def stop(self):
+        self.core.free()
+        self.subject.close()
+        self.thread.join(WAIT)
+        assert not self.thread.is_alive(), "run() did not return"
+        assert [t.name for t in threading.enumerate()
+                if t.name.startswith("pw-conn-7")] == []
+
+    def wait(self, what, predicate):
+        with self.core.cond:
+            assert self.core.cond.wait_for(predicate, timeout=WAIT), (
+                f"never {what}: {self.core.calls[-12:]}")
+
+    def wait_round(self, k):
+        """The ``k``-th round (from 1) of the verify thread waits for its
+        permit: every round before it has ended."""
+        self.wait(f"round {k} waiting", lambda: self.core.count("verify", "waiting") >= k)
+
+    def batches(self):
+        """What was committed so far, batch by batch: ``(op, name, data)``."""
+        with self.subject._lock:
+            batches = list(self.subject._committed)
+        return [[(op, os.path.relpath(values[1].value["path"], self.d), values[0])
+                 for op, _key, values in batch] for batch in batches]
+
+    def events(self):
+        return [event for batch in self.batches() for event in batch]
+
+    def wait_event(self, event):
+        self.wait(f"committed {event}", lambda: event in self.events())
+
+    def on_disk(self):
+        out = []
+        for root, _dirs, files in os.walk(self.d):
+            for name in files:
+                path = os.path.join(root, name)
+                with builtins.open(path, "rb") as f:
+                    out.append((os.path.relpath(path, self.d), f.read()))
+        return sorted(out)
+
+
+def _net(events):
+    """The rows left by ``events``; a row is retracted only after it was
+    inserted, and is there once."""
+    rows = []
+    for op, name, data in events:
+        if op == "insert":
+            assert (name, data) not in rows, f"doubled row of {name}"
+            rows.append((name, data))
+        else:
+            rows.remove((name, data))  # ValueError: retracted what was not there
+    return sorted(rows)
+
+
+def _assert_cadence(calls):
+    """No sleep is shortened and none is skipped: between two passes of one
+    kind lies one whole ``refresh_interval`` of sleep of that pass's own."""
+    for who, head in (("listing", "list_dir"), ("verify", "stat_files")):
+        mine = [(name, n) for w, name, n in calls
+                if w == who and name in (head, "pause")]
+        if who == "listing":
+            # the first poll makes both passes in turn, then the loop sleeps
+            mine = [c for c in mine if c != ("stat_files", mine[0][1])]
+        passes = [i for i, (name, _n) in enumerate(mine) if name == head]
+        for a, b in zip(passes, passes[1:]):
+            assert mine[a + 1:b] == [("pause", "whole")], (who, mine[a:b + 1])
+
+
+def _edit_in_place(path, data: bytes):
+    """Other bytes of the same length in the same inode, 1 ms later."""
+    st = os.stat(path)
+    assert len(data) == st.st_size
+    with builtins.open(path, "r+b") as f:
+        f.write(data)
+    os.utime(path, ns=(st.st_atime_ns, st.st_mtime_ns + 1_000_000))
+
+
+def _watched(tmp_path, names=("a", "b", "c")):
+    d = tmp_path / "watched"
+    for name in names:
+        _put(f"{d}/{name}.txt", f"{name} one".encode())
+    return d
+
+
+@needs_native
+def test_fs_run_lists_new_files_while_a_verify_round_is_held(tmp_path, monkeypatch):
+    """(a) and (g): the listing loop does not wait for a round."""
+    d = _watched(tmp_path)
+    periods, verifies = _staged("connector.period"), _staged("connector.verify")
+    live = _Live(d, monkeypatch, _LiveCore(_built_core))
+    with live:
+        live.wait_round(1)
+        passes = live.core.count("listing", "list_dir")
+        _put(f"{d}/d.txt", b"d one")
+        live.wait_event(("insert", "d.txt", b"d one"))
+        _put(f"{d}/sub/e.txt", b"e one")
+        live.wait_event(("insert", "sub/e.txt", b"e one"))
+        live.wait("two more listing passes",
+                  lambda: live.core.count("listing", "list_dir") >= passes + 2)
+        # the round still holds its snapshot of three, and is the only one
+        assert [c for c in live.core.calls if c[1] in ("stat_files", "waiting")] == [
+            ("verify", "stat_files", 3), ("verify", "waiting", 3)]
+        live.core.permits.release()
+        live.wait_round(2)
+        assert [c for c in live.core.calls if c[0] == "verify" and c[1] != "pause"][2:] == [
+            ("verify", "stat_files", 5), ("verify", "waiting", 5)]
+    calls = live.core.calls
+    assert live.error is None
+    # the new files were one commit each, of a listing pass; nothing else moved
+    assert live.batches()[1:] == [[("insert", "d.txt", b"d one")],
+                                  [("insert", "sub/e.txt", b"e one")]]
+    assert _net(live.events()) == live.on_disk()
+    _assert_cadence(calls)
+    # one observation a listing pass after the first, one a round
+    assert _staged("connector.period") - periods == live.core.count("listing", "list_dir") - 1
+    assert _staged("connector.verify") - verifies == live.core.count("verify", "stat_files")
+    assert live.core.count("listing", "stat_files") == 0
+
+
+@needs_native
+@pytest.mark.parametrize("stat_first", [True, False], ids=["during_round", "before_stats"])
+def test_fs_run_emits_a_file_edited_in_place_once(tmp_path, monkeypatch, stat_first):
+    """(b): a file modified in place is found by the first round whose
+    stats are taken after the edit, once, delete and insert in one commit;
+    no listing pass touches it."""
+    d = _watched(tmp_path)
+    live = _Live(d, monkeypatch, _LiveCore(_built_core, stat_first))
+    with live:
+        live.wait_round(1)
+        _edit_in_place(f"{d}/b.txt", b"b two")
+        _put(f"{d}/d.txt", b"d one")
+        live.wait_event(("insert", "d.txt", b"d one"))  # a listing pass since the edit
+        assert ("insert", "b.txt", b"b two") not in live.events()
+        live.core.permits.release()
+        live.wait_round(2)  # round 1 is over
+        edited = [("delete", "b.txt", b"b one"), ("insert", "b.txt", b"b two")]
+        assert (edited in live.batches()) is (not stat_first)
+        live.core.permits.release()
+        live.wait_round(3)  # round 2 is over
+        assert edited in live.batches()
+        live.core.permits.release()
+        live.wait_round(4)
+    assert live.error is None
+    assert live.batches()[1:] == [[("insert", "d.txt", b"d one")], edited]
+    assert _net(live.events()) == live.on_disk()
+    _assert_cadence(live.core.calls)
+
+
+@needs_native
+@pytest.mark.parametrize("stat_first", [True, False], ids=["old_stats", "new_stats"])
+@pytest.mark.parametrize("back", [False, True], ids=["removed", "removed_and_back"])
+def test_fs_run_round_skips_what_the_listing_took(tmp_path, monkeypatch, back, stat_first):
+    """(c): a round's result is applied to the entries of ``_seen`` that its
+    snapshot held and no listing pass has touched since."""
+    d = _watched(tmp_path)
+    live = _Live(d, monkeypatch, _LiveCore(_built_core, stat_first))
+    with live:
+        live.wait_round(1)  # its snapshot holds b.txt
+        os.unlink(f"{d}/b.txt")
+        live.wait_event(("delete", "b.txt", b"b one"))
+        if back:
+            _put(f"{d}/b.txt", b"b two, longer")
+            live.wait_event(("insert", "b.txt", b"b two, longer"))
+        for k in (2, 3):
+            live.core.permits.release()
+            live.wait_round(k)
+    assert live.error is None  # no KeyError of the round
+    assert live.batches()[1:] == [[("delete", "b.txt", b"b one")]] + (
+        [[("insert", "b.txt", b"b two, longer")]] if back else [])
+    assert _net(live.events()) == live.on_disk()
+    seen = live.subject._seen
+    assert sorted(os.path.relpath(p, d) for p in seen) == [n for n, _ in live.on_disk()]
+    for path, (mtime, size, _keys) in seen.items():
+        st = os.stat(path)
+        assert (mtime, size) == (st.st_mtime, st.st_size)
+    _assert_cadence(live.core.calls)
+
+
+@needs_native
+def test_fs_run_round_finds_a_name_that_became_a_directory(tmp_path, monkeypatch):
+    """A known name that is a directory now is retracted by the round, and
+    the round asks the listing loop for a pass without it."""
+    d = _watched(tmp_path)
+    outside = tmp_path / "outside"
+    _put(f"{outside}/target.txt", b"t one")
+    _put(f"{outside}/tree/in.txt", b"i one")
+    os.symlink(f"{outside}/target.txt", f"{d}/link")
+    live = _Live(d, monkeypatch, _LiveCore(_built_core, stat_first=False))
+    with live:
+        live.wait_round(1)
+        os.unlink(f"{d}/link")
+        os.symlink(f"{outside}/tree", f"{d}/link")
+        live.core.permits.release()
+        live.wait_event(("insert", "link/in.txt", b"i one"))
+    assert live.error is None
+    assert live.batches()[1:] == [[("delete", "link", b"t one")],
+                                  [("insert", "link/in.txt", b"i one")]]
+    woken = live.core.calls.index(("listing", "pause", "cut"))
+    assert live.core.calls[woken + 1][:2] == ("listing", "list_dir")
+
+
+@needs_native
+def test_fs_run_offsets_of_every_commit_restore(tmp_path, monkeypatch):
+    """(d): whichever pass committed, ``_offsets_at_commit`` covers the
+    committed batches and nothing else: a fresh subject restored from it
+    emits, on the directory as it is now, what the batches lack."""
+    d = _watched(tmp_path)
+    live = _Live(d, monkeypatch, _LiveCore(_built_core, stat_first=False))
+    live.subject._record_offsets = True
+    with live:
+        live.wait_round(1)
+        _edit_in_place(f"{d}/a.txt", b"a two")
+        os.unlink(f"{d}/c.txt")
+        _put(f"{d}/d.txt", b"d one")
+        live.wait_event(("insert", "d.txt", b"d one"))
+        _edit_in_place(f"{d}/d.txt", b"d two")
+        _put(f"{d}/e.txt", b"e one")
+        live.wait_event(("insert", "e.txt", b"e one"))
+        for k in (2, 3):
+            live.core.permits.release()
+            live.wait_round(k)
+        os.unlink(f"{d}/e.txt")
+        live.wait_event(("delete", "e.txt", b"e one"))
+    assert live.error is None
+    batches = live.batches()
+    assert _net(live.events()) == live.on_disk()
+    commits = [c for c in live.commits if c[0] is not None]
+    assert sorted({n for _offsets, n in commits}) == list(range(1, len(batches) + 1))
+    monkeypatch.setattr(fs_mod, "_native_core", _built_core)
+    for offsets, n in commits:
+        restored = _fs_subject(d, "binary")
+        restored.seek(pickle.loads(pickle.dumps(offsets)))
+        restored._scan_once()
+        rest = [(op, os.path.relpath(values[1].value["path"], d), values[0])
+                for batch in restored._committed for op, _key, values in batch]
+        before = [event for batch in batches[:n] for event in batch]
+        assert _net(before + rest) == live.on_disk(), n
+        assert restored._scan_once() is False
+
+
+@needs_native
+def test_fs_run_fault_of_a_round_reaches_the_supervisor(tmp_path, monkeypatch):
+    """(e): a fault in ``stat_files`` on the verify thread is raised by
+    ``run()``; the supervisor registers it and starts ``run()`` again, whose
+    first poll and whose rounds verify again; ``close()`` ends both threads."""
+    from pathway_tpu.internals.errors import error_stats
+
+    monkeypatch.setenv("PATHWAY_CONNECTOR_BACKOFF_S", "0.01")
+    d = _watched(tmp_path)
+    live = _Live(d, monkeypatch, _LiveCore(_built_core), supervised=True)
+    errors = error_stats().get("connector", 0)
+    with live:
+        live.wait_round(1)
+        live.core.fault = OSError("stat_files failed")
+        live.core.permits.release()
+        # the restart: a poll of both passes on run()'s thread, then rounds again
+        live.wait("the first poll of the restart",
+                  lambda: live.core.count("listing", "stat_files") >= 1)
+        _edit_in_place(f"{d}/b.txt", b"b two")
+        live.wait_round(2)
+        live.core.permits.release()
+        live.wait_round(3)
+        assert live.supervisor.restarts == 1
+    assert live.error is None
+    assert error_stats()["connector"] == errors + 1
+    assert live.batches()[1:] == [[("delete", "b.txt", b"b one"), ("insert", "b.txt", b"b two")]]
+    assert _net(live.events()) == live.on_disk()
+
+
+def test_fs_run_python_lister_is_one_thread(tmp_path, monkeypatch):
+    """(f): without the native core a poll is one pass on run()'s thread."""
+    d = _watched(tmp_path)
+    core = _LiveCore(None)
+    live = _Live(d, monkeypatch, core)
+    assert fs_mod._native_core is None
+    with live:
+        live.wait("three polls", lambda: core.count("listing", "pause") >= 3)
+        _edit_in_place(f"{d}/b.txt", b"b two")
+        _put(f"{d}/d.txt", b"d one")
+        live.wait_event(("insert", "b.txt", b"b two"))
+        live.wait_event(("insert", "d.txt", b"d one"))
+        assert [t.name for t in threading.enumerate() if t.name.endswith("-verify")] == []
+    assert live.error is None
+    assert {c[:2] for c in core.calls} == {("listing", "pause")}
+    assert _net(live.events()) == live.on_disk()
+
+
+@needs_native
+def test_fs_run_both_passes_under_churn(tmp_path, monkeypatch):
+    """Both threads free, the interpreter switching between them as often as
+    it can, while the directory churns: every commit holds whole files, the
+    index ends at the directory, ``_seen`` too."""
+    import sys
+
+    d = _watched(tmp_path, names=[f"f{i:02d}" for i in range(24)])
+    core = _LiveCore(_built_core, stat_first=False)
+    core.free()
+    live = _Live(d, monkeypatch, core)
+    monkeypatch.setattr(live.subject, "refresh_s", 0.0005)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        with live:
+            for step in range(240):
+                name = f"{d}/f{step % 31:02d}.txt"
+                if step % 5 == 4 and os.path.exists(name):
+                    os.unlink(name)
+                elif step % 2 and os.path.exists(name):
+                    _edit_in_place(name, (b"%d " % step * 8)[:os.stat(name).st_size])
+                else:
+                    _put(name, f"f{step % 31:02d} put {step}".encode())
+            final = live.on_disk()
+            live.wait("two more rounds", lambda n=core.count("verify", "stat_files"):
+                      core.count("verify", "stat_files") >= n + 2)
+            live.wait("the directory in the index", lambda: _net(live.events()) == final)
+    finally:
+        sys.setswitchinterval(interval)
+    assert live.error is None
+    for batch in live.batches():  # a changed file's rows leave and come in one commit
+        for op, name, _data in batch:
+            if op == "delete":
+                others = [o for o, n, _ in batch if n == name]
+                assert others in (["delete"], ["delete", "insert"]), batch
+    assert sorted(os.path.relpath(p, d) for p in live.subject._seen) == [n for n, _ in final]
