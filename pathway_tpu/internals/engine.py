@@ -1606,13 +1606,17 @@ class Engine:
 
             set_current_local(logs)
         try:
-            from .flight_recorder import span
+            from .flight_recorder import current_batch_link, span
 
             # the flight recorder sees every flush even when the stats
             # monitor is off (the default server path): a slow operator
-            # window is dumpable from /v1/debug/traces with zero setup
+            # window is dumpable from /v1/debug/traces with zero setup,
+            # and a step that carries connector rows files its flushes
+            # under that batch's trace id
+            link = current_batch_link()
             with span(
-                f"flush:{node.name}", "engine", stage="engine.flush", t=time
+                f"flush:{node.name}", "engine", stage="engine.flush",
+                links=None if link is None else [link], t=time,
             ) as timed:
                 out = node.flush(time)
                 timed.set(rows=len(out))

@@ -11,7 +11,10 @@ buffer of spans from every plane:
 * HTTP requests + their per-stage child spans (``io/http/_server.py``
   tracing middleware + ``xpacks/llm/_scheduler.py``),
 * engine operator flushes (``internals/engine.py`` ``_flush_node``),
-* connector commits (``io/streaming.py``),
+* batches of connector rows: an engine timestamp that carries them is
+  traced like a request, under :func:`batch_trace_id`, in seven segments
+  from the connector's read to the index (``internals/monitoring.py``
+  ``FreshnessTracker``; ``?category=ingest``),
 * scheduler device ticks, breaker transitions, injected faults,
 * unified-runtime ticks (``pathway_tpu/runtime/executor.py``): one
   ``tick:runtime`` span per composed tick (category ``runtime``, attrs:
@@ -51,6 +54,7 @@ instead.
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import os
 import random
 import re
@@ -75,6 +79,9 @@ __all__ = [
     "batch_traces",
     "batch_stage",
     "current_trace_link",
+    "batch_trace_id",
+    "batch_link_scope",
+    "current_batch_link",
     "new_trace_id",
     "new_span_id",
     "parse_traceparent",
@@ -116,7 +123,10 @@ def new_trace_id() -> str:
 
 
 def new_span_id() -> str:
-    return os.urandom(8).hex()
+    # not os.urandom: a getrandom call costs some 10 us on the chip's
+    # gVisor host, and a batch of connector rows mints some thirty span
+    # ids (``random`` reseeds itself in a forked child)
+    return f"{random.getrandbits(64) or 1:016x}"
 
 
 def parse_traceparent(header: str | None) -> tuple[str, str] | None:
@@ -769,6 +779,43 @@ def current_trace_link() -> tuple[str, str] | None:
         if tr.sampled:
             return tr.trace_id, tr.span_id
     return None
+
+
+# -- batches of connector rows ----------------------------------------------
+# An engine timestamp that carries connector rows is traced like a request:
+# its trace id is derived from the engine and the timestamp, so the driver,
+# the engine's flushes, the index node and the tick runtime name it alike
+# without handing it around.  The link rides a context variable: the index
+# node's embed calls run on the persistent loop, and a coroutine scheduled
+# from the engine thread runs in a copy of that thread's context, so the
+# calls carry the link of the flush that made them to ``submit``.
+
+_M64 = (1 << 64) - 1
+_batch_link: contextvars.ContextVar[tuple[str, None] | None] = (
+    contextvars.ContextVar("pw_batch_link", default=None)
+)
+
+
+def batch_trace_id(scope: int, t: int) -> str:
+    """The trace id of engine timestamp ``t`` of engine ``scope``
+    (``id(engine)``, the freshness tracker's scope): 16 hex digits each."""
+    return f"{scope & _M64:016x}{t & _M64:016x}"
+
+
+@contextlib.contextmanager
+def batch_link_scope(link: tuple[str, None] | None) -> Iterator[None]:
+    """Scope: the batch whose work runs here (``(trace_id, None)``: spans
+    recorded under it are roots of the batch's trace), or None."""
+    token = _batch_link.set(link)
+    try:
+        yield
+    finally:
+        _batch_link.reset(token)
+
+
+def current_batch_link() -> tuple[str, None] | None:
+    """The link of the batch in scope in this context, or None."""
+    return _batch_link.get()
 
 
 @contextlib.contextmanager

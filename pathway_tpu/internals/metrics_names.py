@@ -117,8 +117,10 @@ METRICS: dict[str, tuple[str, str]] = {
         "stage latency: request stages (queue_wait / embed / search / serialize / "
         "total) and every flight_recorder.span(stage=...) of the ingest path "
         "(connector.scan, connector.verify, engine.flush, index.*, tick.*, embed.*) "
-        "and the observations without a span (ingest.read_to_indexed; "
-        "connector.period, from one listing pass's start to the next one's)",
+        "and the observations without a span (ingest.read_to_indexed and its "
+        "seven segments, ingest.read_to_commit ... ingest.embedded_to_indexed, "
+        "once per indexed engine timestamp and connector; connector.period, "
+        "from one listing pass's start to the next one's)",
     ),
     "pathway_flight_recorder_spans_total": (
         "counter",

@@ -25,6 +25,7 @@ from collections import defaultdict, deque
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Any
 
+from .flight_recorder import batch_trace_id
 from .metrics_names import Histogram, escape_label_value
 
 __all__ = [
@@ -359,6 +360,53 @@ class StatsMonitor:
 # ---------------------------------------------------------------------------
 
 
+#: the seven segments of a batch's way from the connector's read to the
+#: index, each from one milestone of :class:`_Batch` to the next; observed
+#: once per indexed timestamp and connector, so their means add up to
+#: ``ingest.read_to_indexed``'s (PERF.md 3)
+INGEST_SEGMENTS = (
+    "ingest.read_to_commit",
+    "ingest.commit_to_step",
+    "ingest.step_to_index",
+    "ingest.index_to_tick",
+    "ingest.tick",
+    "ingest.tick_to_embedded",
+    "ingest.embedded_to_indexed",
+)
+
+
+class _Batch:
+    """One engine timestamp's milestones, on the wall clock (``time.time()``,
+    the span primitive's ``start_s``): per connector its earliest read, the
+    commit that carried that read and the rows drained; then the driver's
+    ``engine.step`` begins, the index node's ``index.doc_data`` begins, the
+    first tick carrying its embed calls begins, the last one ends, and
+    ``index.doc_data`` ends.  ``note_indexed`` is the eighth."""
+
+    __slots__ = ("sources", "step", "index", "tick_start", "tick_end", "embedded")
+
+    def __init__(self) -> None:
+        #: connector label -> [read wall, commit wall, messages]
+        self.sources: dict[str, list] = {}
+        self.step: float | None = None
+        self.index: float | None = None
+        self.tick_start: float | None = None
+        self.tick_end: float | None = None
+        self.embedded: float | None = None
+
+    def milestones(self, read: float, commit: float | None, now: float) -> list:
+        """The eight milestones of one connector's rows.  A milestone not
+        stamped takes the next one's time (a timestamp whose rows rode no
+        tick reads 0 in ``ingest.tick`` and ``ingest.tick_to_embedded``),
+        and none lies after the next, so the segments add up to
+        ``now - read`` exactly."""
+        ms = [read, commit, self.step, self.index, self.tick_start,
+              self.tick_end, self.embedded, now]
+        for i in range(6, 0, -1):
+            ms[i] = ms[i + 1] if ms[i] is None else min(ms[i], ms[i + 1])
+        return ms
+
+
 class FreshnessTracker:
     """High-watermark plumbing for ``pathway_index_freshness_seconds``.
 
@@ -376,6 +424,12 @@ class FreshnessTracker:
     (threaded servers, test suites) would join engine B's ``t=5`` apply
     against engine A's hours-old ``t=5`` stamp and report phantom lag.
     Both sides pass ``id(engine)``.
+
+    A timestamp with connector read stamps is a traced batch
+    (:class:`_Batch`): the driver, the index node and the tick runtime stamp
+    its milestones, and :meth:`note_indexed` observes the seven
+    :data:`INGEST_SEGMENTS` between them and files them in the ring under
+    ``flight_recorder.batch_trace_id(scope, t)``.
     """
 
     MAX_PENDING = 4096
@@ -386,14 +440,15 @@ class FreshnessTracker:
         self._ingest_order: deque[tuple[int, int]] = deque()
         #: index name -> (last observed lag seconds, observed wall time)
         self._lag: dict[str, tuple[float, float]] = {}
-        #: (scope, engine_time) -> {connector label: earliest READ wall}
-        #: — the end-to-end half: connectors stamp when the row was READ
-        #: from the source (io/streaming.py ``_push``), not when the
-        #: driver pushed the batch, so the freshness SLO covers
-        #: parse→split→embed→upsert→commit including connector-side
-        #: batching delay
-        self._source_read: dict[tuple[int, int], dict[str, float]] = {}
-        self._source_order: deque[tuple[int, int]] = deque()
+        #: batch trace id (``flight_recorder.batch_trace_id(scope,
+        #: engine_time)``) -> its milestones, keyed by the earliest READ
+        #: wall per connector — the end-to-end half: connectors stamp
+        #: when the row was READ from the source (io/streaming.py
+        #: ``_push``), not when the driver pushed the batch, so the
+        #: freshness SLO covers parse→split→embed→upsert→commit including
+        #: connector-side batching delay
+        self._batches: dict[str, _Batch] = {}
+        self._source_order: deque[str] = deque()
         #: connector label -> (end-to-end lag seconds, observed wall)
         self._source_lag: dict[str, tuple[float, float]] = {}
         #: ``fn(index_name, engine_time, scope)`` callbacks fired on
@@ -434,17 +489,72 @@ class FreshnessTracker:
         ``engine_time`` — the start of the end-to-end freshness span
         (``pathway_freshness_seconds{connector=}``).  Earliest wins, as
         with :meth:`note_ingest`."""
-        key = (scope, engine_time)
+        key = batch_trace_id(scope, engine_time)
         with self._lock:
-            per_conn = self._source_read.get(key)
-            if per_conn is None:
-                per_conn = self._source_read[key] = {}
+            batch = self._batches.get(key)
+            if batch is None:
+                batch = self._batches[key] = _Batch()
                 self._source_order.append(key)
                 while len(self._source_order) > self.MAX_PENDING:
-                    self._source_read.pop(self._source_order.popleft(), None)
-            prev = per_conn.get(connector)
-            if prev is None or read_wall < prev:
-                per_conn[connector] = read_wall
+                    self._batches.pop(self._source_order.popleft(), None)
+            prev = batch.sources.get(connector)
+            if prev is None or read_wall < prev[0]:
+                batch.sources[connector] = [read_wall, None, 0]
+
+    def note_commit(
+        self,
+        connector: str,
+        engine_time: int,
+        commit_wall: float | None,
+        messages: int,
+        scope: int = 0,
+    ) -> None:
+        """Stamp the connector's ``commit()`` of the batch that holds its
+        earliest read of ``engine_time`` (and the rows drained)."""
+        with self._lock:
+            batch = self._batches.get(batch_trace_id(scope, engine_time))
+            source = batch.sources.get(connector) if batch else None
+            if source is not None:
+                source[1] = commit_wall
+                source[2] = messages
+
+    def _stamp(self, engine_time: int, scope: int, field: str, wall: float) -> bool:
+        """First stamp of a milestone wins; True when the timestamp is a
+        traced batch."""
+        with self._lock:
+            batch = self._batches.get(batch_trace_id(scope, engine_time))
+            if batch is None:
+                return False
+            if getattr(batch, field) is None:
+                setattr(batch, field, wall)
+            return True
+
+    def note_step(self, engine_time: int, wall: float, scope: int = 0) -> bool:
+        """The driver's ``engine.step(engine_time)`` begins."""
+        return self._stamp(engine_time, scope, "step", wall)
+
+    def note_index(self, engine_time: int, wall: float, scope: int = 0) -> bool:
+        """The index node's ``index.doc_data`` begins."""
+        return self._stamp(engine_time, scope, "index", wall)
+
+    def note_embedded(self, engine_time: int, wall: float, scope: int = 0) -> None:
+        """The index node's ``index.doc_data`` ends."""
+        self._stamp(engine_time, scope, "embedded", wall)
+
+    def note_tick(self, links, start: float, end: float) -> None:
+        """A tick carried work linked to ``links`` (``(trace_id, parent)``
+        pairs): stamp its start and end on the batches among them whose
+        index flush has begun.  Called before the tick's futures resolve,
+        so before the engine thread can close the batch."""
+        with self._lock:
+            for trace_id, _parent in links:
+                batch = self._batches.get(trace_id)
+                if batch is None or batch.index is None:
+                    continue
+                if batch.tick_start is None or start < batch.tick_start:
+                    batch.tick_start = start
+                if batch.tick_end is None or end > batch.tick_end:
+                    batch.tick_end = end
 
     def note_indexed(
         self, index_name: str, engine_time: int, scope: int = 0
@@ -457,7 +567,7 @@ class FreshnessTracker:
         observations and feed the freshness SLO burn windows."""
         now = time.time()
         lag: float | None = None
-        sources: dict[str, float] = {}
+        batch: _Batch | None = None
         with self._lock:
             wall = self._ingest_wall.get((scope, engine_time))
             if wall is not None:
@@ -471,10 +581,12 @@ class FreshnessTracker:
                 # flapping the gauge to whichever index flushed last.
                 # Per-index staleness stays on
                 # pathway_index_freshness_seconds{index=}.
-                sources = (
-                    self._source_read.pop((scope, engine_time), None) or {}
+                batch = self._batches.pop(
+                    batch_trace_id(scope, engine_time), None
                 )
-                for connector, read_wall in sources.items():
+                for connector, (read_wall, _c, _n) in (
+                    batch.sources.items() if batch else ()
+                ):
                     self._source_lag[connector] = (
                         max(0.0, now - read_wall), now,
                     )
@@ -491,24 +603,49 @@ class FreshnessTracker:
             return None
         # burn-rate treatment (observability/slo.py) — lazy and fail-open:
         # freshness accounting must never take down an index flush
-        if sources:
-            from .flight_recorder import observe_stage
-
-            for read_wall in sources.values():
-                # connector read -> queryable, per source: the part of a
-                # document's way the program sees from the inside
-                observe_stage(
-                    "ingest.read_to_indexed",
-                    max(0.0, now - read_wall) * 1000.0,
-                )
+        if batch is not None and batch.sources:
+            self._observe_batch(batch, engine_time, scope, now)
             try:
                 from ..observability import slo
 
-                for connector, read_wall in sources.items():
+                for connector, (read_wall, _c, _n) in batch.sources.items():
                     slo.observe_freshness(connector, max(0.0, now - read_wall))
             except Exception:  # noqa: BLE001
                 pass
         return lag
+
+    @staticmethod
+    def _observe_batch(batch: _Batch, engine_time: int, scope: int, now: float) -> None:
+        """Per connector: ``ingest.read_to_indexed`` (connector read ->
+        queryable, the part of a document's way the program sees from the
+        inside) and its seven segments, as stages and as ring records under
+        the batch's trace id (the whole way a root, the segments its
+        children)."""
+        from .flight_recorder import get_recorder, new_span_id, observe_stage
+
+        rec = get_recorder()
+        trace_id = batch_trace_id(scope, engine_time)
+        for connector, (read_wall, commit_wall, messages) in batch.sources.items():
+            observe_stage(
+                "ingest.read_to_indexed", max(0.0, now - read_wall) * 1000.0
+            )
+            ms = batch.milestones(read_wall, commit_wall, now)
+            for i, stage in enumerate(INGEST_SEGMENTS):
+                observe_stage(stage, (ms[i + 1] - ms[i]) * 1000.0)
+            if not rec.enabled:
+                continue
+            root = new_span_id()
+            attrs = {"t": engine_time, "connector": connector}
+            rec.record(
+                "ingest.read_to_indexed", "ingest", read_wall,
+                (now - read_wall) * 1000.0, trace_id, root, None, attrs,
+            )
+            for i, stage in enumerate(INGEST_SEGMENTS):
+                rec.record(
+                    stage, "ingest", ms[i], (ms[i + 1] - ms[i]) * 1000.0,
+                    trace_id, new_span_id(), root,
+                    {**attrs, "messages": messages} if i == 1 else attrs,
+                )
 
     def stats(self) -> dict[str, Any]:
         """Per-INDEX lag view (shape unchanged since PR 4 — consumers
@@ -562,7 +699,7 @@ class FreshnessTracker:
             self._ingest_wall.clear()
             self._ingest_order.clear()
             self._lag.clear()
-            self._source_read.clear()
+            self._batches.clear()
             self._source_order.clear()
             self._source_lag.clear()
 

@@ -133,13 +133,15 @@ class ConnectorSubject:
         self._record_offsets = False
         # end-to-end freshness stamps (pathway_freshness_seconds): the
         # wall clock of the FIRST row read into the current pending
-        # batch, carried through commit() and _drain() so the driver can
-        # hand the earliest read time of each engine timestamp to the
-        # freshness tracker — measuring from source READ, not from the
-        # driver push, covers connector-side batching delay too
+        # batch, paired at commit() with the commit's own, carried
+        # through _drain() so the driver can hand the earliest read time
+        # of each engine timestamp, and the commit that carried it, to
+        # the freshness tracker — measuring from source READ, not from
+        # the driver push, covers connector-side batching delay too
         self._pending_read_wall: float | None = None
-        self._committed_read_walls: list[float] = []
+        self._committed_read_walls: list[tuple[float, float]] = []
         self._read_wall_at_drain: float | None = None
+        self._commit_wall_at_drain: float | None = None
 
     # -- to be implemented by subclasses --
     def run(self) -> None:
@@ -210,7 +212,9 @@ class ConnectorSubject:
                 self._committed.append(self._pending)
                 self._pending = []
                 if self._pending_read_wall is not None:
-                    self._committed_read_walls.append(self._pending_read_wall)
+                    self._committed_read_walls.append(
+                        (self._pending_read_wall, _time.time())
+                    )
                     self._pending_read_wall = None
             # every connector updates its offsets before its own commit()
             # (fs: _seen per emitted file; kafka: per consumed message),
@@ -299,7 +303,9 @@ class ConnectorSubject:
             # earliest read time across the drained batches: the start of
             # the end-to-end freshness span for this engine timestamp
             walls, self._committed_read_walls = self._committed_read_walls, []
-            self._read_wall_at_drain = min(walls) if walls else None
+            self._read_wall_at_drain, self._commit_wall_at_drain = (
+                min(walls) if walls else (None, None)
+            )
         entries: list[Entry] = []
         for batch in batches:
             for op, key, values in batch:
@@ -1033,7 +1039,7 @@ class StreamingDriver:
             # while the run continues (reference: ConnectorMonitor finish)
             self._record_finished_connectors()
             if pushed:
-                self.engine.step(t)
+                self._step(t)
                 self._write_commit_record(t)
                 t += 1
                 continue
@@ -1046,7 +1052,7 @@ class StreamingDriver:
                 # the next input), or a tiered index migrated under pure
                 # query traffic (end_of_step must stage + persist the
                 # new placement — waiting for input could be forever)
-                self.engine.step(t)
+                self._step(t)
                 self._write_commit_record(t)
                 t += 1
                 continue
@@ -1060,10 +1066,28 @@ class StreamingDriver:
                         self._record_connector(subject, len(entries), t)
                         pushed = True
                 if pushed:
-                    self.engine.step(t)
+                    self._step(t)
                     self._write_commit_record(t)
                     t += 1
                 break
+
+    def _step(self, t: int) -> None:
+        """``engine.step(t)`` as a span on the profiler's clock (the ring
+        keeps the batch's segments instead), under the link of the batch of
+        connector rows it carries, so that its operators' flushes file
+        under the batch's trace id."""
+        from ..internals.flight_recorder import (
+            batch_link_scope, batch_trace_id, span,
+        )
+        from ..internals.monitoring import get_freshness
+
+        scope = id(self.engine)
+        with span("engine.step", "engine", record=False, t=t) as timed:
+            traced = get_freshness().note_step(t, timed.start_s, scope=scope)
+            with batch_link_scope(
+                (batch_trace_id(scope, t), None) if traced else None
+            ):
+                self.engine.step(t)
 
     def _write_snapshot(self, subject: ConnectorSubject, entries: list[Entry]) -> None:
         # OPERATOR_PERSISTING never registers writers: its offsets are
@@ -1087,17 +1111,9 @@ class StreamingDriver:
         monitor = getattr(self.engine, "monitor", None)
         if monitor is not None:
             monitor.record_connector_commit(label, n)
-        import time as _time_mod
-
-        from ..internals.flight_recorder import record_span
         from ..internals.monitoring import get_freshness
 
-        now = _time_mod.time()
-        # commit event into the flight recorder (works without a monitor)
-        record_span(
-            f"commit:{label}", "connector", now, 0.0,
-            attrs={"messages": n, "t": t},
-        )
+        now = _time.time()
         if t is not None:
             # freshness watermark: these rows entered at `now` under engine
             # timestamp `t`; when an index node applies timestamp `t` the
@@ -1111,8 +1127,14 @@ class StreamingDriver:
             # applies timestamp t (read→parse→split→embed→upsert→commit)
             read_wall = getattr(subject, "_read_wall_at_drain", None)
             if read_wall is not None:
-                get_freshness().note_source(
-                    label, t, read_wall, scope=id(self.engine)
+                # ...and the batch of them is traced from here to the
+                # index: the commit that carried the read and the rows
+                # drained open its ingest.commit_to_step segment
+                fresh = get_freshness()
+                fresh.note_source(label, t, read_wall, scope=id(self.engine))
+                fresh.note_commit(
+                    label, t, subject._commit_wall_at_drain, n,
+                    scope=id(self.engine),
                 )
             # fleet watermark hook: the subject learns the engine
             # timestamp its drained rows ride under, so the member can

@@ -269,6 +269,21 @@ class WorkItem:
         self.trace_link = trace_link
 
 
+def _note_batch_ticks(links, began: float | None, timed: Any) -> None:
+    """Stamp a tick's start and end on the batches of connector rows whose
+    embed calls it ran (``FreshnessTracker.note_tick``), BEFORE their
+    futures resolve: the engine thread closes a batch as soon as its rows
+    are embedded and applied."""
+    if links:
+        from ..internals.monitoring import get_freshness
+
+        get_freshness().note_tick(
+            links,
+            timed.start_s if began is None else began,
+            timed.start_s + timed.duration_ms / 1000.0,
+        )
+
+
 #: wait-time histogram bucket upper bounds (milliseconds)
 _WAIT_BUCKETS_MS = (1.0, 2.0, 5.0, 10.0, 25.0, 50.0, 100.0, 250.0, 1000.0)
 #: items-per-tick histogram buckets
@@ -426,14 +441,21 @@ class DeviceTickRuntime:
             sheddable = deadline_s is not None
         if trace is not None and not trace.sampled:
             trace = None
-        if defer and trace_link is None:
+        if trace_link is None:
             # deferred work submitted from inside a request's batch scope
             # (query-cache refresh, tier migration) would otherwise start
             # trace-orphaned — capture the triggering request's span now,
-            # while the scope still exists
-            from ..internals.flight_recorder import current_trace_link
+            # while the scope still exists.  The embed calls of an index
+            # flush carry the batch of connector rows they embed (a
+            # context variable: it reaches the persistent loop with them)
+            from ..internals.flight_recorder import (
+                current_batch_link,
+                current_trace_link,
+            )
 
-            trace_link = current_trace_link()
+            trace_link = (
+                current_trace_link() if defer else None
+            ) or current_batch_link()
         if tokens is None:
             estimate = getattr(group, "token_estimate", None)
             tokens = (estimate or estimate_tokens)(payload)
@@ -720,7 +742,7 @@ class DeviceTickRuntime:
                 )
         for group, gitems in live_groups.values():
             for chunk in budget_chunks(group, gitems):
-                self._execute(group, chunk, chunk[0].qos)
+                self._execute(group, chunk, chunk[0].qos, began=timed.start_s)
         timed.set(
             occupancy=len(items),
             tokens=live_tokens,
@@ -734,7 +756,10 @@ class DeviceTickRuntime:
         chunk: list[WorkItem],
         qos: QoS,
         inline: bool = False,
+        began: float | None = None,
     ) -> None:
+        """Run one chunk and resolve its futures.  ``began``: the wall
+        clock the tick began at (the chunk's own start when inline)."""
         if not chunk:
             return
         from ..internals.flight_recorder import batch_traces, span
@@ -766,7 +791,9 @@ class DeviceTickRuntime:
         )
         if inline:
             timed.set(inline=True)
-        if links:
+        if any(parent is not None for _trace, parent in links):
+            # a request's deferred work links to the span that caused it;
+            # a batch of connector rows links to its trace alone
             timed.set(deferred=True)
         prev_qos = self._tick_qos
         self._tick_qos = qos
@@ -798,6 +825,7 @@ class DeviceTickRuntime:
                         f"{len(results)} results for {len(chunk)} items"
                     )
         except BaseException as exc:  # noqa: BLE001 — propagate to every waiter
+            _note_batch_ticks(links, began, timed)
             with self._mx:
                 self._class_counters[qos]["failed_total"] += len(chunk)
             if obs is not None:
@@ -808,6 +836,7 @@ class DeviceTickRuntime:
             return
         finally:
             self._tick_qos = prev_qos
+        _note_batch_ticks(links, began, timed)
         with self._mx:
             self._class_counters[qos]["completed_total"] += len(chunk)
         if obs is not None:

@@ -135,17 +135,35 @@ class ExternalIndexNode(Node):
         updates = self.take(0)
         if updates:
             index_changed = True
-            from ...internals.flight_recorder import span
+            from ...internals.flight_recorder import (
+                batch_link_scope, batch_trace_id, span,
+            )
+            from ...internals.monitoring import get_freshness
 
+            fresh = get_freshness()
+            scope = getattr(self, "_freshness_scope", 0)
             # the index data expression is evaluated here: for a vector
             # index that is the embedder, whose calls for every row of the
             # flush are pending in the tick runtime before any is awaited,
-            # so that the flush rides one device tick inside this span
+            # so that the flush rides one device tick inside this span.
+            # A timestamp that carries connector rows is a traced batch:
+            # its embed calls carry the batch's link to the tick runtime,
+            # which stamps the ticks that run them on it
             with span(
                 "index.doc_data", "index", stage="index.doc_data",
                 rows=len(updates),
-            ):
-                self._collect_updates(updates, last, payloads)
+            ) as timed:
+                traced = fresh.note_index(time, timed.start_s, scope=scope)
+                link = (batch_trace_id(scope, time), None) if traced else None
+                if link is not None:
+                    timed.links = [link]
+                with batch_link_scope(link):
+                    self._collect_updates(updates, last, payloads)
+            if traced:
+                fresh.note_embedded(
+                    time, timed.start_s + timed.duration_ms / 1000.0,
+                    scope=scope,
+                )
         add_keys = [k for k, v in last.items() if v is not None]
         # the corpus visible to queries changes only when something real
         # applies: an upsert, or a remove of a key actually present.
@@ -200,8 +218,6 @@ class ExternalIndexNode(Node):
             # are queryable from here on (updates-before-queries), closing
             # the ingest->queryable loop the driver opened when it stamped
             # this timestamp (pathway_index_freshness_seconds{index=...})
-            from ...internals.monitoring import get_freshness
-
             get_freshness().note_indexed(
                 self.name, time, scope=getattr(self, "_freshness_scope", 0)
             )

@@ -21,6 +21,7 @@ from pathway_tpu.internals.metrics_names import (
     escape_label_value,
 )
 from pathway_tpu.internals.monitoring import (
+    INGEST_SEGMENTS,
     StatsMonitor,
     get_freshness,
     start_http_server_thread,
@@ -369,6 +370,9 @@ def test_request_trace_end_to_end(corpus_dir):
         "pathway_index_freshness_seconds{index=",
         "pathway_xla_compile_total{site=",
         "pathway_flight_recorder_spans_total",
+        # the served ingest's batches, in seven segments (ISSUE 38)
+        *(f'pathway_request_stage_ms_count{{stage="{stage}"}}'
+          for stage in INGEST_SEGMENTS),
     ):
         assert needle in status, f"missing on /status: {needle}"
 
