@@ -103,6 +103,7 @@ __all__ = [
     "ingest_stats",
     "record_moe_launch",
     "mla_stats",
+    "conv_stats",
     "moe_stats",
     "record_ssm_launch",
     "ssm_stats",
@@ -1047,7 +1048,7 @@ def ingest_stats() -> dict[str, Any]:
 
 # ---------------------------------------------------------------------------
 # launch counters of a language-model embedder (pathway_moe_*, pathway_mla_*,
-# pathway_ssm_*):
+# pathway_conv_*, pathway_ssm_*):
 # computed on the device by the forward, they come back with its result and
 # are added up here once the launch has finished: recording one never waits
 # for the device
@@ -1090,6 +1091,7 @@ _MOE_NAMES = ("launches_total", "routed_tokens_total", "experts_touched_total",
               "max_expert_tokens_sum", "max_expert_tokens")
 _MLA_NAMES = ("launches_total", "documents_total", "tokens_total", "bucket_tokens_total",
               "attention_pairs_total")
+_CONV_NAMES = _MLA_NAMES[:4]
 
 
 def _add_moe(totals: dict, values: list) -> None:
@@ -1099,9 +1101,13 @@ def _add_moe(totals: dict, values: list) -> None:
     totals["experts_touched_total"] += touched
     totals["max_expert_tokens_sum"] += fullest_sum
     totals["max_expert_tokens"] = fullest
-    if len(values) > 4:  # a forward with latent attention carries four more: pathway_mla_*
-        for name, value in zip(_MLA_NAMES, [1] + values[4:]):
-            totals["mla_" + name] += value
+    # a forward with latent attention carries four more (pathway_mla_*), one
+    # with conv layers three (pathway_conv_*)
+    held = values[4:]
+    if held:
+        family = "mla_" if len(held) == len(_MLA_NAMES) - 1 else "conv_"
+        for name, value in zip(_MLA_NAMES, [1] + held):
+            totals[family + name] += value
 
 
 def _add_ssm(totals: dict, values: list) -> None:
@@ -1110,7 +1116,8 @@ def _add_ssm(totals: dict, values: list) -> None:
 
 
 _moe_launches = _LaunchCounters(
-    _MOE_NAMES + tuple("mla_" + name for name in _MLA_NAMES), _add_moe)
+    _MOE_NAMES + tuple("mla_" + name for name in _MLA_NAMES)
+    + tuple("conv_" + name for name in _CONV_NAMES), _add_moe)
 _ssm_launches = _LaunchCounters(
     ("launches_total", "documents_total", "tokens_total", "bucket_tokens_total"),
     _add_ssm)
@@ -1123,8 +1130,10 @@ def record_moe_launch(counters: Any) -> None:
     layers), each layer's fullest expert summed, and the fullest of all;
     from a forward with latent attention four more behind them (documents,
     real tokens, the tokens of its bucket, the (query, key) pairs its causal
-    mask let through: :func:`mla_stats`).  Launches that have finished are
-    added up; this one waits in line until a later call or :func:`moe_stats`."""
+    mask let through: :func:`mla_stats`), from one with conv layers the
+    first three of those (:func:`conv_stats`).  Launches that have finished
+    are added up; this one waits in line until a later call or
+    :func:`moe_stats`."""
     _moe_launches.record(counters)
 
 
@@ -1142,6 +1151,14 @@ def mla_stats(wait: bool = True) -> dict[str, int]:
     takes); ``wait`` as :func:`moe_stats`."""
     totals = _moe_launches.stats(wait)
     return {name: totals["mla_" + name] for name in _MLA_NAMES}
+
+
+def conv_stats(wait: bool = True) -> dict[str, int]:
+    """The ``pathway_conv_*`` counters over every launch of a forward with
+    conv layers so far (they ride the array :func:`record_moe_launch`
+    takes); ``wait`` as :func:`moe_stats`."""
+    totals = _moe_launches.stats(wait)
+    return {name: totals["conv_" + name] for name in _CONV_NAMES}
 
 
 def record_ssm_launch(counters: Any) -> None:
@@ -1264,7 +1281,7 @@ def observability_metrics_lines() -> list[str]:
         f"{ing['intra_bucket_efficiency']:.4f}"
     )
     for family, totals in (("moe", moe_stats(wait=False)), ("mla", mla_stats(wait=False)),
-                           ("ssm", ssm_stats(wait=False))):
+                           ("conv", conv_stats(wait=False)), ("ssm", ssm_stats(wait=False))):
         if totals["launches_total"]:
             for name, value in totals.items():
                 kind = "counter" if name.endswith(("_total", "_sum")) else "gauge"

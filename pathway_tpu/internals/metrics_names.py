@@ -266,6 +266,21 @@ METRICS: dict[str, tuple[str, str]] = {
         "counter", "(query, key) pairs the causal mask let through, L(L+1)/2 a document, "
                    "counted once a launch",
     ),
+    # launch counters of a forward with conv layers (the gated short
+    # convolution; models/causal_moe_embedder.py _counters; they ride the
+    # array flight_recorder.record_moe_launch adds up)
+    "pathway_conv_launches_total": (
+        "counter", "launches of a forward with conv layers",
+    ),
+    "pathway_conv_documents_total": (
+        "counter", "documents (rows that hold a token) those launches carried",
+    ),
+    "pathway_conv_tokens_total": (
+        "counter", "real tokens those launches carried",
+    ),
+    "pathway_conv_bucket_tokens_total": (
+        "counter", "tokens of those launches' buckets, padding included",
+    ),
     # launch counters of a forward with state-space layers
     # (models/causal_hybrid_embedder.py, added up by
     # flight_recorder.record_ssm_launch)

@@ -1,6 +1,7 @@
 """A causal language model as a sentence embedder: window, full and latent
-attention, grouped queries, rotary positions, routed and shared experts, the
-final-norm state of the last token as the vector.
+attention, gated short convolutions, grouped queries, rotary positions,
+routed and shared experts, the final-norm state of the last token as the
+vector.
 
 The forward of a decoder-only model with nothing generated (no output head,
 no cache), the way language-model embedders are deployed (E5-Mistral,
@@ -13,17 +14,20 @@ Layer ``l`` of kind ``"full"`` or ``"window"`` (``x`` [T, D]; ``H_l`` query
 heads, ``KV`` key/value heads of size ``hd``; no bias; RMS norms):
 
 1. ``a = rmsnorm(x)``; ``q = a Wq`` [T, H_l, hd], ``k = a Wk``, ``v = a Wv``
-   [T, KV, hd].
+   [T, KV, hd]; with ``qk_norm`` each head of ``q`` and of ``k`` is
+   RMS-normed over its ``hd`` values (a scale of ``hd`` each).
 2. rotary on the first ``rotary_factor * hd`` dimensions of ``q`` and ``k``
    (half-split pairing), plain or YaRN, by the layer's kind.
 3. query head ``h`` reads KV head ``h // (H_l / KV)``; causal scores
    ``q_i . k_j / sqrt(hd)``, on window layers only ``i - j < window``;
    softmax in float32.
-4. ``g = sigmoid(a Wg)`` [T, H_l]; ``x += concat_h(g_h o_h) Wo``.
+4. with ``attention_gate``: ``g = sigmoid(a Wg)`` [T, H_l],
+   ``x += concat_h(g_h o_h) Wo``; without: ``x += concat_h(o_h) Wo``.
 5. ``b = rmsnorm(x)``; dense layers: ``x += (silu(b Wg) * (b Wu)) Wd``; sparse
    layers: ``x += routed_experts(b) + shared_expert(b)``
    (:mod:`pathway_tpu.ops.routed_experts`; ``router_scoring`` picks the
-   softmax router or the sigmoid one with its selection bias).
+   softmax router or the sigmoid one with its selection bias; no shared
+   expert where ``shared_expert_dim`` is 0).
 6. after the last layer ``rmsnorm``; a row's vector is the state of its last
    real token (the index normalises it).
 
@@ -41,10 +45,18 @@ latent itself) costs ``kv_lora_rank + qk_rope_dim + kv_lora_rank`` (576 +
 512).  The absorbed form pays where a cache of latents is read back; an
 embedder keeps no cache, so there is nothing it would save.
 
+A layer of kind ``"conv"`` (the gated short convolution of LFM2,
+:func:`_conv_mixer`) replaces steps 1-4 with ``[B | C | h] = a W_in`` (three
+blocks of D, in that order), ``u = B * h``, ``v`` = the causal depthwise
+convolution of ``u`` over ``conv_taps`` tokens with no bias
+(:func:`pathway_tpu.ops.ssd_scan.causal_conv`: a tap that would reach into
+the document before is dropped, so a document convolves on a packed axis as
+it does alone), ``x += (C * v) W_out``.
+
 Precision: weights are held in ``param_dtype`` (bfloat16) and products take
 ``dtype`` (bfloat16) operands with float32 accumulation; the residual
-stream, norms, rotary tables, softmax, the router and the combine are
-float32.
+stream, norms, rotary tables, softmax, the router, the combine and the
+convolution's gates and taps are float32.
 
 Attention is XLA over blocks of ``q_block`` queries.  A query block visits
 the key blocks from the first that holds a token some query of it may see
@@ -84,6 +96,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.routed_experts import launch_counters, routed_experts
+from ..ops.ssd_scan import causal_conv
 
 __all__ = ["RotarySpec", "CausalMoeEmbedderConfig", "CausalMoeEmbedder",
            "rotary_inv_freq", "init_params", "count_params"]
@@ -118,8 +131,8 @@ class CausalMoeEmbedderConfig:
     hidden_dim: int = 2048
     head_dim: int = 128
     num_kv_heads: int = 8
-    #: per layer: "full", "window" or "latent"; its query heads; "dense" or
-    #: "sparse"
+    #: per layer: "full", "window", "latent" or "conv"; its query heads
+    #: (read by the attention kinds alone); "dense" or "sparse"
     layer_types: tuple[str, ...] = ("full", "window", "window", "window", "full")
     heads_per_layer: tuple[int, ...] = (48, 64, 64, 64, 48)
     mlp_types: tuple[str, ...] = ("dense", "sparse", "sparse", "sparse", "sparse")
@@ -138,15 +151,24 @@ class CausalMoeEmbedderConfig:
     latent_rope_dim: int = 64
     latent_v_dim: int = 128
     latent_rotary: RotarySpec = RotarySpec(theta=32_000_000.0, interleaved=True)
+    #: grouped-query layers: per-head RMS norms of q and k before rotary;
+    #: the per-head sigmoid gate on the attention's output
+    qk_norm: bool = False
+    attention_gate: bool = True
+    #: conv layers: tokens the causal depthwise convolution reaches
+    conv_taps: int = 3
     dense_mlp_dim: int = 8192
     num_experts: int = 256
     top_k: int = 8
     expert_dim: int = 512
+    #: 0: the sparse layers hold no shared expert
     shared_expert_dim: int = 512
     routed_scaling: float = 2.5
     #: "softmax", or "sigmoid": the sparse layers then hold a per-expert
     #: ``bias`` that enters the choice of experts and not their weights
     router_scoring: str = "softmax"
+    #: the sigmoid router's weights are divided by their sum + this
+    router_eps: float = 1e-20
     rms_eps: float = 1e-6
     #: longest row the dispatch takes; rotary positions need no table
     max_len: int = 2048
@@ -194,10 +216,10 @@ class CausalMoeEmbedderConfig:
         if not (len(self.heads_per_layer) == len(self.mlp_types) == n):
             raise ValueError("layer_types, heads_per_layer and mlp_types "
                              "must name the same layers")
-        if not set(self.layer_types) <= {"full", "window", "latent"}:
+        if not set(self.layer_types) <= {"full", "window", "latent", "conv"}:
             raise ValueError(f"layer_types {self.layer_types}")
         if any(h % self.num_kv_heads for kind, h in zip(self.layer_types, self.heads_per_layer)
-               if kind != "latent"):
+               if kind in ("full", "window")):
             raise ValueError("a grouped-query layer's query heads must be a multiple "
                              f"of num_kv_heads={self.num_kv_heads}")
         if "latent" in self.layer_types and (self.latent_rope_dim % 2 or min(
@@ -207,6 +229,8 @@ class CausalMoeEmbedderConfig:
                              "the rotary part even")
         if self.router_scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"router_scoring {self.router_scoring!r}")
+        if "conv" in self.layer_types and self.conv_taps < 1:
+            raise ValueError(f"conv_taps {self.conv_taps}")
 
     @property
     def num_layers(self) -> int:
@@ -345,9 +369,12 @@ def _gated_mlp(x, w_gate_up, w_down, gate_scale: float = 1.0):
 
 def _attend_row(cfg: CausalMoeEmbedderConfig, i: int, p, a, pos, seg, valid):
     """Steps 1-4 of layer ``i`` for one token axis: ``a`` [T, D] the normed
-    input (float32) -> the attention's addition to the residual, float32."""
+    input (float32) -> the attention's (or the convolution's) addition to
+    the residual, float32."""
     if cfg.layer_types[i] == "latent":
         return _attend_latent(cfg, p, a, pos, seg, valid)
+    if cfg.layer_types[i] == "conv":
+        return _conv_mixer(cfg, p, a, pos)
     dt = cfg.dtype
     full = cfg.layer_types[i] == "full"
     spec = cfg.full_rotary if full else cfg.window_rotary
@@ -355,12 +382,26 @@ def _attend_row(cfg: CausalMoeEmbedderConfig, i: int, p, a, pos, seg, valid):
     q = jnp.einsum("td,dhe->the", ad, p["wq"], preferred_element_type=jnp.float32)
     k = jnp.einsum("td,dhe->the", ad, p["wk"], preferred_element_type=jnp.float32)
     v = jnp.einsum("td,dhe->the", ad, p["wv"], preferred_element_type=jnp.float32)
+    if cfg.qk_norm:
+        q, k = _rms_norm(q, p["q_norm"], cfg.rms_eps), _rms_norm(k, p["k_norm"], cfg.rms_eps)
     q, k = _rotate(q, pos, spec), _rotate(k, pos, spec)
     o = _attention(q.astype(dt), k.astype(dt), v.astype(dt), pos, seg, valid,
                    window=None if full else cfg.window, q_block=cfg.q_block)
-    gate = jax.nn.sigmoid(jnp.dot(ad, p["wg"], preferred_element_type=jnp.float32))
-    o = (o * gate[:, :, None]).astype(dt)
-    return jnp.einsum("the,hed->td", o, p["wo"], preferred_element_type=jnp.float32)
+    if cfg.attention_gate:
+        o = o * jax.nn.sigmoid(
+            jnp.dot(ad, p["wg"], preferred_element_type=jnp.float32))[:, :, None]
+    return jnp.einsum("the,hed->td", o.astype(dt), p["wo"], preferred_element_type=jnp.float32)
+
+
+def _conv_mixer(cfg: CausalMoeEmbedderConfig, p, a, pos):
+    """A conv layer's mixer for one token axis: ``[B | C | h] = a W_in``,
+    ``(C * causal_conv(B * h)) W_out``; the gates and taps float32, the
+    taps dropped across a document's start by ``pos``."""
+    f32 = dict(preferred_element_type=jnp.float32)
+    bch = jnp.dot(a.astype(cfg.dtype), p["w_in"], **f32)
+    d = bch.shape[-1] // 3
+    v = causal_conv(bch[:, :d] * bch[:, 2 * d:], p["conv"], None, pos)
+    return jnp.dot((bch[:, d: 2 * d] * v).astype(cfg.dtype), p["w_out"], **f32)
 
 
 def _attend_latent(cfg: CausalMoeEmbedderConfig, p, a, pos, seg, valid):
@@ -412,9 +453,11 @@ def _layer(cfg: CausalMoeEmbedderConfig, i: int, p, x, pos, seg, valid):
         bd.reshape(flat + bd.shape[2:]), valid.reshape(flat), m["router"],
         m["w_gate_up"], m["w_down"], top_k=cfg.top_k, scaling=cfg.routed_scaling,
         router_input=b.reshape(flat + b.shape[2:]), scoring=cfg.router_scoring,
-        bias=m.get("bias"))
-    shared = _gated_mlp(bd, m["shared"]["w_gate_up"], m["shared"]["w_down"])
-    return x + routed.reshape(x.shape) + shared, group_sizes
+        bias=m.get("bias"), eps=cfg.router_eps)
+    x = x + routed.reshape(x.shape)
+    if "shared" in m:
+        x = x + _gated_mlp(bd, m["shared"]["w_gate_up"], m["shared"]["w_down"])
+    return x, group_sizes
 
 
 def _tokens_forward(cfg, params, ids, pos, seg, valid):
@@ -435,14 +478,15 @@ def _counters(cfg, sizes: list, lengths, valid):
     and behind them, from a model with latent layers, its documents, real
     tokens, the tokens of its bucket and the (query, key) pairs the causal
     mask lets through (``L (L + 1) / 2`` a document of ``L`` tokens; once a
-    launch, not once a layer).  ``flight_recorder.record_moe_launch`` adds
-    either up."""
+    launch, not once a layer); from a model with conv layers the first
+    three of those.  ``flight_recorder.record_moe_launch`` adds them up."""
     moe = launch_counters(sizes) if sizes else jnp.zeros((4,), jnp.int32)
-    if "latent" not in cfg.layer_types:
+    held = [jnp.sum(lengths > 0), jnp.sum(lengths), jnp.int32(valid.size)]
+    if "latent" in cfg.layer_types:
+        held.append(jnp.sum(lengths * (lengths + 1) // 2))
+    elif "conv" not in cfg.layer_types:
         return moe
-    return jnp.concatenate([moe, jnp.stack([
-        jnp.sum(lengths > 0), jnp.sum(lengths), jnp.int32(valid.size),
-        jnp.sum(lengths * (lengths + 1) // 2)]).astype(jnp.int32)])
+    return jnp.concatenate([moe, jnp.stack(held).astype(jnp.int32)])
 
 
 class CausalMoeEmbedder:
@@ -537,7 +581,13 @@ def init_params(cfg: CausalMoeEmbedderConfig, key):
         h = cfg.heads_per_layer[i]
         k = jax.random.split(keys[i + 1], 8)
         layer = {"attn_norm": jnp.ones((d,), pd), "mlp_norm": jnp.ones((d,), pd)}
-        if cfg.layer_types[i] == "latent":
+        if cfg.layer_types[i] == "conv":
+            layer.update({
+                "w_in": normal(k[0], (d, 3 * d), d),
+                "conv": normal(k[1], (cfg.conv_taps, d), cfg.conv_taps),
+                "w_out": normal(k[2], (d, d), d),
+            })
+        elif cfg.layer_types[i] == "latent":
             qr, kvr, nope = cfg.latent_q_rank, cfg.latent_kv_rank, cfg.latent_nope_dim
             rope, vd = cfg.latent_rope_dim, cfg.latent_v_dim
             layer.update({
@@ -550,17 +600,21 @@ def init_params(cfg: CausalMoeEmbedderConfig, key):
         else:
             layer.update({
                 "wq": normal(k[0], (d, h, hd), d), "wk": normal(k[1], (d, kv, hd), d),
-                "wv": normal(k[2], (d, kv, hd), d), "wg": normal(k[3], (d, h), d),
-                "wo": normal(k[4], (h, hd, d), h * hd),
+                "wv": normal(k[2], (d, kv, hd), d), "wo": normal(k[4], (h, hd, d), h * hd),
             })
+            if cfg.attention_gate:
+                layer["wg"] = normal(k[3], (d, h), d)
+            if cfg.qk_norm:
+                layer["q_norm"], layer["k_norm"] = jnp.ones((hd,), pd), jnp.ones((hd,), pd)
         if cfg.mlp_types[i] == "dense":
             layer["mlp"] = mlp(k[5], (), cfg.dense_mlp_dim)
         else:
             layer["moe"] = {
                 "router": normal(k[5], (d, cfg.num_experts), d),
                 **mlp(k[6], (cfg.num_experts,), cfg.expert_dim),
-                "shared": mlp(k[7], (), cfg.shared_expert_dim),
             }
+            if cfg.shared_expert_dim:
+                layer["moe"]["shared"] = mlp(k[7], (), cfg.shared_expert_dim)
             if cfg.router_scoring == "sigmoid":  # float32, as the router reads it
                 layer["moe"]["bias"] = jnp.zeros((cfg.num_experts,), jnp.float32)
         params[f"layer_{i}"] = layer

@@ -34,7 +34,7 @@ __all__ = ["route", "group_tokens", "grouped_matmul", "routed_experts",
 
 
 def route(x, router, *, top_k: int, scaling: float, scoring: str = "softmax",
-          bias=None):
+          bias=None, eps: float = 1e-20):
     """``x`` [T, D] (any float dtype), ``router`` [D, E] -> the chosen
     experts [T, top_k] (int32, by falling score) and their weights
     [T, top_k] (float32), all in float32.
@@ -42,7 +42,7 @@ def route(x, router, *, top_k: int, scaling: float, scoring: str = "softmax",
     ``scoring="softmax"``: softmax over all E experts, the ``top_k``
     largest, divided by their sum, times ``scaling``.
     ``scoring="sigmoid"``: a sigmoid an expert; divided by the chosen's sum
-    + 1e-20 (as published), times ``scaling``.
+    + ``eps`` (1e-20 in the DeepSeek-V3 lineage), times ``scaling``.
     ``bias`` [E] (``e_score_correction_bias``) enters the CHOICE and not the
     weight: the ``top_k`` largest of score + bias are chosen (listed by
     falling biased score), and weighted by their scores without it.  The
@@ -63,7 +63,7 @@ def route(x, router, *, top_k: int, scaling: float, scoring: str = "softmax",
         _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
         top = jnp.take_along_axis(scores, experts, axis=-1)
     total = jnp.sum(top, axis=-1, keepdims=True)
-    return experts.astype(jnp.int32), top / (total if soft else total + 1e-20) * scaling
+    return experts.astype(jnp.int32), top / (total if soft else total + eps) * scaling
 
 
 def group_tokens(experts, valid, num_experts: int):
@@ -91,18 +91,20 @@ def grouped_matmul(lhs, rhs, group_sizes):
 
 def routed_experts(x, valid, router, w_gate_up, w_down, *, top_k: int,
                    scaling: float, router_input=None, scoring: str = "softmax",
-                   bias=None):
+                   bias=None, eps: float = 1e-20):
     """Sum over each token's experts of ``weight * E(x)`` with ``E`` a gated
     MLP (``silu(x Wg) * (x Wu)) Wd``.
 
     ``x`` [T, D] in the compute dtype, ``valid`` [T] bool, ``router``
     [D, E], ``w_gate_up`` [E, D, 2F] (gate columns first), ``w_down``
     [E, F, D]; ``router_input`` is what the router reads where ``x`` is a
-    rounded copy of it; ``scoring`` and ``bias`` [E] are :func:`route`'s.
+    rounded copy of it; ``scoring``, ``bias`` [E] and ``eps`` are
+    :func:`route`'s.
     Returns ([T, D] float32, group sizes [E])."""
     num_experts, _, two_f = w_gate_up.shape
     experts, weights = route(x if router_input is None else router_input, router,
-                             top_k=top_k, scaling=scaling, scoring=scoring, bias=bias)
+                             top_k=top_k, scaling=scaling, scoring=scoring, bias=bias,
+                             eps=eps)
     order, group_sizes, inverse = group_tokens(experts, valid, num_experts)
     rows = x[order // top_k]
     h = grouped_matmul(rows, w_gate_up, group_sizes)

@@ -67,14 +67,15 @@ _ROWS = 8
 def causal_conv(x, weight, bias, pos):
     """Causal depthwise convolution along the token axis: ``x`` [T, C],
     ``weight`` [K, C] (tap ``k`` multiplies ``x[t - (K - 1 - k)]``, as a
-    ``conv1d`` padded on the left by ``K - 1`` holds them), ``bias`` [C],
-    ``pos`` [T] each token's position in its document.  A tap that would
-    read a token of the document before (``pos`` smaller than the tap's
-    reach) is dropped, so documents packed end to end convolve as each does
-    alone.  float32."""
+    ``conv1d`` padded on the left by ``K - 1`` holds them), ``bias`` [C] or
+    None (no bias), ``pos`` [T] each token's position in its document.  A
+    tap that would read a token of the document before (``pos`` smaller
+    than the tap's reach) is dropped, so documents packed end to end
+    convolve as each does alone.  float32."""
     x = x.astype(jnp.float32)
     taps = weight.shape[0]
-    out = jnp.broadcast_to(bias.astype(jnp.float32), x.shape)
+    out = (jnp.zeros_like(x) if bias is None
+           else jnp.broadcast_to(bias.astype(jnp.float32), x.shape))
     for back in range(taps):
         shifted = x if back == 0 else jnp.pad(x, ((back, 0), (0, 0)))[: x.shape[0]]
         shifted = jnp.where((pos >= back)[:, None], shifted, 0.0)

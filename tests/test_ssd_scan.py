@@ -2,7 +2,8 @@
 recurrence it stands for, computed token by token in float64, one document at
 a time: over one document, and over a packed axis of documents whose lengths
 straddle chunk ends, with a padded tail.  The convolution's taps at document
-starts.  The Pallas kernel's body in interpret mode against the XLA form.
+starts, with a bias and without one.  The Pallas kernel's body in interpret
+mode against the XLA form.
 
 Tolerance: both sides sum the same products in another order, in float32 on
 one side: 2e-6 of the largest output (read here: 1e-7 to 4e-7).
@@ -155,3 +156,48 @@ def test_a_tap_of_the_convolution_is_dropped_at_a_documents_start():
         off += n
     # a document's first token sees its own tap and the bias alone
     assert np.allclose(got[5], bias + w[taps - 1] * x[5], atol=1e-6)
+
+
+def _causal_conv_with_bias(x, weight, bias, pos):
+    """``causal_conv`` as it stood when a bias was required (Falcon-H1's
+    call): the bias broadcast first, then the taps from the nearest back."""
+    x = x.astype(jnp.float32)
+    taps = weight.shape[0]
+    out = jnp.broadcast_to(bias.astype(jnp.float32), x.shape)
+    for back in range(taps):
+        shifted = x if back == 0 else jnp.pad(x, ((back, 0), (0, 0)))[: x.shape[0]]
+        shifted = jnp.where((pos >= back)[:, None], shifted, 0.0)
+        out = out + shifted * weight[taps - 1 - back].astype(jnp.float32)
+    return out
+
+
+@pytest.mark.parametrize("jit", [False, True])
+def test_the_convolution_with_a_bias_is_bit_for_bit_what_it_was(jit):
+    """Falcon-H1's call (bfloat16 weights and bias, 4 taps, a packed axis)
+    gives the same bits as before ``bias=None`` was allowed."""
+    lengths, t, taps, channels = (5, 1, 2, 9, 4), 24, 4, 40
+    rng = np.random.default_rng(11)
+    x = jnp.asarray(rng.normal(size=(t, channels)), jnp.bfloat16)
+    w = jnp.asarray(rng.normal(size=(taps, channels)), jnp.bfloat16)
+    bias = jnp.asarray(rng.normal(size=channels), jnp.bfloat16)
+    _seg, pos, _valid = _layout(lengths, t)
+    pos = jnp.asarray(pos)
+    now, before = S.causal_conv, _causal_conv_with_bias
+    if jit:
+        now, before = jax.jit(now), jax.jit(before)
+    np.testing.assert_array_equal(np.asarray(now(x, w, bias, pos)),
+                                  np.asarray(before(x, w, bias, pos)))
+
+
+def test_no_bias_is_the_taps_alone():
+    """``bias=None`` (LFM2's conv has none) is the convolution with a bias
+    of zero, and a document's first token is its own tap alone."""
+    lengths, t, taps, channels = (3, 6, 1, 7), 20, 3, 5
+    rng = np.random.default_rng(12)
+    x = jnp.asarray(rng.normal(size=(t, channels)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(taps, channels)), jnp.float32)
+    _seg, pos, _valid = _layout(lengths, t)
+    pos = jnp.asarray(pos)
+    got = np.asarray(S.causal_conv(x, w, None, pos))
+    np.testing.assert_array_equal(got, np.asarray(S.causal_conv(x, w, jnp.zeros(channels), pos)))
+    np.testing.assert_array_equal(got[3], np.asarray(x[3] * w[taps - 1]))

@@ -14,7 +14,8 @@ the published shapes, the packed forward at each token bucket with that
 kernel in it, and the index's search and apply at rows of 5,120 values.  The
 latent-attention embedder's cell (``ingest-docs-joyai``), the fullest, its
 packed forward at each token bucket beside 10.59 GB of weights and the index
-at its shapes.  Some twenty compiles, one file (the on-chip-measurement guide, section 2): the topology is described
+at its shapes; the conv embedder's cell (``ingest-docs-lfm2``) its packed
+forward at each token bucket beside 10.53 GB.  Some twenty compiles, one file: the topology is described
 inside a fixture of THIS file only, because one process at a time may load the
 TPU's library, and the persistent compile cache is kept out of it, because a
 compile for a described chip cannot be read back without the chip."""
@@ -314,3 +315,35 @@ def test_the_index_searches_and_applies_this_cell_s_rows(one_chip, no_persistent
     mask = getattr(knn._scatter_mask, "__wrapped__", knn._scatter_mask)
     mask.lower(shape((n,), jnp.bool_), shape((32,), jnp.int32),
                shape((32,), jnp.bool_)).compile()
+
+
+# -- the conv embedder's cell ---------------------------------------------------
+
+def test_the_conv_packed_forward_compiles_at_each_token_bucket_beside_the_index(
+        one_chip, no_persistent_cache):
+    """10.53 GB of weights (64 experts of 1,536 in eight layers, the whole
+    vocabulary).  Each launch that serves: its temporaries (four routed rows
+    a token through experts of 1,536, ``W_in``'s 6,144 columns a token on
+    eight layers) have to fit beside the weights and the three copies of the
+    index's 0.54 GB that an apply holds."""
+    import numpy as np
+
+    from pathway_tpu.models import causal_moe_embedder as cme
+    from pathway_tpu.models.encoder import dispatch_dtype, ragged_chunk
+
+    _config, cfg, params = _cell_model(one_chip, "lfm2", "vs-lfm2-24b-a2b-bf16-marcodoc", cme)
+    assert set(cfg.layer_types) == {"conv", "full"} and len(cfg.token_buckets) == 4
+    model = cme.CausalMoeEmbedder(cfg, packed=True)
+    none = np.zeros(0, np.int64)
+    for tokens in cfg.token_buckets:
+        chunk = ragged_chunk(none, none, None, None, cfg.max_len,
+                             dispatch_dtype(cfg.vocab_size), cfg, tokens=tokens)
+        args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+                for a in (chunk.ids, chunk.pos, chunk.seg, chunk.starts)]
+        compiled = jax.jit(lambda p, *a: model.apply({"params": p}, *a)).lower(
+            params, *args).compile()
+        memory = compiled.memory_analysis()
+        assert memory.argument_size_in_bytes == pytest.approx(10.534e9, rel=0.01)  # bfloat16
+        assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+                + 3 * 0.537e9 < 15.75e9), tokens
+        assert "ragged-dot" in compiled.as_text()
