@@ -102,6 +102,7 @@ __all__ = [
     "record_tokenizer_cache",
     "ingest_stats",
     "record_moe_launch",
+    "moe_grouped_stats",
     "mla_stats",
     "conv_stats",
     "moe_stats",
@@ -1123,7 +1124,13 @@ _ssm_launches = _LaunchCounters(
     _add_ssm)
 
 
-def record_moe_launch(counters: Any) -> None:
+#: launches of a forward with routed experts by the grouped product's
+#: implementation its program was traced with (``pathway_moe_grouped_launches_total``)
+_moe_grouped_lock = threading.Lock()
+_moe_grouped_launches: dict[str, int] = {}
+
+
+def record_moe_launch(counters: Any, grouped_impl: str | None = None) -> None:
     """One launch of a forward with routed experts.  ``counters`` is the
     int32 device array the forward returned beside its result: token-expert
     pairs routed and experts that got a token (summed over the routed
@@ -1133,8 +1140,20 @@ def record_moe_launch(counters: Any) -> None:
     mask let through: :func:`mla_stats`), from one with conv layers the
     first three of those (:func:`conv_stats`).  Launches that have finished
     are added up; this one waits in line until a later call or
-    :func:`moe_stats`."""
+    :func:`moe_stats`.  ``grouped_impl`` (``"pallas"`` or ``"xla"``, what
+    the program's grouped product was traced with) is counted at once, on
+    the host (:func:`moe_grouped_stats`)."""
+    if grouped_impl is not None:
+        with _moe_grouped_lock:
+            _moe_grouped_launches[grouped_impl] = _moe_grouped_launches.get(grouped_impl, 0) + 1
     _moe_launches.record(counters)
+
+
+def moe_grouped_stats() -> dict[str, int]:
+    """Launches of a forward with routed experts by the implementation of
+    its grouped product (``pathway_moe_grouped_launches_total{impl=}``)."""
+    with _moe_grouped_lock:
+        return dict(_moe_grouped_launches)
 
 
 def moe_stats(wait: bool = True) -> dict[str, int]:
@@ -1287,6 +1306,13 @@ def observability_metrics_lines() -> list[str]:
                 kind = "counter" if name.endswith(("_total", "_sum")) else "gauge"
                 lines.append(f"# TYPE pathway_{family}_{name} {kind}")
                 lines.append(f"pathway_{family}_{name} {value}")
+    grouped = moe_grouped_stats()
+    if grouped:
+        lines.append("# TYPE pathway_moe_grouped_launches_total counter")
+        for impl, n in sorted(grouped.items()):
+            lines.append(
+                f'pathway_moe_grouped_launches_total{{impl="{escape_label_value(impl)}"}} {n}'
+            )
     impls = attention_impl_stats()
     if impls:
         lines.append("# TYPE pathway_attention_impl gauge")

@@ -247,6 +247,12 @@ METRICS: dict[str, tuple[str, str]] = {
     "pathway_moe_max_expert_tokens": (
         "gauge", "the fullest expert of the last launch",
     ),
+    # counted on the host a launch, by the implementation of the grouped
+    # product the forward's program was traced with (ops/grouped_matmul.py)
+    "pathway_moe_grouped_launches_total": (
+        "counter", "launches of a forward with routed experts, by the grouped "
+                   "product's implementation (impl=pallas|xla)",
+    ),
     # launch counters of a forward with latent attention
     # (models/causal_moe_embedder.py _counters; they ride the array
     # flight_recorder.record_moe_launch adds up)

@@ -95,6 +95,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..ops.grouped_matmul import grouped_matmul_impl
 from ..ops.routed_experts import launch_counters, routed_experts
 from ..ops.ssd_scan import causal_conv
 
@@ -493,17 +494,20 @@ class CausalMoeEmbedder:
     """The model as :class:`SentenceEncoder` takes one: ``init`` and
     ``apply`` over ``{"params": tree}``.  ``apply`` returns (vectors
     float32, the launch's counters: :func:`_counters`); ``record_launch``
-    is where the encoder sends the second."""
+    is where the encoder sends the second, with the grouped product's
+    implementation that ``apply`` was traced with."""
 
     def __init__(self, cfg: CausalMoeEmbedderConfig, packed: bool = False):
         self.cfg = cfg
         self.packed = packed
+        #: ``grouped_matmul_impl()`` when ``apply`` was last traced (None:
+        #: not yet, or no routed layer)
+        self.grouped_impl: str | None = None
 
-    @staticmethod
-    def record_launch(counters) -> None:
+    def record_launch(self, counters) -> None:
         from ..internals.flight_recorder import record_moe_launch
 
-        record_moe_launch(counters)
+        record_moe_launch(counters, grouped_impl=self.grouped_impl)
 
     def init(self, key, *_example):
         return {"params": init_params(self.cfg, key)}
@@ -519,6 +523,8 @@ class CausalMoeEmbedder:
 
     def apply(self, variables, *args, **kwargs):
         params = variables["params"]
+        if "sparse" in self.cfg.mlp_types:
+            self.grouped_impl = grouped_matmul_impl()
         if self.packed:
             return self._apply_packed(params, *args, **kwargs)
         return self._apply_dense(params, *args)

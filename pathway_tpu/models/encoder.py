@@ -1029,12 +1029,11 @@ class SentenceEncoder:
         )
         name = self.cfg.program_name
         # a model whose forward also returns its launch's counters says
-        # where they go (``record_launch``); they stay on the device until
-        # somebody reads the counters
-        record = getattr(self.model, "record_launch", None)
+        # where they go (``record_launch``, of the model that program
+        # applies); they stay on the device until somebody reads the counters
         self._apply = _peel_launch_counters(
             instrument_jit(named_jit(self._forward, name), "encoder.forward"),
-            record,
+            getattr(self.model, "record_launch", None),
         )
         # packed ragged forward: same params, concatenated-token layout —
         # built unconditionally (construction is free until first trace)
@@ -1047,7 +1046,7 @@ class SentenceEncoder:
                 ),
                 "encoder.forward_ragged",
             ),
-            record,
+            getattr(self._packed_model, "record_launch", None),
         )
 
     def _forward(self, params, ids, mask):

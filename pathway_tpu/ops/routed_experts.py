@@ -13,9 +13,12 @@ chosen, renormalised and scaled), for a flat axis of tokens:
   a launch of several documents gives each the result it gets alone;
 * a token that is not ``valid`` (padding) is routed nowhere: it belongs to
   no group of the grouped product, reads no expert's weights and gets 0;
-* the grouped product is :func:`jax.lax.ragged_dot`, which XLA lowers on a
-  TPU to a grouped-matmul kernel of its own (the device trace shows it as
-  ``ragged-dot``) that visits only the experts whose group is not empty;
+* the grouped product is :func:`pathway_tpu.ops.grouped_matmul.grouped_matmul`:
+  on a TPU the Pallas kernel ``pw_grouped_matmul``, which reads each expert
+  that got a row once a call, visits no row past the groups' total and
+  writes the gated activation ``silu(x Wg) * (x Wu)`` in the compute dtype
+  from the first product; elsewhere its XLA twin, :func:`jax.lax.ragged_dot`
+  with the same epilogue after it (``ragged-dot`` in a device trace);
 * the router's scores, the choice and the weights are float32 at
   ``highest`` precision whatever the weights are held in: a score rounded to
   bfloat16 flips an expert wherever two lie within 2^-8 of each other.
@@ -28,6 +31,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from .grouped_matmul import grouped_matmul
 
 __all__ = ["route", "group_tokens", "grouped_matmul", "routed_experts",
            "launch_counters"]
@@ -81,14 +86,6 @@ def group_tokens(experts, valid, num_experts: int):
     return order, group_sizes, inverse
 
 
-def grouped_matmul(lhs, rhs, group_sizes):
-    """``lhs`` [M, K] rows sorted by group, ``rhs`` [G, K, N] -> [M, N]
-    float32: rows of group ``g`` times ``rhs[g]``.  Rows past the groups'
-    total are not defined: the caller masks them."""
-    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
-                              preferred_element_type=jnp.float32)
-
-
 def routed_experts(x, valid, router, w_gate_up, w_down, *, top_k: int,
                    scaling: float, router_input=None, scoring: str = "softmax",
                    bias=None, eps: float = 1e-20):
@@ -101,14 +98,13 @@ def routed_experts(x, valid, router, w_gate_up, w_down, *, top_k: int,
     rounded copy of it; ``scoring``, ``bias`` [E] and ``eps`` are
     :func:`route`'s.
     Returns ([T, D] float32, group sizes [E])."""
-    num_experts, _, two_f = w_gate_up.shape
+    num_experts = w_gate_up.shape[0]
     experts, weights = route(x if router_input is None else router_input, router,
                              top_k=top_k, scaling=scaling, scoring=scoring, bias=bias,
                              eps=eps)
     order, group_sizes, inverse = group_tokens(experts, valid, num_experts)
     rows = x[order // top_k]
-    h = grouped_matmul(rows, w_gate_up, group_sizes)
-    act = (jax.nn.silu(h[:, : two_f // 2]) * h[:, two_f // 2:]).astype(x.dtype)
+    act = grouped_matmul(rows, w_gate_up, group_sizes, gated=True)
     y = grouped_matmul(act, w_down, group_sizes)
     y = y[inverse].reshape(experts.shape + (y.shape[-1],))
     keep = valid[:, None, None]

@@ -15,13 +15,17 @@ kernel in it, and the index's search and apply at rows of 5,120 values.  The
 latent-attention embedder's cell (``ingest-docs-joyai``), the fullest, its
 packed forward at each token bucket beside 10.59 GB of weights and the index
 at its shapes; the conv embedder's cell (``ingest-docs-lfm2``) its packed
-forward at each token bucket beside 10.53 GB.  Some twenty compiles, one file: the topology is described
+forward at each token bucket beside 10.53 GB.  The routed embedders' forwards
+are compiled with the grouped product's Pallas kernel in them, as a TPU runs
+them, and the kernel alone at the three cells' shapes.  Some thirty compiles,
+one file: the topology is described
 inside a fixture of THIS file only, because one process at a time may load the
 TPU's library, and the persistent compile cache is kept out of it, because a
 compile for a described chip cannot be read back without the chip."""
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import sys
@@ -95,6 +99,40 @@ def _cell_model(one_chip, builder_name: str, config_name: str, model_module):
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip), shapes)
 
 
+def _the_chip_s_grouped_product(monkeypatch):
+    """The model asks the platform, which is the CPU here: hand it the
+    grouped product's kernel, as a TPU would."""
+    from pathway_tpu.ops import grouped_matmul as GM
+
+    monkeypatch.setattr(GM, "grouped_matmul_impl", lambda: "pallas")
+
+
+def _assert_the_kernel_and_no_ragged_dot(compiled):
+    from pathway_tpu.ops import grouped_matmul as GM
+
+    text = compiled.as_text()
+    assert GM.KERNEL_NAME in text and "ragged-dot" not in text
+
+
+@pytest.mark.parametrize("rows", [4096 * 4, 6144 * 8, 937 * 4])
+@pytest.mark.parametrize("expert_width,experts", [(1536, 64), (768, 256), (512, 256)],
+                         ids=["lfm2", "joyai", "laguna"])
+def test_the_grouped_product_kernel_compiles_at_the_three_cells_shapes(
+        one_chip, no_persistent_cache, expert_width, experts, rows):
+    """Both products of a routed layer over a hidden of 2,048: the gated one
+    (two blocks of the expert's [2,048, 2 x width] a group in fast memory)
+    and the plain one, at a launch's rows and at a check's odd row count."""
+    from pathway_tpu.ops import grouped_matmul as GM
+
+    shape = lambda s, t: jax.ShapeDtypeStruct(s, t, sharding=one_chip)
+    for gated, (k, n) in ((True, (2048, 2 * expert_width)), (False, (expert_width, 2048))):
+        compiled = jax.jit(functools.partial(GM.grouped_matmul_pallas, gated=gated)).lower(
+            shape((rows, k), jnp.bfloat16), shape((experts, k, n), jnp.bfloat16),
+            shape((experts,), jnp.int32)).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        assert compiled.memory_analysis().temp_size_in_bytes < 1e8
+
+
 def _published_model(one_chip):
     from pathway_tpu.models import causal_moe_embedder as cme
 
@@ -102,9 +140,10 @@ def _published_model(one_chip):
 
 
 def test_the_forward_compiles_at_the_published_widths_and_fits_the_chip(
-        one_chip, no_persistent_cache):
+        one_chip, no_persistent_cache, monkeypatch):
     from pathway_tpu.models import causal_moe_embedder as cme
 
+    _the_chip_s_grouped_product(monkeypatch)
     cfg, params = _published_model(one_chip)
     model = cme.CausalMoeEmbedder(cfg)
     ids = jax.ShapeDtypeStruct((1, 128), jnp.int32, sharding=one_chip)
@@ -114,13 +153,13 @@ def test_the_forward_compiles_at_the_published_widths_and_fits_the_chip(
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes == pytest.approx(7.33e9, rel=0.01)  # bfloat16
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 16e9
-    # the grouped product is XLA's own TPU kernel, not a dense product over
-    # 256 experts: the trace names it ragged-dot
-    assert "ragged-dot" in compiled.as_text()
+    # the grouped product is the repo's kernel, not a dense product over
+    # 256 experts
+    _assert_the_kernel_and_no_ragged_dot(compiled)
 
 
 def test_the_packed_forward_compiles_at_its_largest_token_bucket_beside_the_index(
-        one_chip, no_persistent_cache):
+        one_chip, no_persistent_cache, monkeypatch):
     """The launch that serves: the largest token bucket, the arrays as
     ``ragged_chunk`` lays them out.  Its temporaries (eight routed rows a
     token) have to fit beside the weights and the three copies of the
@@ -130,6 +169,7 @@ def test_the_packed_forward_compiles_at_its_largest_token_bucket_beside_the_inde
     from pathway_tpu.models import causal_moe_embedder as cme
     from pathway_tpu.models.encoder import dispatch_dtype, ragged_chunk
 
+    _the_chip_s_grouped_product(monkeypatch)
     cfg, params = _published_model(one_chip)
     none = np.zeros(0, np.int64)
     chunk = ragged_chunk(none, none, None, None, cfg.max_len, dispatch_dtype(cfg.vocab_size),
@@ -143,7 +183,7 @@ def test_the_packed_forward_compiles_at_its_largest_token_bucket_beside_the_inde
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes == pytest.approx(7.33e9, rel=0.01)
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes + 3 * 2.15e9 < 15.75e9
-    assert "ragged-dot" in compiled.as_text()
+    _assert_the_kernel_and_no_ragged_dot(compiled)
 
 
 # -- the hybrid (attention + state-space) embedder's cell -----------------------
@@ -259,7 +299,7 @@ def _latent_model(one_chip):
 
 
 def test_the_latent_packed_forward_compiles_at_each_token_bucket_beside_the_index(
-        one_chip, no_persistent_cache):
+        one_chip, no_persistent_cache, monkeypatch):
     """The fullest cell: 10.59 GB of weights (every one of 256 experts of 768
     in four layers, the whole vocabulary).  Each launch that serves, the
     arrays as ``ragged_chunk`` lays them out: its temporaries (eight routed
@@ -270,6 +310,7 @@ def test_the_latent_packed_forward_compiles_at_each_token_bucket_beside_the_inde
     from pathway_tpu.models import causal_moe_embedder as cme
     from pathway_tpu.models.encoder import dispatch_dtype, ragged_chunk
 
+    _the_chip_s_grouped_product(monkeypatch)
     _config, cfg, params = _latent_model(one_chip)
     assert set(cfg.layer_types) == {"latent"} and len(cfg.token_buckets) == 4
     model = cme.CausalMoeEmbedder(cfg, packed=True)
@@ -286,7 +327,7 @@ def test_the_latent_packed_forward_compiles_at_each_token_bucket_beside_the_inde
         assert memory.argument_size_in_bytes == pytest.approx(10.587e9, rel=0.01)  # bfloat16
         assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
                 + 3 * 0.537e9 < 15.75e9), tokens
-        assert "ragged-dot" in compiled.as_text()
+        _assert_the_kernel_and_no_ragged_dot(compiled)
 
 
 def test_the_index_searches_and_applies_this_cell_s_rows(one_chip, no_persistent_cache):
@@ -320,7 +361,7 @@ def test_the_index_searches_and_applies_this_cell_s_rows(one_chip, no_persistent
 # -- the conv embedder's cell ---------------------------------------------------
 
 def test_the_conv_packed_forward_compiles_at_each_token_bucket_beside_the_index(
-        one_chip, no_persistent_cache):
+        one_chip, no_persistent_cache, monkeypatch):
     """10.53 GB of weights (64 experts of 1,536 in eight layers, the whole
     vocabulary).  Each launch that serves: its temporaries (four routed rows
     a token through experts of 1,536, ``W_in``'s 6,144 columns a token on
@@ -331,6 +372,7 @@ def test_the_conv_packed_forward_compiles_at_each_token_bucket_beside_the_index(
     from pathway_tpu.models import causal_moe_embedder as cme
     from pathway_tpu.models.encoder import dispatch_dtype, ragged_chunk
 
+    _the_chip_s_grouped_product(monkeypatch)
     _config, cfg, params = _cell_model(one_chip, "lfm2", "vs-lfm2-24b-a2b-bf16-marcodoc", cme)
     assert set(cfg.layer_types) == {"conv", "full"} and len(cfg.token_buckets) == 4
     model = cme.CausalMoeEmbedder(cfg, packed=True)
@@ -346,4 +388,4 @@ def test_the_conv_packed_forward_compiles_at_each_token_bucket_beside_the_index(
         assert memory.argument_size_in_bytes == pytest.approx(10.534e9, rel=0.01)  # bfloat16
         assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
                 + 3 * 0.537e9 < 15.75e9), tokens
-        assert "ragged-dot" in compiled.as_text()
+        _assert_the_kernel_and_no_ragged_dot(compiled)
