@@ -1133,12 +1133,13 @@ _moe_grouped_launches: dict[str, int] = {}
 def record_moe_launch(counters: Any, grouped_impl: str | None = None) -> None:
     """One launch of a forward with routed experts.  ``counters`` is the
     int32 device array the forward returned beside its result: token-expert
-    pairs routed and experts that got a token (summed over the routed
-    layers), each layer's fullest expert summed, and the fullest of all;
-    from a forward with latent attention four more behind them (documents,
-    real tokens, the tokens of its bucket, the (query, key) pairs its causal
-    mask let through: :func:`mla_stats`), from one with conv layers the
-    first three of those (:func:`conv_stats`).  Launches that have finished
+    pairs routed to an expert held here and held experts that got a token
+    (summed over the routed layers), each layer's fullest expert summed, and
+    the fullest of all; from a forward with latent attention four more
+    behind them (documents, real tokens, the tokens of its bucket, the
+    (query, key) pairs its causal mask let through: :func:`mla_stats`), from
+    one with conv layers the first three of those (:func:`conv_stats`).
+    Launches that have finished
     are added up; this one waits in line until a later call or
     :func:`moe_stats`.  ``grouped_impl`` (``"pallas"`` or ``"xla"``, what
     the program's grouped product was traced with) is counted at once, on

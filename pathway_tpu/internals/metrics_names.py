@@ -236,10 +236,11 @@ METRICS: dict[str, tuple[str, str]] = {
         "counter", "launches of a forward with routed experts",
     ),
     "pathway_moe_routed_tokens_total": (
-        "counter", "(token, expert) pairs routed, summed over the routed layers",
+        "counter", "(token, expert) pairs routed to an expert held here, summed over "
+                   "the routed layers",
     ),
     "pathway_moe_experts_touched_total": (
-        "counter", "experts that got a token, summed over the routed layers",
+        "counter", "experts held here that got a token, summed over the routed layers",
     ),
     "pathway_moe_max_expert_tokens_sum": (
         "counter", "each routed layer's fullest expert, summed over layers and launches",

@@ -1,7 +1,7 @@
 """A causal language model as a sentence embedder: window, full and latent
-attention, gated short convolutions, grouped queries, rotary positions,
-routed and shared experts, the final-norm state of the last token as the
-vector.
+attention, gated short convolutions, gated delta rules, grouped queries,
+rotary positions, routed and shared experts, the final-norm state of the last
+token as the vector.
 
 The forward of a decoder-only model with nothing generated (no output head,
 no cache), the way language-model embedders are deployed (E5-Mistral,
@@ -36,7 +36,11 @@ as the DeepSeek-V3 lineage configures it) replaces steps 1-4
 (:func:`_attend_latent`): queries and keys/values come through two low-rank
 projections with an RMS norm inside each, a head's query and key are a
 rotary-free part and a rotary part side by side, the rotary key is ONE head
-shared by all, the value has a size of its own, there is no gate.  It runs
+shared by all, the value has a size of its own, there is no gate.  With
+``latent_q_rank`` 0 the query is one direct projection ``q = a W_q`` (no
+low-rank path, no norm); with ``latent_rotary`` None no dimension turns (the
+rotary part is kept in the product as it is, the key's one head under all).
+It runs
 here in the prefill ("expanded") form: keys and values are expanded from the
 latent for every token and a (query, key, head) triple costs ``qk_nope_dim +
 qk_rope_dim + v_head_dim`` multiply-adds (192 + 128), where the absorbed form
@@ -53,10 +57,22 @@ convolution of ``u`` over ``conv_taps`` tokens with no bias
 the document before is dropped, so a document convolves on a packed axis as
 it does alone), ``x += (C * v) W_out``.
 
+A layer of kind ``"kda"`` (Kimi Delta Attention, arXiv:2510.26692;
+:func:`_kda_mixer`) replaces steps 1-4 with a gated delta rule over
+``kda_heads`` heads of ``kda_head_dim`` (``P`` = their product):
+``q, k, v = silu(causal_conv(a W_qkv))`` over ``conv_taps`` tokens with no
+bias; ``q`` and ``k`` L2-normed a head, ``q`` times ``1/sqrt(kda_head_dim)``;
+a decay a channel ``g = -exp(A_log) softplus((a W_fa) W_fb + dt_bias)`` and
+``beta = sigmoid(a W_b)``; ``o`` the delta rule's output
+(:func:`pathway_tpu.ops.kda_scan.kda_scan`, the state zero at a document's
+start); ``x += (rmsnorm_head(o) * sigmoid((a W_ga) W_gb)) W_o``.  The two
+low-rank paths are of rank ``kda_head_dim``.
+
 Precision: weights are held in ``param_dtype`` (bfloat16) and products take
 ``dtype`` (bfloat16) operands with float32 accumulation; the residual
 stream, norms, rotary tables, softmax, the router, the combine and the
-convolution's gates and taps are float32.
+convolution's gates and taps, and the delta rule's norms, decays,
+gates and scan are float32.
 
 Attention is XLA over blocks of ``q_block`` queries.  A query block visits
 the key blocks from the first that holds a token some query of it may see
@@ -96,6 +112,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.grouped_matmul import grouped_matmul_impl
+from ..ops.kda_scan import kda_scan
 from ..ops.routed_experts import launch_counters, routed_experts
 from ..ops.ssd_scan import causal_conv
 
@@ -132,7 +149,7 @@ class CausalMoeEmbedderConfig:
     hidden_dim: int = 2048
     head_dim: int = 128
     num_kv_heads: int = 8
-    #: per layer: "full", "window", "latent" or "conv"; its query heads
+    #: per layer: "full", "window", "latent", "conv" or "kda"; its query heads
     #: (read by the attention kinds alone); "dense" or "sparse"
     layer_types: tuple[str, ...] = ("full", "window", "window", "window", "full")
     heads_per_layer: tuple[int, ...] = (48, 64, 64, 64, 48)
@@ -143,23 +160,33 @@ class CausalMoeEmbedderConfig:
         original_max_len=4096, beta_fast=64.0, beta_slow=1.0,
         attention_factor=1.4158883083359672)
     window_rotary: RotarySpec = RotarySpec(theta=10_000.0)
-    #: latent layers: the ranks of the query's and the key/value's low-rank
-    #: paths, a head's rotary-free and rotary parts (the rotary key is one
-    #: head under all), the value's size, rotary over the whole rotary part
+    #: latent layers: the ranks of the query's (0: a direct projection) and
+    #: the key/value's low-rank paths, a head's rotary-free and rotary parts
+    #: (the rotary key is one head under all), the value's size, rotary over
+    #: the whole rotary part (None: no rotary)
     latent_q_rank: int = 1536
     latent_kv_rank: int = 512
     latent_nope_dim: int = 128
     latent_rope_dim: int = 64
     latent_v_dim: int = 128
-    latent_rotary: RotarySpec = RotarySpec(theta=32_000_000.0, interleaved=True)
+    latent_rotary: RotarySpec | None = RotarySpec(theta=32_000_000.0, interleaved=True)
     #: grouped-query layers: per-head RMS norms of q and k before rotary;
     #: the per-head sigmoid gate on the attention's output
     qk_norm: bool = False
     attention_gate: bool = True
-    #: conv layers: tokens the causal depthwise convolution reaches
+    #: conv and kda layers: tokens the causal depthwise convolution reaches
     conv_taps: int = 3
+    #: kda layers: heads of the delta rule and their size (also the rank of
+    #: the decay's and the output gate's low-rank paths)
+    kda_heads: int = 32
+    kda_head_dim: int = 128
     dense_mlp_dim: int = 8192
+    #: experts the router chooses among; this chip holds ``held_experts`` of
+    #: them (None: all) from ``first_expert`` on (expert parallelism: the
+    #: others lie on other chips, and their part of a layer is not computed here)
     num_experts: int = 256
+    held_experts: int | None = None
+    first_expert: int = 0
     top_k: int = 8
     expert_dim: int = 512
     #: 0: the sparse layers hold no shared expert
@@ -217,21 +244,29 @@ class CausalMoeEmbedderConfig:
         if not (len(self.heads_per_layer) == len(self.mlp_types) == n):
             raise ValueError("layer_types, heads_per_layer and mlp_types "
                              "must name the same layers")
-        if not set(self.layer_types) <= {"full", "window", "latent", "conv"}:
+        if not set(self.layer_types) <= {"full", "window", "latent", "conv", "kda"}:
             raise ValueError(f"layer_types {self.layer_types}")
         if any(h % self.num_kv_heads for kind, h in zip(self.layer_types, self.heads_per_layer)
                if kind in ("full", "window")):
             raise ValueError("a grouped-query layer's query heads must be a multiple "
                              f"of num_kv_heads={self.num_kv_heads}")
-        if "latent" in self.layer_types and (self.latent_rope_dim % 2 or min(
-                self.latent_q_rank, self.latent_kv_rank, self.latent_nope_dim,
-                self.latent_rope_dim, self.latent_v_dim) < 1):
+        if "latent" in self.layer_types and (self.latent_rope_dim % 2 or self.latent_q_rank < 0
+                                             or min(self.latent_kv_rank, self.latent_nope_dim,
+                                                    self.latent_rope_dim, self.latent_v_dim) < 1):
             raise ValueError("a latent layer needs positive ranks and head parts, "
                              "the rotary part even")
         if self.router_scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"router_scoring {self.router_scoring!r}")
-        if "conv" in self.layer_types and self.conv_taps < 1:
+        if {"conv", "kda"} & set(self.layer_types) and self.conv_taps < 1:
             raise ValueError(f"conv_taps {self.conv_taps}")
+        if not 0 <= self.first_expert <= self.first_expert + self.experts_here <= self.num_experts:
+            raise ValueError(f"experts {self.first_expert}..+{self.experts_here} of "
+                             f"{self.num_experts}")
+
+    @property
+    def experts_here(self) -> int:
+        """Routed experts this chip holds a layer."""
+        return self.num_experts if self.held_experts is None else self.held_experts
 
     @property
     def num_layers(self) -> int:
@@ -376,6 +411,8 @@ def _attend_row(cfg: CausalMoeEmbedderConfig, i: int, p, a, pos, seg, valid):
         return _attend_latent(cfg, p, a, pos, seg, valid)
     if cfg.layer_types[i] == "conv":
         return _conv_mixer(cfg, p, a, pos)
+    if cfg.layer_types[i] == "kda":
+        return _kda_mixer(cfg, p, a, pos, seg, valid)
     dt = cfg.dtype
     full = cfg.layer_types[i] == "full"
     spec = cfg.full_rotary if full else cfg.window_rotary
@@ -405,6 +442,30 @@ def _conv_mixer(cfg: CausalMoeEmbedderConfig, p, a, pos):
     return jnp.dot((bch[:, d: 2 * d] * v).astype(cfg.dtype), p["w_out"], **f32)
 
 
+def _l2_norm(x, eps: float = 1e-6):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+def _kda_mixer(cfg: CausalMoeEmbedderConfig, p, a, pos, seg, valid):
+    """A kda layer's mixer for one token axis (see the module): the three
+    projections in one product, their taps dropped across a document's start
+    by ``pos``, the delta rule over the axis with ``seg`` and ``valid``."""
+    f32 = dict(preferred_element_type=jnp.float32)
+    dt, h, dk = cfg.dtype, cfg.kda_heads, cfg.kda_head_dim
+    ad = a.astype(dt)
+    low_rank = lambda wa, wb: jnp.dot(jnp.dot(ad, p[wa], **f32).astype(dt), p[wb], **f32)
+    qkv = jax.nn.silu(causal_conv(jnp.dot(ad, p["w_qkv"], **f32), p["conv"], None, pos))
+    q, k, v = (qkv[:, n * h * dk:(n + 1) * h * dk].reshape(-1, h, dk) for n in range(3))
+    decay = (low_rank("w_fa", "w_fb").reshape(-1, h, dk)
+             + p["dt_bias"].astype(jnp.float32).reshape(h, dk))
+    g = -jnp.exp(p["A_log"].astype(jnp.float32))[:, None] * jax.nn.softplus(decay)
+    beta = jax.nn.sigmoid(jnp.dot(ad, p["w_b"], **f32))
+    o = kda_scan(_l2_norm(q) * dk ** -0.5, _l2_norm(k), v, g, beta, seg, pos, valid)
+    o = _rms_norm(o, p["o_norm"], cfg.rms_eps) * jax.nn.sigmoid(
+        low_rank("w_ga", "w_gb").reshape(-1, h, dk))
+    return jnp.dot(o.reshape(-1, h * dk).astype(dt), p["w_o"], **f32)
+
+
 def _attend_latent(cfg: CausalMoeEmbedderConfig, p, a, pos, seg, valid):
     """A latent layer's attention for one token axis, in the prefill form:
     ``c_q = rmsnorm(a W_dq)``, ``q = c_q W_uq`` [T, H, nope + rope]; ``[c_kv |
@@ -416,14 +477,20 @@ def _attend_latent(cfg: CausalMoeEmbedderConfig, p, a, pos, seg, valid):
     dt, nope = cfg.dtype, cfg.latent_nope_dim
     f32 = dict(preferred_element_type=jnp.float32)
     ad = a.astype(dt)
-    c_q = _rms_norm(jnp.dot(ad, p["wq_a"], **f32), p["q_norm"], cfg.rms_eps)
-    q = jnp.einsum("tr,rhe->the", c_q.astype(dt), p["wq_b"], **f32)
+    if cfg.latent_q_rank:
+        c_q = _rms_norm(jnp.dot(ad, p["wq_a"], **f32), p["q_norm"], cfg.rms_eps)
+        q = jnp.einsum("tr,rhe->the", c_q.astype(dt), p["wq_b"], **f32)
+    else:
+        q = jnp.einsum("td,dhe->the", ad, p["wq"], **f32)
     ckv = jnp.dot(ad, p["wkv_a"], **f32)
     c_kv = _rms_norm(ckv[:, : cfg.latent_kv_rank], p["kv_norm"], cfg.rms_eps)
     kv = jnp.einsum("tr,rhe->the", c_kv.astype(dt), p["wkv_b"], **f32)
-    q_pe = _rotate(q[..., nope:], pos, cfg.latent_rotary)
-    k_pe = _rotate(ckv[:, None, cfg.latent_kv_rank:], pos, cfg.latent_rotary)
-    q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+    if cfg.latent_rotary is None:
+        q_pe, k_pe = q[..., nope:], ckv[:, None, cfg.latent_kv_rank:]
+    else:
+        q_pe = _rotate(q[..., nope:], pos, cfg.latent_rotary)
+        k_pe = _rotate(ckv[:, None, cfg.latent_kv_rank:], pos, cfg.latent_rotary)
+        q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
     k = jnp.concatenate(
         [kv[..., :nope], jnp.broadcast_to(k_pe, q_pe.shape)], axis=-1)  # the one rotary key
     o = _attention(q.astype(dt), k.astype(dt), kv[..., nope:].astype(dt), pos, seg, valid,
@@ -454,7 +521,7 @@ def _layer(cfg: CausalMoeEmbedderConfig, i: int, p, x, pos, seg, valid):
         bd.reshape(flat + bd.shape[2:]), valid.reshape(flat), m["router"],
         m["w_gate_up"], m["w_down"], top_k=cfg.top_k, scaling=cfg.routed_scaling,
         router_input=b.reshape(flat + b.shape[2:]), scoring=cfg.router_scoring,
-        bias=m.get("bias"), eps=cfg.router_eps)
+        bias=m.get("bias"), eps=cfg.router_eps, first_expert=cfg.first_expert)
     x = x + routed.reshape(x.shape)
     if "shared" in m:
         x = x + _gated_mlp(bd, m["shared"]["w_gate_up"], m["shared"]["w_down"])
@@ -569,7 +636,8 @@ def init_params(cfg: CausalMoeEmbedderConfig, key):
     """A parameter tree drawn layer by layer (a tensor of experts is a
     gigabyte): token embeddings at unit scale, matrices at
     1/sqrt(fan-in), norms at one, a sigmoid router's selection bias at
-    zero."""
+    zero; a kda layer's ``A_log`` and ``dt_bias`` at zero, float32 as the
+    scan reads them."""
     pd, d, hd, kv = cfg.param_dtype, cfg.hidden_dim, cfg.head_dim, cfg.num_kv_heads
 
     def normal(key, shape, fan_in):
@@ -593,12 +661,26 @@ def init_params(cfg: CausalMoeEmbedderConfig, key):
                 "conv": normal(k[1], (cfg.conv_taps, d), cfg.conv_taps),
                 "w_out": normal(k[2], (d, d), d),
             })
+        elif cfg.layer_types[i] == "kda":
+            kh, dk = cfg.kda_heads, cfg.kda_head_dim
+            kp = jax.random.split(k[0], 7)
+            layer.update({
+                "w_qkv": normal(kp[0], (d, 3 * kh * dk), d),
+                "conv": normal(kp[1], (cfg.conv_taps, 3 * kh * dk), cfg.conv_taps),
+                "w_fa": normal(kp[2], (d, dk), d), "w_fb": normal(kp[3], (dk, kh * dk), dk),
+                "dt_bias": jnp.zeros((kh * dk,), jnp.float32),
+                "A_log": jnp.zeros((kh,), jnp.float32),
+                "w_b": normal(kp[4], (d, kh), d),
+                "w_ga": normal(kp[5], (d, dk), d), "w_gb": normal(kp[6], (dk, kh * dk), dk),
+                "o_norm": jnp.ones((dk,), pd), "w_o": normal(k[1], (kh * dk, d), kh * dk),
+            })
         elif cfg.layer_types[i] == "latent":
             qr, kvr, nope = cfg.latent_q_rank, cfg.latent_kv_rank, cfg.latent_nope_dim
             rope, vd = cfg.latent_rope_dim, cfg.latent_v_dim
-            layer.update({
+            layer.update({"wq": normal(k[0], (d, h, nope + rope), d)} if not qr else {
                 "wq_a": normal(k[0], (d, qr), d), "q_norm": jnp.ones((qr,), pd),
-                "wq_b": normal(k[1], (qr, h, nope + rope), qr),
+                "wq_b": normal(k[1], (qr, h, nope + rope), qr)})
+            layer.update({
                 "wkv_a": normal(k[2], (d, kvr + rope), d), "kv_norm": jnp.ones((kvr,), pd),
                 "wkv_b": normal(k[3], (kvr, h, nope + vd), kvr),
                 "wo": normal(k[4], (h, vd, d), h * vd),
@@ -617,7 +699,7 @@ def init_params(cfg: CausalMoeEmbedderConfig, key):
         else:
             layer["moe"] = {
                 "router": normal(k[5], (d, cfg.num_experts), d),
-                **mlp(k[6], (cfg.num_experts,), cfg.expert_dim),
+                **mlp(k[6], (cfg.experts_here,), cfg.expert_dim),
             }
             if cfg.shared_expert_dim:
                 layer["moe"]["shared"] = mlp(k[7], (), cfg.shared_expert_dim)

@@ -13,6 +13,13 @@ chosen, renormalised and scaled), for a flat axis of tokens:
   a launch of several documents gives each the result it gets alone;
 * a token that is not ``valid`` (padding) is routed nowhere: it belongs to
   no group of the grouped product, reads no expert's weights and gets 0;
+* the layer may hold a share of the experts (expert parallelism: the
+  others lie on other chips): ``w_gate_up``/``w_down`` hold ``E_held``
+  experts from ``first_expert`` on while the router scores all of them; a
+  token is routed and weighted over all, and a pair whose expert is held
+  elsewhere goes with the padding, reads no weights and adds 0, so the layer
+  gives its own experts' part of the result.  Nothing here stands in for the
+  other chips or for the exchange with them;
 * the grouped product is :func:`pathway_tpu.ops.grouped_matmul.grouped_matmul`:
   on a TPU the Pallas kernel ``pw_grouped_matmul``, which reads each expert
   that got a row once a call, visits no row past the groups' total and
@@ -34,7 +41,7 @@ import jax.numpy as jnp
 
 from .grouped_matmul import grouped_matmul
 
-__all__ = ["route", "group_tokens", "grouped_matmul", "routed_experts",
+__all__ = ["route", "group_tokens", "held_pairs", "grouped_matmul", "routed_experts",
            "launch_counters"]
 
 
@@ -71,14 +78,27 @@ def route(x, router, *, top_k: int, scaling: float, scoring: str = "softmax",
     return experts.astype(jnp.int32), top / (total if soft else total + eps) * scaling
 
 
-def group_tokens(experts, valid, num_experts: int):
+def held_pairs(experts, valid, num_experts: int, first_expert: int = 0):
+    """[T, K] bool: the pairs of real tokens whose expert is among the
+    ``num_experts`` held from ``first_expert`` on."""
+    return valid[:, None] & (experts >= first_expert) & (experts < first_expert + num_experts)
+
+
+def group_tokens(experts, valid, num_experts: int, first_expert=None):
     """The routed (token, expert) pairs sorted by expert.
 
-    ``experts`` [T, K], ``valid`` [T] bool.  Returns ``order`` [T*K] (the
-    pairs by expert, pairs of padding last), ``group_sizes`` [E] (valid
-    pairs of each expert) and ``inverse`` [T*K] (where each pair went)."""
+    ``experts`` [T, K], ``valid`` [T] bool; ``first_expert`` None: all
+    ``num_experts`` are held, else ``num_experts`` from ``first_expert`` on
+    and a pair of another expert goes with the padding.  Returns ``order``
+    [T*K] (the pairs by held expert, the others last), ``group_sizes``
+    [num_experts] (valid pairs of each held expert) and ``inverse`` [T*K]
+    (where each pair went)."""
     t, k = experts.shape
-    flat = jnp.where(valid[:, None], experts, num_experts).reshape(t * k)
+    if first_expert is None:
+        flat = jnp.where(valid[:, None], experts, num_experts).reshape(t * k)
+    else:
+        flat = jnp.where(held_pairs(experts, valid, num_experts, first_expert),
+                         experts - first_expert, num_experts).reshape(t * k)
     order = jnp.argsort(flat, stable=True)
     group_sizes = jnp.zeros((num_experts + 1,), jnp.int32).at[flat].add(1)[:num_experts]
     inverse = jnp.zeros((t * k,), jnp.int32).at[order].set(
@@ -88,26 +108,30 @@ def group_tokens(experts, valid, num_experts: int):
 
 def routed_experts(x, valid, router, w_gate_up, w_down, *, top_k: int,
                    scaling: float, router_input=None, scoring: str = "softmax",
-                   bias=None, eps: float = 1e-20):
+                   bias=None, eps: float = 1e-20, first_expert: int = 0):
     """Sum over each token's experts of ``weight * E(x)`` with ``E`` a gated
     MLP (``silu(x Wg) * (x Wu)) Wd``.
 
     ``x`` [T, D] in the compute dtype, ``valid`` [T] bool, ``router``
-    [D, E], ``w_gate_up`` [E, D, 2F] (gate columns first), ``w_down``
-    [E, F, D]; ``router_input`` is what the router reads where ``x`` is a
+    [D, E], ``w_gate_up`` [E_held, D, 2F] (gate columns first), ``w_down``
+    [E_held, F, D], experts ``first_expert..first_expert + E_held - 1`` of
+    the E; ``router_input`` is what the router reads where ``x`` is a
     rounded copy of it; ``scoring``, ``bias`` [E] and ``eps`` are
     :func:`route`'s.
-    Returns ([T, D] float32, group sizes [E])."""
+    Returns ([T, D] float32: the held experts' part, group sizes [E_held])."""
     num_experts = w_gate_up.shape[0]
+    share = first_expert != 0 or num_experts != router.shape[1]
     experts, weights = route(x if router_input is None else router_input, router,
                              top_k=top_k, scaling=scaling, scoring=scoring, bias=bias,
                              eps=eps)
-    order, group_sizes, inverse = group_tokens(experts, valid, num_experts)
+    order, group_sizes, inverse = group_tokens(experts, valid, num_experts,
+                                               first_expert if share else None)
     rows = x[order // top_k]
     act = grouped_matmul(rows, w_gate_up, group_sizes, gated=True)
     y = grouped_matmul(act, w_down, group_sizes)
     y = y[inverse].reshape(experts.shape + (y.shape[-1],))
-    keep = valid[:, None, None]
+    keep = (held_pairs(experts, valid, num_experts, first_expert)[:, :, None] if share
+            else valid[:, None, None])
     out = jnp.sum(jnp.where(keep, y * weights[:, :, None], 0.0), axis=1)
     return out, group_sizes
 

@@ -115,6 +115,10 @@ def test_the_cell_is_declared_with_its_configuration_traffic_and_metrics(bench):
     for name in reported - {"setup_s"}:  # every metric the cell reports has its reader
         kind = "end_to_end" if name == "fresh_p95_ms" else "layer_metrics"
         assert os.path.exists(os.path.join(BENCH, kind, name + ".py")), name
-    for name in ("ingest_mla_step.mfu", "mla.keys_per_query", "mla.padding_share"):
+    # the latent family's counters are also laid out by Kimi-Linear's forward
+    # (it has a latent layer), so its cell reads the two counter readers too
+    for name, cells in (("ingest_mla_step.mfu", [CELL]),
+                        ("mla.keys_per_query", [CELL, "ingest-docs-kimi-linear"]),
+                        ("mla.padding_share", [CELL, "ingest-docs-kimi-linear"])):
         metric = next(m for m in bench["per_layer"] if m["name"] == name)
-        assert metric["workloads"] == [CELL] and metric["moves"] == "fresh_p95_ms"
+        assert metric["workloads"] == cells and metric["moves"] == "fresh_p95_ms"

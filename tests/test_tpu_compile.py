@@ -389,3 +389,134 @@ def test_the_conv_packed_forward_compiles_at_each_token_bucket_beside_the_index(
         assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
                 + 3 * 0.537e9 < 15.75e9), tokens
         _assert_the_kernel_and_no_ragged_dot(compiled)
+
+
+# -- the delta-rule embedder's cell ---------------------------------------------
+
+@pytest.mark.parametrize("tokens", [1536, 6144])
+def test_the_delta_rule_scan_kernel_compiles_at_the_published_shapes(
+        one_chip, no_persistent_cache, tokens):
+    """32 heads of 128 key and value channels, chunks of 64 in sub-blocks of
+    16, the heads' states in VMEM: interpret mode knows nothing of Mosaic's
+    tiles or of VMEM."""
+    from pathway_tpu.ops import kda_scan as K
+
+    h, d = 32, 128
+    shape = lambda s, t=jnp.float32: jax.ShapeDtypeStruct(s, t, sharding=one_chip)
+    args = [shape((tokens, h, d)) for _ in range(4)] + [
+        shape((tokens, h)), shape((tokens,), jnp.int32), shape((tokens,), jnp.int32),
+        shape((tokens,), jnp.bool_)]
+    compiled = jax.jit(K.kda_scan_pallas).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and K.KERNEL_NAME in text
+
+
+def _kimi_model(one_chip):
+    from pathway_tpu.models import causal_moe_embedder as cme
+
+    return _cell_model(one_chip, "kimi_linear", "vs-kimi-linear-48b-a3b-bf16-marcodoc", cme)
+
+
+def test_the_delta_rule_packed_forward_compiles_at_each_token_bucket_beside_the_index(
+        one_chip, no_persistent_cache, monkeypatch):
+    """8.57 GB of weights (128 of 256 experts of 1,024 in four layers, the
+    whole vocabulary of 163,840), with the scan's and the grouped product's
+    kernels in it.  Each launch that serves: its temporaries (``W_qkv``'s
+    12,288 columns a token in float32 on four layers, eight routed rows a
+    token) have to fit beside the weights and the three copies of the
+    index's 0.60 GB that an apply holds."""
+    import numpy as np
+
+    from pathway_tpu.models import causal_moe_embedder as cme
+    from pathway_tpu.models.encoder import dispatch_dtype, ragged_chunk
+    from pathway_tpu.ops import kda_scan as K
+
+    _the_chip_s_grouped_product(monkeypatch)
+    monkeypatch.setattr(cme, "kda_scan", K.kda_scan_pallas)
+    _config, cfg, params = _kimi_model(one_chip)
+    assert cfg.layer_types == ("kda", "kda", "kda", "latent", "kda")
+    assert (cfg.num_experts, cfg.experts_here, cfg.first_expert) == (256, 128, 0)
+    model = cme.CausalMoeEmbedder(cfg, packed=True)
+    none = np.zeros(0, np.int64)
+    for tokens in cfg.token_buckets:
+        chunk = ragged_chunk(none, none, None, None, cfg.max_len,
+                             dispatch_dtype(cfg.vocab_size), cfg, tokens=tokens)
+        args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+                for a in (chunk.ids, chunk.pos, chunk.seg, chunk.starts)]
+        compiled = jax.jit(lambda p, *a: model.apply({"params": p}, *a)).lower(
+            params, *args).compile()
+        memory = compiled.memory_analysis()
+        assert memory.argument_size_in_bytes == pytest.approx(8.566e9, rel=0.01)  # bfloat16
+        assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+                + 3 * 0.604e9 < 15.75e9), tokens
+        _assert_the_kernel_and_no_ragged_dot(compiled)
+        assert K.KERNEL_NAME in compiled.as_text()
+
+
+def test_the_delta_rule_check_s_layer_compiles_at_the_published_widths(
+        one_chip, no_persistent_cache, monkeypatch):
+    """What ``layer_gap`` calls in the cell: one KDA block over the longest
+    document (2,048 tokens), with the scan's kernel in it."""
+    from pathway_tpu.models import causal_moe_embedder as cme
+    from pathway_tpu.ops import kda_scan as K
+
+    _the_chip_s_grouped_product(monkeypatch)
+    monkeypatch.setattr(cme, "kda_scan", K.kda_scan_pallas)
+    config, cfg, params = _kimi_model(one_chip)  # puts perfbench/ on the path
+    from encoders import kimi_linear as builder
+
+    x = jax.ShapeDtypeStruct((cfg.max_len, cfg.hidden_dim), jnp.float32, sharding=one_chip)
+    program = builder._layer_program(json.dumps(config, sort_keys=True), 1)
+    compiled = program.lower(params["layer_1"], x).compile()
+    assert K.KERNEL_NAME in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e9
+
+
+#: sha256 of each sibling cell's packed program at its first token bucket,
+#: lowered for the described chip with the kernels' serialised bodies taken
+#: out (they carry the source's path): the delta rule, the direct query,
+#: the no-rotary form and the expert share lower to nothing in these programs
+_SIBLING_PROGRAMS = {
+    "laguna": ("vs-laguna-xs2-bf16-marcodoc",
+               "0f0dd1cbd5063fbe963dd11b9f3b7b21a2d1641731519375ce047ff2d1246522"),
+    "joyai": ("vs-joyai-flash-bf16-marcodoc",
+              "836ff5a33089b0c29bcdac469a900f67b20aa54563b4118b8686ca031e6460ac"),
+    "lfm2": ("vs-lfm2-24b-a2b-bf16-marcodoc",
+             "3362e4640ae88d189406b602f0fc6d5b940f9953950273199a0fab344e565cfe"),
+    "falcon_h1": ("vs-falcon-h1-34b-bf16-marcodoc",
+                  "d4095770bd59c5bb9e7480c074d864093d9f934c6fa3d6233f60b878b1603cee"),
+}
+
+
+@pytest.mark.parametrize("builder_name", sorted(_SIBLING_PROGRAMS))
+def test_the_sibling_cells_packed_programs_lower_as_before(
+        one_chip, no_persistent_cache, monkeypatch, builder_name):
+    """The packed programs of the four other language-model cells lower to
+    the same text as before the delta-rule layer kind, the latent kind's
+    direct query and no-rotary form and the routed layer's expert share were
+    added: a change here that reaches them changes their cells."""
+    import hashlib
+    import re
+
+    import numpy as np
+
+    from pathway_tpu.models import causal_hybrid_embedder as che
+    from pathway_tpu.models import causal_moe_embedder as cme
+    from pathway_tpu.models.encoder import dispatch_dtype, ragged_chunk
+    from pathway_tpu.ops import ssd_scan as S
+
+    _the_chip_s_grouped_product(monkeypatch)
+    monkeypatch.setattr(che, "ssd_scan", S.ssd_scan_pallas)
+    config_name, want = _SIBLING_PROGRAMS[builder_name]
+    hybrid = builder_name == "falcon_h1"
+    _config, cfg, params = _cell_model(one_chip, builder_name, config_name,
+                                       che if hybrid else cme)
+    model = (che.CausalHybridEmbedder if hybrid else cme.CausalMoeEmbedder)(cfg, packed=True)
+    none = np.zeros(0, np.int64)
+    chunk = ragged_chunk(none, none, None, None, cfg.max_len, dispatch_dtype(cfg.vocab_size),
+                         cfg, tokens=cfg.token_buckets[0])
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+            for a in (chunk.ids, chunk.pos, chunk.seg, chunk.starts)]
+    text = jax.jit(lambda p, *a: model.apply({"params": p}, *a)).lower(params, *args).as_text()
+    text = re.sub(r'backend_config = "[^"]*"', 'backend_config = ""', text)
+    assert hashlib.sha256(text.encode()).hexdigest() == want
